@@ -33,6 +33,7 @@ from repro.core import fractal as F
 from repro.core.compact import CompactLayout, cell_neighbor_tables
 from repro.core.domain import make_fractal_domain
 from repro.kernels import ops, ref
+from repro.launch.mesh import make_mesh
 from .common import row, time_fn
 
 # keep the dense path under ~0.5 GiB of f32 buffers by default
@@ -157,7 +158,7 @@ def run_overlap_ab(iters: int = 3, steps: int = 8,
         if jax.device_count() >= 2:
             from repro.core.shard import ShardedPlan
             D = jax.device_count()
-            mesh = jax.make_mesh((D,), ("data",))
+            mesh = make_mesh((D,), ("data",))
             plan = ShardedPlan(lay.domain, "prefetch_lut",
                                storage="compact", mesh=mesh,
                                axis="data", halo=True)
@@ -215,7 +216,7 @@ def run_shard_ab(iters: int = 3, steps: int = 8, cases=((128, 8),)):
             row(f"ca_shard/{storage}/D=1/n={n}/rho={block}", base,
                 f"bytes_per_device={bytes_dev};speedup=1.00")
             for D in sizes:
-                mesh = jax.make_mesh((D,), ("data",))
+                mesh = make_mesh((D,), ("data",))
                 t = time_fn(
                     lambda a, b: ops.ca_run(a, b, steps, fuse=1,
                                             rule="parity", block=block,

@@ -23,6 +23,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.mesh import make_mesh
 from .common import row
 
 
@@ -180,7 +181,7 @@ def run_zigzag_balance(device_counts=(2, 4, 8), nby: int = 32):
     rng = np.random.default_rng(0)
     s, d = nby * 16, 16
     q = jnp.asarray(rng.normal(size=(1, 2, s, d)), jnp.float32)
-    mesh = jax.make_mesh((D,), ("data",))
+    mesh = make_mesh((D,), ("data",))
     for bal in ("contiguous", "zigzag"):
         t = time_fn(
             lambda: ops.flash_attention(q, q, q, kind="causal",

@@ -36,6 +36,9 @@ def main(argv=None) -> None:
 
     import jax
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (bench_attention_domains, bench_ca, bench_map_time,
                    bench_serve, bench_sierpinski_map,
                    bench_space_efficiency, common)

@@ -105,7 +105,8 @@ def main():
                 f"--shard {args.shard} needs {args.shard} devices, have "
                 f"{jax.device_count()} (on CPU set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={args.shard})")
-        mesh = jax.make_mesh((args.shard,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((args.shard,), ("data",))
         print(f"sharded over {args.shard} devices "
               f"({'orthotope row slabs + ppermute halo' if args.storage == 'compact' else 'replicated state, disjoint psum'})")
 

@@ -4,8 +4,8 @@
                  race freedom, exactly-once coverage, table fidelity,
                  index bounds, aliasing safety.
 ``sanitizer`` -- interpret-mode access sanitizer: instruments emitted
-                 ``pallas_call``s (BlockSpec index maps, ``pl.load`` /
-                 ``pl.store``) and cross-checks the recorded traces
+                 ``pallas_call``s (BlockSpec index maps, ``backend.load`` /
+                 ``backend.store``) and cross-checks the recorded traces
                  against the statically computed read/write sets.
 ``verify``    -- the CLI: ``python -m repro.analysis.verify --matrix``
                  sweeps the feature matrix and emits a JSON report.

@@ -8,7 +8,8 @@ engine lowers while the trace is active gets instrumented:
 * every ``BlockSpec`` index map is wrapped to record, per grid step,
   the block index it actually returned (``jax.debug.callback`` fires
   with the concrete runtime values even under ``jit``);
-* ``pl.load`` / ``pl.store`` are shimmed for the duration, so the
+* :func:`repro.core.backend.load` / ``store`` are shimmed for the
+  duration, so the
   gpu structure's computed-offset accesses -- the ones no BlockSpec
   describes -- are recorded as concrete (offset, size) windows per ref
   shape.
@@ -97,9 +98,10 @@ class AccessTrace:
 
     def __enter__(self) -> "AccessTrace":
         self._prev_hook = backend_lib.set_emit_hook(self)
-        self._orig_load, self._orig_store = pl.load, pl.store
-        pl.load = self._shim_load
-        pl.store = self._shim_store
+        self._orig_load = backend_lib.load
+        self._orig_store = backend_lib.store
+        backend_lib.load = self._shim_load
+        backend_lib.store = self._shim_store
         self._active = True
         # previously traced configs would reuse cached, un-instrumented
         # executables: force a re-trace of everything run in the block
@@ -109,7 +111,8 @@ class AccessTrace:
     def __exit__(self, *exc):
         self._active = False
         backend_lib.set_emit_hook(self._prev_hook)
-        pl.load, pl.store = self._orig_load, self._orig_store
+        backend_lib.load = self._orig_load
+        backend_lib.store = self._orig_store
         # drop the instrumented executables so later calls re-trace
         # clean (the recording callbacks hold a reference to us)
         jax.clear_caches()
@@ -186,13 +189,13 @@ class AccessTrace:
         launch.im_trace[opid].add(
             tuple(int(np.asarray(v)) for v in vals))
 
-    def _shim_load(self, ref, idx=None, *args, **kwargs):
+    def _shim_load(self, ref, idx):
         self._record_access("load", ref, idx)
-        return self._orig_load(ref, idx, *args, **kwargs)
+        return self._orig_load(ref, idx)
 
-    def _shim_store(self, ref, idx, val, *args, **kwargs):
+    def _shim_store(self, ref, idx, val):
         self._record_access("store", ref, idx)
-        return self._orig_store(ref, idx, val, *args, **kwargs)
+        return self._orig_store(ref, idx, val)
 
     def _record_access(self, kind, ref, idx):
         if not self._stack or not self._active:

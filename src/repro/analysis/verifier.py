@@ -31,7 +31,7 @@ is also evaluable on host numpy arrays, so the verifier enumerates the
 ``bounds``
     ``storage_index`` / ``neighbor_index`` are evaluated over **all**
     grid steps (dead and pad steps still drive index maps and
-    ``pl.load``) and the exact [min, max] hull per axis is checked
+    ``backend.load``) and the exact [min, max] hull per axis is checked
     against the operand tile grid.
 
 ``alias``
@@ -359,7 +359,7 @@ def _check_bounds(plan, refs_per_device, per_device, model, findings):
                 "bounds", f"storage index hull "
                 f"rows [{r.min()}, {r.max()}] x cols "
                 f"[{c.min()}, {c.max()}] exceeds the ({nr}, {nc}) "
-                f"tile grid (some pl.load/pl.store may go OOB)",
+                f"tile grid (some backend.load/store may go OOB)",
                 device=d))
         if not model["neighbors"]:
             continue

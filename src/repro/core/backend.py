@@ -22,8 +22,8 @@ gives the engine a real backend axis:
     thread-mapping work, arXiv:2004.13475) do: the per-block
     lambda / slot / neighbour LUT travels as a **regular HBM operand**
     read in-kernel at ``pl.program_id``; state arrays arrive whole and
-    kernels address tiles with computed offsets (``pl.load`` /
-    ``pl.store``); run-time step counts are ordinary scalar operands;
+    kernels address tiles with computed offsets (:func:`load` /
+    :func:`store`); run-time step counts are ordinary scalar operands;
     reduction state lives in loop carries, not scratch.  On a CUDA
     device the call lowers through Triton with ``num_warps`` /
     ``num_stages`` from the autotuner.
@@ -37,6 +37,8 @@ Selection order for the default target: an explicit ``backend=``
 argument > :func:`set_default` > the ``REPRO_BACKEND`` environment
 variable > the jax platform (tpu -> ``tpu``, gpu -> ``gpu``, anything
 else -> ``tpu-interpret``, preserving the historical CPU behaviour).
+A native target named explicitly stays native on every platform: off
+its chip it fails to lower instead of being emulated unseen.
 """
 from __future__ import annotations
 
@@ -46,6 +48,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -88,7 +91,7 @@ class BackendTarget:
                          the model each structure assumes.
     async_copy:          kernels may issue explicit in-kernel DMA
                          (``pltpu.make_async_copy`` + DMA semaphores,
-                         operands parked in ``pltpu.ANY``) and overlap
+                         operands parked in ``pl.ANY``) and overlap
                          the copy with compute.  Mosaic has DMA
                          engines; the interpreter emulates the copies
                          synchronously, preserving semantics.
@@ -166,14 +169,14 @@ class BackendTarget:
         return max(1, min(int(num_stages), self.pipeline_stages))
 
     def any_spec(self) -> pl.BlockSpec:
-        """BlockSpec parking an operand un-copied (``pltpu.ANY``) so
+        """BlockSpec parking an operand un-copied (``pl.ANY``) so
         the kernel streams tiles out of it with explicit DMA.  Only
         meaningful on :attr:`async_copy` targets."""
         if not self.async_copy:
             raise ValueError(
                 f"target {self.name!r} has no async-copy support; "
                 f"operands must arrive via BlockSpec pipeline copies")
-        return pl.BlockSpec(memory_space=pltpu.ANY)
+        return pl.BlockSpec(memory_space=pl.ANY)
 
     def dma_sems(self, shape) -> object:
         """A scratch array of DMA-completion semaphores (one per
@@ -249,13 +252,13 @@ def set_default(name: Optional[str]) -> None:
 def resolve(spec=None, interpret: Optional[bool] = None) -> BackendTarget:
     """Normalize a backend spec to a :class:`BackendTarget`.
 
-    spec: a target, a name ("tpu" | "gpu" | "*-interpret" | "interpret"
-    = platform default emulated), or None (defaulting rules in the
-    module docstring).  ``interpret=True`` forces emulation;
-    ``interpret=False`` pins the native structure (the caller takes
-    responsibility for the platform).  With ``interpret`` unset, a
-    native target off its own platform auto-emulates -- the historical
-    "interpret off-TPU" fallback, now per-target.
+    spec: a target (returned as given), a name ("tpu" | "gpu" |
+    "*-interpret" | "interpret" = platform default emulated), or None
+    (defaulting rules in the module docstring).  ``interpret=True``
+    forces emulation; ``interpret=False`` pins the native structure.
+    With ``interpret`` unset the named target is kept as it is: a
+    native target is never emulated behind the caller's back, so a
+    compile rehearsal off the chip reaches the real compiler.
     """
     if isinstance(spec, BackendTarget):
         target = spec
@@ -263,9 +266,7 @@ def resolve(spec=None, interpret: Optional[bool] = None) -> BackendTarget:
         if spec is None:
             spec = _OVERRIDE or os.environ.get(BACKEND_ENV) or None
         if spec is None:
-            plat = jax.default_backend()
-            target = TPU if plat == "tpu" else (
-                GPU if plat == "gpu" else TPU_INTERPRET)
+            target = platform_default()
         else:
             name = _ALIASES.get(spec, spec)
             if name == "interpret":
@@ -282,9 +283,21 @@ def resolve(spec=None, interpret: Optional[bool] = None) -> BackendTarget:
         return target.emulated()
     if interpret is False:
         return target.native()
-    if not target.interpret and jax.default_backend() != target.kind:
-        return target.emulated()
     return target
+
+
+def load(ref, idx):
+    """Read the window ``idx`` (a tuple of ``pl.ds`` / ints) of ``ref``:
+    the gpu structure's computed-offset tile read.  The access
+    sanitizer replaces this function (and :func:`store`) to record
+    every window a launch touches."""
+    return ref[idx]
+
+
+def store(ref, idx, val) -> None:
+    """Write ``val`` into the window ``idx`` of ``ref`` (see
+    :func:`load`)."""
+    ref[idx] = val
 
 
 def stream_tiles(src_ref, bufs_ref, sems, *, srcs_for, lin, total,
@@ -292,7 +305,7 @@ def stream_tiles(src_ref, bufs_ref, sems, *, srcs_for, lin, total,
     """One sequential-grid step of software-pipelined tile streaming
     (the TPU structure's async-copy double/multi buffer).
 
-    ``src_ref`` is the state parked whole in ``pltpu.ANY``;
+    ``src_ref`` is the state parked whole in ``pl.ANY``;
     ``bufs_ref`` is VMEM scratch ``(stages, n_tiles, th, tw)`` and
     ``sems`` a matching ``(stages, n_tiles)`` DMA semaphore array.
     ``srcs_for(step)`` returns the (tile_row, tile_col) indices of the
@@ -350,6 +363,53 @@ def full_spec(shape) -> pl.BlockSpec:
     origin for every grid step)."""
     nd = len(shape)
     return pl.BlockSpec(tuple(shape), lambda *_: (0,) * nd)
+
+
+# ---------------------------------------------------------------------------
+# scalar-prefetch tables in SMEM
+# ---------------------------------------------------------------------------
+
+#: SMEM a kernel's scalar-prefetch tables may fill together: the 1 MiB
+#: of a TPU v5e core, less 16 KiB kept for the compiler's own scalars.
+#: Each table is placed in whole 4 KiB pages.
+SMEM_TABLE_BYTES = (1 << 20) - (16 << 10)
+_SMEM_PAGE = 4096
+
+
+class RowMajor:
+    """2-D read view of a flattened scalar-prefetch table ref.
+
+    Mosaic pads every row of a 2-D SMEM array to 128 words, so a
+    (rows, 2) decode table would take 64x its size; tables therefore
+    ride flat and ``view[i, j]`` reads ``ref[i * cols + j]``."""
+
+    __slots__ = ("ref", "shape")
+
+    def __init__(self, ref, shape):
+        self.ref = ref
+        self.shape = tuple(shape)
+
+    def __getitem__(self, idx):
+        i, j = idx
+        return self.ref[i * self.shape[1] + j]
+
+
+def check_smem_tables(shapes, plan=None) -> None:
+    """Refuse scalar-prefetch tables that would not fit SMEM, naming
+    the limit, before anything is lowered.  ``shapes`` are the i32
+    table shapes of one launch."""
+    need = sum(-(-4 * int(np.prod(s)) // _SMEM_PAGE) * _SMEM_PAGE
+               for s in shapes)
+    if need > SMEM_TABLE_BYTES:
+        what = (f"{plan.lowering} plan over {type(plan.domain).__name__}"
+                f" ({plan.storage} storage)" if plan is not None
+                else "launch")
+        raise ValueError(
+            f"scalar-prefetch tables {list(shapes)} of this {what} need "
+            f"{need} B of SMEM; the limit is {SMEM_TABLE_BYTES} B "
+            f"(1 MiB per TPU v5e core less 16 KiB).  Use a lowering "
+            f"without a table (closed_form, bounding) or a coarser "
+            f"block for this size.")
 
 
 # ---------------------------------------------------------------------------
@@ -466,16 +526,41 @@ def emit(plan, kernel: Callable, *, in_specs, out_specs, out_shape,
     aliases = {i + nsp: o for i, o in aliases.items()}
 
     if target.has_scalar_prefetch:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=nsp,
-            grid=plan.grid,
-            in_specs=list(in_specs),
-            out_specs=out_specs,
-            scratch_shapes=list(scratch_shapes),
-        )
-        call = pl.pallas_call(
-            wrapped, grid_spec=grid_spec, out_shape=out_shape,
-            input_output_aliases=aliases, interpret=interp, **extra)
+        def call(*args):
+            # tables ride flat (see RowMajor); their shapes are known
+            # only at call time (sharded chunks arrive pre-split by
+            # shard_map), so the call is built per trace
+            shapes = [tuple(t.shape) for t in args[:nsp]]
+            check_smem_tables(shapes, plan)
+
+            def views(refs):
+                return tuple(RowMajor(r, shp) if len(shp) == 2 else r
+                             for r, shp in zip(refs, shapes))
+
+            def flat_spec(spec):
+                im = getattr(spec, "index_map", None)
+                if im is None:
+                    return spec
+                return dataclasses.replace(spec, index_map=lambda *a: im(
+                    *a[:-nsp], *views(a[-nsp:])))
+
+            def kernel_flat(*refs):
+                wrapped(*views(refs[:nsp]), *refs[nsp:])
+
+            outs = (type(out_specs)(flat_spec(o) for o in out_specs)
+                    if isinstance(out_specs, (list, tuple))
+                    else flat_spec(out_specs))
+            grid_spec = pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=nsp,
+                grid=plan.grid,
+                in_specs=[flat_spec(sp) for sp in in_specs],
+                out_specs=outs,
+                scratch_shapes=list(scratch_shapes),
+            )
+            c = pl.pallas_call(
+                kernel_flat, grid_spec=grid_spec, out_shape=out_shape,
+                input_output_aliases=aliases, interpret=interp, **extra)
+            return c(*(t.reshape(-1) for t in args[:nsp]), *args[nsp:])
     else:
         def call(*args):
             # table shapes are only known at call time (sharded chunks

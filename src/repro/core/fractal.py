@@ -387,8 +387,10 @@ def all_block_coords(r: int) -> jnp.ndarray:
 
 
 def membership_grid(n: int) -> np.ndarray:
-    """Dense boolean occupancy of the embedded gasket via the bit test."""
-    y, x = np.mgrid[0:n, 0:n]
+    """Dense boolean occupancy of the embedded gasket via the bit test
+    (broadcast rows x columns: no n x n index grids)."""
+    y = np.arange(n)[:, None]
+    x = np.arange(n)[None, :]
     return (x & (n - 1 - y)) == 0
 
 

@@ -146,13 +146,17 @@ class BlockCoords:
         self.grid_ids = tuple(grid_ids)
         self.refs = tuple(refs)
 
-    def when_valid(self, body: Callable[[], None]) -> None:
+    def when_valid(self, body: Callable[[], None],
+                   otherwise: Optional[Callable[[], None]] = None) -> None:
         """Run ``body`` for member blocks only (no-op guard when the
-        lowering already guarantees membership)."""
+        lowering already guarantees membership), and ``otherwise``, when
+        given, for the rest (see :attr:`GridPlan.skip_fills_output`)."""
         if self.valid is None:
             body()
-        else:
-            pl.when(self.valid)(body)
+            return
+        pl.when(self.valid)(body)
+        if otherwise is not None:
+            pl.when(jnp.logical_not(self.valid))(otherwise)
 
 
 class GridPlan:
@@ -501,7 +505,7 @@ class GridPlan:
         inline.  Shared by the ``BlockSpec`` index maps (TPU, where
         ``refs`` are scalar-prefetch refs) and the gpu-structured
         kernel bodies (where ``refs`` are the leading HBM operand refs
-        and the returned index drives ``pl.load``/``pl.store``)."""
+        and the returned index drives ``backend.load``/``backend.store``)."""
         if self.storage == "embedded":
             _, bx, by = self._decode(grid_ids, refs)
             bx, by = self._place_coords(bx, by, refs)
@@ -589,6 +593,34 @@ class GridPlan:
         return self._index_spec(
             self.supertile_shape(block_shape),
             lambda grid_ids, refs: self.neighbor_index(j, grid_ids, refs))
+
+    # -- output write-back on the chip ---------------------------------------
+
+    @property
+    def skip_fills_output(self) -> bool:
+        """Whether a kernel must fill the output block of a skipped step
+        (``coords.valid`` false).  The chip writes every visited output
+        block back without reading it first.  Under ``bounding`` over
+        embedded storage each skipped step owns its block, so the
+        kernel passes it through.  The other skipped steps (the padding
+        of sharded enumerations) revisit the block of the step before
+        them, which must be left as that step wrote it."""
+        return self.lowering == "bounding" and self.storage == "embedded"
+
+    def check_output_writeback(self) -> None:
+        """Refuse, on the chip, a plan whose skipped steps write back
+        over blocks that member steps own: ``bounding`` over compact
+        storage maps non-member blocks onto member slots.  The
+        interpreter reads each output block before a step, so it keeps
+        such plans (the lowering equivalence tests use them)."""
+        if (self.lowering == "bounding" and self.storage == "compact"
+                and self.target.block_indexed
+                and not self.target.interpret):
+            raise ValueError(
+                "grid_mode='bounding' with storage='compact' cannot run "
+                "on the chip: non-member steps map onto member slots and "
+                "every visited output block is written back; use "
+                "closed_form, prefetch_lut or mma with compact storage")
 
     # -- in-kernel accessor -------------------------------------------------
 

@@ -36,8 +36,9 @@ def make_elastic_mesh(devices=None, max_model: int = 16):
     n = len(devices)
     data, model = candidate_meshes(n, max_model)[0]
     used = data * model
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devices[:used])
+    from repro.launch.mesh import make_mesh
+    return make_mesh((data, model), ("data", "model"),
+                     devices=devices[:used])
 
 
 def elastic_restore(ckpt_manager, params_template, cfg=None, *,

@@ -239,9 +239,9 @@ def _gpu_flash_call(*, target, domain, lowering, b, h, group, m_q, m_k,
             # consumes) are unchanged, and pipelined prefetches past
             # the row extent stay in bounds
             kv = jnp.clip(kb - s0, 0, kv_blocks - 1)
-            t = pl.load(ref, (pl.ds(0, 1), pl.ds(0, 1),
-                              pl.ds(kv * block_k, block_k),
-                              pl.ds(0, d)))
+            t = backend_lib.load(ref, (pl.ds(0, 1), pl.ds(0, 1),
+                                       pl.ds(kv * block_k, block_k),
+                                       pl.ds(0, d)))
             return t.reshape(block_k, d).astype(jnp.float32)
 
         def load_tiles(kb):
@@ -508,7 +508,6 @@ def _flash_impl(q, k, v, seq_pos=None, *, kind, window, scale, block_q,
     # shard the query-block axis: q/o split along the sequence dim,
     # k/v replicated; each device runs its contiguous query-row band
     # (whole rows, so the online-softmax state never crosses devices).
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.shard import device_tables
@@ -533,11 +532,11 @@ def _flash_impl(q, k, v, seq_pos=None, *, kind, window, scale, block_q,
         # output back through the inverse permutation after.
         qr = q.reshape(b, h, m_q, block_q, d)
         q = qr[:, :, zz_perm].reshape(b, h, sq, d)
-    out = shard_map(
+    out = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(P(axis, None), tuple(P(axis, None) for _ in luts))
         + qkv_specs,
-        out_specs=P(None, None, axis, None), check_rep=False)(
+        out_specs=P(None, None, axis, None), check_vma=False)(
             tbl, luts, q, k, v)
     if zz_perm is not None:
         inv = np.argsort(zz_perm)
@@ -705,7 +704,7 @@ def _gpu_paged_call(*, target, b, h, group, m_k, page_size, d, window,
     """gpu-structured paged decode: one program per (slot, head), the
     whole pool and page table as HBM operands, an in-kernel loop over
     the slot's logical key blocks that resolves each physical page with
-    a table read and ``pl.load``\\ s the fused ``(2, page_size, d)``
+    a table read and ``backend.load``\\ s the fused ``(2, page_size, d)``
     head tile at its offset.  The loop bound comes from the slot's
     ``seq_pos``, so only O(pos / page_size) pages are *read* -- the
     block-space work saving at run time."""
@@ -723,8 +722,9 @@ def _gpu_paged_call(*, target, b, h, group, m_k, page_size, d, window,
 
         def load_tiles(kb):
             page = pt_ref[slot, kb]
-            t = pl.load(kv_ref, (pl.ds(page, 1), pl.ds(2 * kvh, 2),
-                                 pl.ds(0, page_size), pl.ds(0, d)))
+            t = backend_lib.load(
+                kv_ref, (pl.ds(page, 1), pl.ds(2 * kvh, 2),
+                         pl.ds(0, page_size), pl.ds(0, d)))
             t = t.reshape(2, page_size, d).astype(jnp.float32)
             return t[0], t[1]
 

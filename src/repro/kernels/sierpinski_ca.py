@@ -50,13 +50,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import backend as backend_lib
 from repro.core.backend import full_spec
 from repro.core.compact import NEIGHBOR_OFFSETS8
 from repro.core.domain import BlockDomain
 from repro.core.plan import GridPlan
-from .sierpinski_write import resolve_auto_schedule, resolve_storage_args
+from .sierpinski_write import (resolve_auto_schedule, resolve_storage_args,
+                               tile_grid)
 
 #: trace/build telemetry the schedule-equivalence tests read: "kernel"
 #: counts fused-kernel body traces, "build" counts pallas_call
@@ -114,6 +116,10 @@ def launch_schedule(steps: int, fuse: int) -> list:
     return [fuse] * full + ([rem] if rem else [])
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
 def _trapezoid_update(tiles, bx, by, steps, *, rule, alpha, block, n,
                       plan, halo):
     """The fused-CA math, shared by both emission structures: assemble
@@ -124,54 +130,59 @@ def _trapezoid_update(tiles, bx, by, steps, *, rule, alpha, block, n,
 
     ``tiles``: 9 arrays in [center] + NEIGHBOR_OFFSETS8 order, each the
     plan's storage-supertile shape.  ``(bx, by)``: scheduled (coarse)
-    block coords."""
+    block coords.
+
+    Everything here is a static slice, a concatenation, a roll or an
+    elementwise op, which Mosaic lowers.  The halo ring is widened to
+    the (8, 128) vreg tiling -- ``hr`` rows, ``hc`` columns -- so every
+    concatenated piece and the final interior slice stay tile-aligned;
+    the wider ring changes nothing inside, because after ``steps <=
+    halo`` iterations only the outer ``steps`` rings are stale."""
     domain = plan.domain
     span = plan.coarsen * block        # embedded superblock side, cells
-    h = halo
-    wid = span + 2 * h                 # working (trapezoid base) side
+    hr = min(span, _round_up(halo, 8))
+    hc = min(span, _round_up(halo, 128))
+    wr, wc = span + 2 * hr, span + 2 * hc   # working (trapezoid base)
     tm = plan.tile_map()
+
+    def fine(t, r, c):
+        return t[r * block:(r + 1) * block, c * block:(c + 1) * block]
 
     def embed(t):
         """Packed supertile -> embedded arrangement (identity when the
         storage tile is already embedded-ordered)."""
         if tm is None:
             return t
-        e = jnp.zeros((span, span), t.dtype)
-        for (py, px), (ey, ex) in tm:
-            e = jax.lax.dynamic_update_slice(
-                e, t[py * block:(py + 1) * block,
-                     px * block:(px + 1) * block],
-                (ey * block, ex * block))
-        return e
+        at = {e: p for p, e in tm}
+        z = jnp.zeros((block, block), t.dtype)
+        s = plan.coarsen
+        return tile_grid([[fine(t, *at[(ey, ex)]) if (ey, ex) in at
+                           else z for ex in range(s)] for ey in range(s)])
 
     def unembed(e):
         if tm is None:
             return e
-        p = jnp.zeros(plan.supertile_shape((block, block)), e.dtype)
-        for (py, px), (ey, ex) in tm:
-            p = jax.lax.dynamic_update_slice(
-                p, e[ey * block:(ey + 1) * block,
-                     ex * block:(ex + 1) * block],
-                (py * block, px * block))
-        return p
+        at = dict(tm)
+        z = jnp.zeros((block, block), e.dtype)
+        th, tw = plan.supertile_shape((block, block))
+        return tile_grid([[fine(e, *at[(py, px)]) if (py, px) in at
+                           else z for px in range(tw // block)]
+                          for py in range(th // block)])
 
     # strip geometry: which rows/cols of a neighbour's embedded view
-    # land where in the padded working array (relative offset -1/0/+1)
-    _SPANS = {-1: (span - h, 0, h), 0: (0, h, span), 1: (0, span + h, h)}
+    # land in the padded working array (relative offset -1/0/+1)
+    rows = {-1: slice(span - hr, span), 0: slice(0, span), 1: slice(0, hr)}
+    cols = {-1: slice(span - hc, span), 0: slice(0, span), 1: slice(0, hc)}
+    by_offset = {(0, 0): embed(tiles[0])}
+    for j, off in enumerate(NEIGHBOR_OFFSETS8):
+        by_offset[off] = embed(tiles[1 + j])
+    P = tile_grid([[by_offset[(dx, dy)][rows[dy], cols[dx]]
+                    for dx in (-1, 0, 1)] for dy in (-1, 0, 1)])
 
-    P = jnp.zeros((wid, wid), tiles[0].dtype)
-    P = jax.lax.dynamic_update_slice(P, embed(tiles[0]), (h, h))
-    for j, (dx, dy) in enumerate(NEIGHBOR_OFFSETS8):
-        e = embed(tiles[1 + j])
-        r_src, r_dst, nr = _SPANS[dy]
-        c_src, c_dst, nc = _SPANS[dx]
-        P = jax.lax.dynamic_update_slice(
-            P, e[r_src:r_src + nr, c_src:c_src + nc], (r_dst, c_dst))
-
-    iy = jax.lax.broadcasted_iota(jnp.int32, (wid, wid), 0)
-    ix = jax.lax.broadcasted_iota(jnp.int32, (wid, wid), 1)
-    gx = bx * span - h + ix
-    gy = by * span - h + iy
+    iy = jax.lax.broadcasted_iota(jnp.int32, (wr, wc), 0)
+    ix = jax.lax.broadcasted_iota(jnp.int32, (wr, wc), 1)
+    gx = bx * span - hc + ix
+    gy = by * span - hr + iy
     inr = (gx >= 0) & (gx < n) & (gy >= 0) & (gy < n)
     gxc = jnp.clip(gx, 0, n - 1)
     gyc = jnp.clip(gy, 0, n - 1)
@@ -184,14 +195,13 @@ def _trapezoid_update(tiles, bx, by, steps, *, rule, alpha, block, n,
     block_ok = inr & domain.contains(gxc // block, gyc // block)
     P = jnp.where(block_ok, P, 0)
 
-    zrow = jnp.zeros((1, wid), P.dtype)
-    zcol = jnp.zeros((wid, 1), P.dtype)
-
     def nsum_of(a):
-        up = jnp.concatenate([zrow.astype(a.dtype), a[:-1, :]], 0)
-        down = jnp.concatenate([a[1:, :], zrow.astype(a.dtype)], 0)
-        left = jnp.concatenate([zcol.astype(a.dtype), a[:, :-1]], 1)
-        right = jnp.concatenate([a[:, 1:], zcol.astype(a.dtype)], 1)
+        # cyclic shifts: what wraps in at the edge of the working array
+        # lands in the stale outer ring, never in the interior
+        up = pltpu.roll(a, 1, 0)            # a[i-1, j]
+        down = pltpu.roll(a, wr - 1, 0)     # a[i+1, j]
+        left = pltpu.roll(a, 1, 1)          # a[i, j-1]
+        right = pltpu.roll(a, wc - 1, 1)    # a[i, j+1]
         return up + down + left + right
 
     if rule == "parity":
@@ -206,7 +216,7 @@ def _trapezoid_update(tiles, bx, by, steps, *, rule, alpha, block, n,
             return jnp.where(cell_ok, new, 0)
 
     P2 = jax.lax.fori_loop(0, steps, lambda i, pv: one(pv), P)
-    return unembed(P2[h:h + span, h:h + span])
+    return unembed(P2[hr:hr + span, hc:hc + span])
 
 
 def _ca_fused_kernel(coords, c_ref, n_ref, s_ref, w_ref, e_ref, nw_ref,
@@ -226,14 +236,18 @@ def _ca_fused_kernel(coords, c_ref, n_ref, s_ref, w_ref, e_ref, nw_ref,
             alpha=alpha, block=block, n=n, plan=plan,
             halo=halo).astype(o_ref.dtype)
 
-    coords.when_valid(body)
+    def keep_stale():
+        o_ref[...] = buf_ref[...]
+
+    coords.when_valid(body, keep_stale if plan.skip_fills_output
+                      else None)
 
 
 def _ca_fused_kernel_dma(coords, c_ref, buf_ref, steps_ref, o_ref,
                          bufs_ref, sems, *, rule, alpha, block, n, plan,
                          halo, stages):
     """Async-copy pipelined fused CA (TPU structure, ``num_stages`` >=
-    2): the state is parked whole in ``pltpu.ANY`` and the kernel
+    2): the state is parked whole in ``pl.ANY`` and the kernel
     streams each step's 9 supertiles (center + 8 lambda^-1-resolved
     neighbours) into rotating VMEM buffers with explicit DMA -- the
     copies for grid step t+stages-1 start before step t's trapezoid
@@ -262,7 +276,11 @@ def _ca_fused_kernel_dma(coords, c_ref, buf_ref, steps_ref, o_ref,
             alpha=alpha, block=block, n=n, plan=plan,
             halo=halo).astype(o_ref.dtype)
 
-    coords.when_valid(body)
+    def keep_stale():
+        o_ref[...] = buf_ref[...]
+
+    coords.when_valid(body, keep_stale if plan.skip_fills_output
+                      else None)
 
 
 def _ca_fused_kernel_gpu(coords, c_ref, buf_ref, steps_ref, o_ref, *,
@@ -277,7 +295,8 @@ def _ca_fused_kernel_gpu(coords, c_ref, buf_ref, steps_ref, o_ref, *,
     gi, refs = coords.grid_ids, coords.refs
 
     def load_at(iy, ix):
-        return pl.load(c_ref, (pl.ds(iy * th, th), pl.ds(ix * tw, tw)))
+        return backend_lib.load(
+            c_ref, (pl.ds(iy * th, th), pl.ds(ix * tw, tw)))
 
     def body():
         cy, cx = plan.storage_index(gi, refs)
@@ -288,8 +307,8 @@ def _ca_fused_kernel_gpu(coords, c_ref, buf_ref, steps_ref, o_ref, *,
         out = _trapezoid_update(
             tiles, coords.bx, coords.by, steps_ref[0], rule=rule,
             alpha=alpha, block=block, n=n, plan=plan, halo=halo)
-        pl.store(o_ref, (pl.ds(cy * th, th), pl.ds(cx * tw, tw)),
-                 out.astype(o_ref.dtype))
+        backend_lib.store(o_ref, (pl.ds(cy * th, th), pl.ds(cx * tw, tw)),
+                          out.astype(o_ref.dtype))
 
     coords.when_valid(body)
 
@@ -300,7 +319,7 @@ def _build_launch(plan, *, rule, alpha, block, n, halo, shape, dtype,
 
     Block-indexed targets receive nine BlockSpec views of the state;
     with ``stages >= 2`` on an async-copy target the state instead
-    arrives whole (``pltpu.ANY``) and the kernel streams the nine tiles
+    arrives whole (``pl.ANY``) and the kernel streams the nine tiles
     through rotating VMEM DMA buffers (:func:`_ca_fused_kernel_dma`).
     gpu targets receive it whole (``in_shape``, which may be the
     halo-extended local array under sharding) plus the stale buffer and
@@ -310,6 +329,7 @@ def _build_launch(plan, *, rule, alpha, block, n, halo, shape, dtype,
     TRACE_COUNTER["build"] += 1
     target = plan.target
     stages = target.resolve_stages(stages)
+    plan.check_output_writeback()
     kernel_kw = dict(rule=rule, alpha=alpha, block=block, n=n, plan=plan,
                      halo=halo)
     if target.block_indexed and stages > 1:
@@ -415,7 +435,6 @@ def _ca_run_sharded_impl(state, stale_buf, *, steps, fuse, rule, alpha,
     combined by a disjoint-ownership-mask psum after every launch.
     Bit-identical to the single-device scan (every block is computed by
     exactly one device with the same operands)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.shard import ShardedPlan, device_tables
@@ -495,12 +514,12 @@ def _ca_run_sharded_impl(state, stale_buf, *, steps, fuse, rule, alpha,
                 (xa, _), _ = jax.lax.scan(body, (a, b), sched_arr)
                 return xa
 
-            out = shard_map(
+            out = jax.shard_map(
                 device_fn, mesh=mesh,
                 in_specs=(tbl_spec, lut_specs, P(axis, None),
                           P(axis, None), sr_specs, P(axis, None),
                           P(axis, None)),
-                out_specs=P(axis, None), check_rep=False)(
+                out_specs=P(axis, None), check_vma=False)(
                     tbl, luts, itb, btb, sr, a, b)
             return plan.unpad_rows(out, block)
 
@@ -516,11 +535,11 @@ def _ca_run_sharded_impl(state, stale_buf, *, steps, fuse, rule, alpha,
             (xa, _), _ = jax.lax.scan(body, (a, b), sched_arr)
             return xa
 
-        out = shard_map(
+        out = jax.shard_map(
             device_fn, mesh=mesh,
             in_specs=(tbl_spec, lut_specs, sr_specs, P(axis, None),
                       P(axis, None)),
-            out_specs=P(axis, None), check_rep=False)(tbl, luts, sr, a, b)
+            out_specs=P(axis, None), check_vma=False)(tbl, luts, sr, a, b)
         return plan.unpad_rows(out, block)
 
     def device_fn(tbl, luts, a, b):
@@ -537,10 +556,10 @@ def _ca_run_sharded_impl(state, stale_buf, *, steps, fuse, rule, alpha,
         (xa, _), _ = jax.lax.scan(body, (a, b), sched_arr)
         return xa
 
-    return shard_map(
+    return jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(tbl_spec, lut_specs, P(None, None), P(None, None)),
-        out_specs=P(None, None), check_rep=False)(
+        out_specs=P(None, None), check_vma=False)(
             tbl, luts, state, stale_buf)
 
 

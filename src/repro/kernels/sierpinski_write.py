@@ -159,13 +159,21 @@ def _tile_mask(plan: GridPlan, bx, by, block: int, n: int):
     # constants, so the offsets enter as scalar adds on iota)
     iy = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
     ix = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-    mask = jnp.zeros((th, tw), jnp.bool_)
-    for (py, px), (ey, ex) in tm:
-        sub = plan.domain.cell_member(bx * span + ex * block + ix,
-                                      by * span + ey * block + iy, n)
-        mask = jax.lax.dynamic_update_slice(mask, sub,
-                                            (py * block, px * block))
-    return mask
+    at = dict(tm)
+    off = jnp.zeros((block, block), jnp.bool_)
+    return tile_grid([[plan.domain.cell_member(
+        bx * span + at[(py, px)][1] * block + ix,
+        by * span + at[(py, px)][0] * block + iy, n)
+        if (py, px) in at else off
+        for px in range(tw // block)] for py in range(th // block)])
+
+
+def tile_grid(rows):
+    """Assemble a 2-D grid of tiles (a list of rows, each a list of
+    arrays of equal height) by concatenation -- the Mosaic-lowerable
+    way to build a larger array from static pieces."""
+    return jnp.concatenate([jnp.concatenate(r, axis=1) for r in rows],
+                           axis=0)
 
 
 def _write_kernel(coords, m_ref, o_ref, *, value, block, n, plan):
@@ -174,12 +182,16 @@ def _write_kernel(coords, m_ref, o_ref, *, value, block, n, plan):
         o_ref[...] = jnp.where(mask, jnp.asarray(value, o_ref.dtype),
                                m_ref[...])
 
-    coords.when_valid(body)
+    def pass_through():
+        o_ref[...] = m_ref[...]
+
+    coords.when_valid(body, pass_through if plan.skip_fills_output
+                      else None)
 
 
 def _stream_storage_tile(coords, m_ref, bufs_ref, sems, plan, stages):
     """This grid step's storage supertile, streamed out of the
-    ``pltpu.ANY``-resident state through the rotating async-copy
+    ``pl.ANY``-resident state through the rotating async-copy
     buffers (the copy for step t+stages-1 starts before this step's
     compute; see :func:`repro.core.backend.stream_tiles`)."""
     lin = plan.linear_step(coords.grid_ids)
@@ -195,7 +207,7 @@ def _stream_storage_tile(coords, m_ref, bufs_ref, sems, plan, stages):
 def _write_kernel_dma(coords, m_ref, alias_ref, o_ref, bufs_ref, sems,
                       *, value, block, n, plan, stages):
     """Async-copy pipelined write (TPU structure, ``num_stages`` >= 2):
-    the state is parked in ``pltpu.ANY`` and each step's input tile
+    the state is parked in ``pl.ANY`` and each step's input tile
     streams through rotating VMEM DMA buffers while the next step's
     copy is in flight.  ``alias_ref`` is the same state routed as a
     BlockSpec operand purely to alias the unwritten remainder to the
@@ -209,7 +221,11 @@ def _write_kernel_dma(coords, m_ref, alias_ref, o_ref, bufs_ref, sems,
         o_ref[...] = jnp.where(mask, jnp.asarray(value, o_ref.dtype),
                                tile.astype(o_ref.dtype))
 
-    coords.when_valid(body)
+    def pass_through():
+        o_ref[...] = tile.astype(o_ref.dtype)
+
+    coords.when_valid(body, pass_through if plan.skip_fills_output
+                      else None)
 
 
 def _write_kernel_gpu(coords, m_ref, o_ref, *, value, block, n, plan):
@@ -222,10 +238,11 @@ def _write_kernel_gpu(coords, m_ref, o_ref, *, value, block, n, plan):
     def body():
         iy, ix = plan.storage_index(coords.grid_ids, coords.refs)
         idx = (pl.ds(iy * th, th), pl.ds(ix * tw, tw))
-        tile = pl.load(m_ref, idx)
+        tile = backend_lib.load(m_ref, idx)
         mask = _tile_mask(plan, coords.bx, coords.by, block, n)
-        pl.store(o_ref, idx,
-                 jnp.where(mask, jnp.asarray(value, o_ref.dtype), tile))
+        backend_lib.store(
+            o_ref, idx,
+            jnp.where(mask, jnp.asarray(value, o_ref.dtype), tile))
 
     coords.when_valid(body)
 
@@ -241,6 +258,7 @@ def _emit_write(plan: GridPlan, shape, dtype, *, value, block, n,
     it only feeds the Triton scheduler."""
     target = plan.target
     stages = target.resolve_stages(stages)
+    plan.check_output_writeback()
     if target.block_indexed and stages > 1:
         spec = plan.storage_spec((block, block))
         th, tw = plan.supertile_shape((block, block))
@@ -327,7 +345,6 @@ def _write_sharded_impl(m, value, *, block, grid_mode, fractal, storage,
     storage combines the replicated per-device results with a disjoint
     ownership-mask psum (member blocks have exactly one owner, the rest
     pass the input through)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     plan, domain, n, block, storage, tbl, luts = _sharded_setup(
@@ -343,11 +360,11 @@ def _write_sharded_impl(m, value, *, block, grid_mode, fractal, storage,
     lut_specs = tuple(P(axis, None) for _ in luts)
     if storage == "compact":
         a = plan.pad_rows(m, block)
-        out = shard_map(
+        out = jax.shard_map(
             lambda tbl, luts, a: call(tbl.reshape(-1), *luts, a),
             mesh=mesh,
             in_specs=(P(axis, None), lut_specs, P(axis, None)),
-            out_specs=P(axis, None), check_rep=False)(tbl, luts, a)
+            out_specs=P(axis, None), check_vma=False)(tbl, luts, a)
         return plan.unpad_rows(out, block)
 
     def device_fn(tbl, luts, a):
@@ -358,10 +375,10 @@ def _write_sharded_impl(m, value, *, block, grid_mode, fractal, storage,
         return jax.lax.psum(jnp.where(owned, part, 0), axis) \
             + jnp.where(member, 0, a).astype(part.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(P(axis, None), lut_specs, P(None, None)),
-        out_specs=P(None, None), check_rep=False)(tbl, luts, m)
+        out_specs=P(None, None), check_vma=False)(tbl, luts, m)
 
 
 def sierpinski_write(m: jnp.ndarray, value: float = 1.0, *,
@@ -423,7 +440,8 @@ def _sum_kernel(coords, m_ref, o_ref, *, block, n, plan):
     def body():
         mask = _tile_mask(plan, coords.bx, coords.by, block, n)
         tile = jnp.where(mask, m_ref[...], 0).astype(jnp.float32)
-        o_ref[0, 0] += jnp.sum(tile)
+        # a (1, 1) vector add: Mosaic cannot store a scalar into VMEM
+        o_ref[...] += jnp.sum(tile, keepdims=True)
 
     coords.when_valid(body)
 
@@ -443,8 +461,8 @@ def _sum_kernel_dma(coords, m_ref, o_ref, bufs_ref, sems, *, block, n,
 
     def body():
         mask = _tile_mask(plan, coords.bx, coords.by, block, n)
-        o_ref[0, 0] += jnp.sum(
-            jnp.where(mask, tile, 0).astype(jnp.float32))
+        o_ref[...] += jnp.sum(
+            jnp.where(mask, tile, 0).astype(jnp.float32), keepdims=True)
 
     coords.when_valid(body)
 
@@ -457,14 +475,15 @@ def _sum_kernel_gpu(coords, m_ref, o_ref, *, block, n, plan):
     th, tw = plan.supertile_shape((block, block))
     t = plan.linear_step(coords.grid_ids)
     out_idx = (pl.ds(t, 1), pl.ds(0, 1))
-    pl.store(o_ref, out_idx, jnp.zeros((1, 1), jnp.float32))
+    backend_lib.store(o_ref, out_idx, jnp.zeros((1, 1), jnp.float32))
 
     def body():
         iy, ix = plan.storage_index(coords.grid_ids, coords.refs)
-        tile = pl.load(m_ref, (pl.ds(iy * th, th), pl.ds(ix * tw, tw)))
+        tile = backend_lib.load(
+            m_ref, (pl.ds(iy * th, th), pl.ds(ix * tw, tw)))
         mask = _tile_mask(plan, coords.bx, coords.by, block, n)
         part = jnp.sum(jnp.where(mask, tile, 0).astype(jnp.float32))
-        pl.store(o_ref, out_idx, part.reshape(1, 1))
+        backend_lib.store(o_ref, out_idx, part.reshape(1, 1))
 
     coords.when_valid(body)
 
@@ -550,7 +569,6 @@ def _sum_sharded_impl(m, *, block, grid_mode, fractal, storage, n,
     reduces across the axis.  The per-device accumulation order differs
     from the single-device grid order, so results agree to float
     tolerance (exactly, for integer-valued states)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     plan, domain, n, block, storage, tbl, luts = _sharded_setup(
@@ -572,10 +590,10 @@ def _sum_sharded_impl(m, *, block, grid_mode, fractal, storage, n,
         part = finish(call(tbl.reshape(-1), *luts, a))
         return jax.lax.psum(part, axis)
 
-    out = shard_map(
+    out = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(P(axis, None), lut_specs, state_spec),
-        out_specs=P(None, None), check_rep=False)(tbl, luts, a)
+        out_specs=P(None, None), check_vma=False)(tbl, luts, a)
     return out[0, 0]
 
 

@@ -2,10 +2,15 @@
 cell on the production meshes and extract the roofline terms.
 
 MUST be run as a module (python -m repro.launch.dryrun ...) so the
-device-count override below precedes any jax initialization.
+device-count override below precedes any jax initialization.  The run
+pins itself to the CPU: on a machine with a chip, a dry-run child that
+initialized the TPU would take it from the process that owns it.
 """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512")))
 
 import argparse     # noqa: E402
 import json         # noqa: E402

@@ -7,17 +7,25 @@ the single real CPU device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: sharding rules and
+    ``shard_map`` specs steer the partitioner, and untyped ops such as
+    the embedding gather keep working on sharded operands (``Explicit``
+    axes, the ``jax.make_mesh`` default, would demand an out-sharding
+    for each of them)."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 chips per pod ('data' x 'model'); 2 pods when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -25,8 +33,8 @@ def make_host_mesh(model_parallel: int = 1):
     n = jax.device_count()
     if n % model_parallel:
         raise ValueError(f"{n} devices not divisible by tp={model_parallel}")
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return make_mesh((n // model_parallel, model_parallel),
+                     ("data", "model"))
 
 
 def resolve_cli_mesh(spec: str):
@@ -48,4 +56,4 @@ def resolve_cli_mesh(spec: str):
         raise ValueError(
             f"--mesh expects '', 'host' or 'DATAxMODEL' (e.g. '4x2'); "
             f"got {spec!r}") from None
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -154,12 +154,21 @@ class Server:
                 return self._decode(self.params, tok, cache,
                                     jnp.asarray(pos, jnp.int32))
             except GuardExhausted as e:
-                if not self.ladder.step_down(reason=str(e)):
-                    e.report.transitions = list(self.ladder.transitions)
-                    self._write_report(e.report)
-                    raise
-                self.state = ServerState.DEGRADED
-                self._apply_rung(self.ladder.current())
+                self._degrade_or_raise(e)
+
+    def _degrade_or_raise(self, e: GuardExhausted) -> None:
+        """Step down one rung after transient failures exhausted the
+        retries.  A fatal error -- a kernel the compiler refuses, a
+        shape bug -- is re-raised with its report instead: serving it
+        from a lower rung would hide the broken path behind a healthy
+        exit."""
+        if (e.report.classification == "fatal"
+                or not self.ladder.step_down(reason=str(e))):
+            e.report.transitions = list(self.ladder.transitions)
+            self._write_report(e.report)
+            raise e
+        self.state = ServerState.DEGRADED
+        self._apply_rung(self.ladder.current())
 
     def _write_report(self, report) -> Optional[str]:
         if not self.scfg.report_dir:
@@ -377,6 +386,7 @@ class PagedServer:
 
     _guarded = Server._guarded
     _write_report = Server._write_report
+    _degrade_or_raise = Server._degrade_or_raise
     check_substrate = Server.check_substrate
 
     def __init__(self, cfg: ModelConfig, params, scfg: PagedServeConfig,
@@ -560,12 +570,7 @@ class PagedServer:
                 return self._decode(self.params, toks, self.pools,
                                     jnp.asarray(self.table), posv, act)
             except GuardExhausted as e:
-                if not self.ladder.step_down(reason=str(e)):
-                    e.report.transitions = list(self.ladder.transitions)
-                    self._write_report(e.report)
-                    raise
-                self.state = ServerState.DEGRADED
-                self._apply_rung(self.ladder.current())
+                self._degrade_or_raise(e)
 
     def step(self) -> bool:
         """One decode step for every active slot.  Returns False when
@@ -673,6 +678,9 @@ class _null:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="quickstart")
+    ap.add_argument("--smoke", action="store_true",
+                    help="use the arch's reduced smoke config (default: "
+                         "the published widths)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=32)
@@ -735,8 +743,13 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import get_config
+    from repro.launch.cache import enable_compile_cache
     from repro.launch.mesh import resolve_cli_mesh
-    cfg = get_config(args.arch, smoke=True)
+    enable_compile_cache()
+    cfg = get_config(args.arch, smoke=True if args.smoke else None)
+    # serving holds its weights in the compute dtype (bf16 for the
+    # published configs): f32 master weights are a training concern
+    cfg = cfg.replace(param_dtype=cfg.dtype)
     if args.grid_lowering:
         cfg = cfg.replace(grid_lowering=args.grid_lowering)
         print(f"grid lowering: {cfg.grid_mode} "

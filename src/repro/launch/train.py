@@ -267,6 +267,8 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import get_config
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=True if args.smoke else None)
     if args.grid_lowering:
         cfg = cfg.replace(grid_lowering=args.grid_lowering)

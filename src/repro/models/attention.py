@@ -351,7 +351,6 @@ def decode_attention_flash(q, k, v, pos, *, kind="causal", window=0,
     if mesh is None or b % int(mesh.shape[axis]):
         return flash_attention_kernel(q, k, v, seq_pos=pos, **kw)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def device_fn(qd, kd, vd, posd):
@@ -360,10 +359,10 @@ def decode_attention_flash(q, k, v, pos, *, kind="causal", window=0,
     posv = jnp.broadcast_to(
         jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     batched = P(axis, None, None, None)
-    return shard_map(
+    return jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(batched, batched, batched, P(axis)),
-        out_specs=batched, check_rep=False)(q, k, v, posv)
+        out_specs=batched, check_vma=False)(q, k, v, posv)
 
 
 def flash_attention_kernel(*args, **kwargs):
@@ -428,17 +427,16 @@ def decode_attention_paged(q, kv_pool, page_table, pos, *,
     if mesh is None or b % int(mesh.shape[axis]):
         return paged_attention_kernel(q, kv_pool, page_table, posv, **kw)
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def device_fn(qd, pool, ptd, posd):
         return paged_attention_kernel(qd, pool, ptd, posd, **kw)
 
-    return shard_map(
+    return jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(P(axis, None, None, None), P(None, None, None, None),
                   P(axis, None), P(axis)),
-        out_specs=P(axis, None, None, None), check_rep=False)(
+        out_specs=P(axis, None, None, None), check_vma=False)(
             q, kv_pool, page_table.astype(jnp.int32), posv)
 
 

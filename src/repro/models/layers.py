@@ -22,7 +22,9 @@ from . import attention as attn_lib
 
 def dense_init(key, d_in, d_out, dtype, scale=None):
     scale = scale if scale is not None else 1.0 / np.sqrt(d_in)
-    return (jax.random.normal(key, (d_in, d_out), dtype) * scale)
+    # the numpy f64 scale would promote a bf16 draw to f32
+    return (jax.random.normal(key, (d_in, d_out), dtype) * scale
+            ).astype(dtype)
 
 
 def split(key, n):

@@ -33,9 +33,12 @@ def moe_init(key, cfg, dtype=None):
     scale_out = 1.0 / np.sqrt(fe)
     p = {
         "router": dense_init(ks[0], d, e, jnp.float32),
-        "wi": jax.random.normal(ks[1], (e, d, fe), dtype) * scale_in,
-        "wg": jax.random.normal(ks[2], (e, d, fe), dtype) * scale_in,
-        "wo": jax.random.normal(ks[3], (e, fe, d), dtype) * scale_out,
+        "wi": (jax.random.normal(ks[1], (e, d, fe), dtype)
+               * scale_in).astype(dtype),
+        "wg": (jax.random.normal(ks[2], (e, d, fe), dtype)
+               * scale_in).astype(dtype),
+        "wo": (jax.random.normal(ks[3], (e, fe, d), dtype)
+               * scale_out).astype(dtype),
     }
     if cfg.n_shared_experts:
         fs = fe * cfg.n_shared_experts

@@ -547,7 +547,8 @@ def scenario_drop_halo(seed: int, smoke: bool) -> dict:
     from repro.core.domain import make_fractal_domain
     from repro.kernels.sierpinski_ca import ca_run
     n, block, steps = 32, 8, 4
-    mesh = jax.make_mesh((2,), ("data",), devices=jax.devices()[:2])
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2,), ("data",), devices=jax.devices()[:2])
     dom = make_fractal_domain("sierpinski-gasket", n)
     lay = CompactLayout(make_fractal_domain("sierpinski-gasket",
                                             n // block))

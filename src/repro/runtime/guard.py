@@ -42,6 +42,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.errors import JaxRuntimeError
 
 
 # ---------------------------------------------------------------------------
@@ -89,13 +90,14 @@ FATAL_MARKERS = (
     "failed_precondition", "shape", "mosaic", "lowering", "dtype",
 )
 
-
-def _jax_runtime_error() -> type:
-    try:
-        from jax.errors import JaxRuntimeError
-        return JaxRuntimeError
-    except Exception:  # pragma: no cover - ancient jax
-        return RuntimeError
+#: substrings of a compiler's refusal to fit a program into the chip's
+#: memories (HBM, VMEM, SMEM).  Their status is RESOURCE_EXHAUSTED, like
+#: a run-time allocation failure, but the same program is refused again
+#: on every retry: fatal, whatever the exception type.
+COMPILE_REFUSAL_MARKERS = (
+    "compile permanent error", "ran out of memory in memory space",
+    "would exceed memory",
+)
 
 
 def classify_error(exc: BaseException) -> str:
@@ -104,10 +106,11 @@ def classify_error(exc: BaseException) -> str:
     Explicit transient types (:class:`TransientFault`,
     :class:`ValidationError`, :class:`DeadlineExceeded`, timeouts,
     connection errors) are transient.  Python-level programming errors
-    (TypeError/ValueError/KeyError/...) are fatal.  XLA runtime errors
-    are transient *unless* their message carries a compile/shape-family
-    marker; generic RuntimeErrors are fatal unless their message
-    carries a transient-family marker.
+    (TypeError/ValueError/KeyError/...) are fatal, and so is a
+    compile-time memory refusal (:data:`COMPILE_REFUSAL_MARKERS`).  XLA
+    runtime errors are transient *unless* their message carries a
+    compile/shape-family marker; generic RuntimeErrors are fatal unless
+    their message carries a transient-family marker.
     """
     if isinstance(exc, (TransientFault, ValidationError, DeadlineExceeded,
                         TimeoutError, ConnectionError, BrokenPipeError)):
@@ -117,7 +120,9 @@ def classify_error(exc: BaseException) -> str:
                         ZeroDivisionError, AssertionError)):
         return "fatal"
     msg = str(exc).lower()
-    if isinstance(exc, _jax_runtime_error()):
+    if any(m in msg for m in COMPILE_REFUSAL_MARKERS):
+        return "fatal"
+    if isinstance(exc, JaxRuntimeError):
         if any(m in msg for m in FATAL_MARKERS):
             return "fatal"
         return "transient"

@@ -59,14 +59,16 @@ def test_resolution_rules():
     # platform default on CPU is the historical tpu-interpret path
     assert jax.default_backend() == "cpu"
     assert B.resolve(None) is B.TPU_INTERPRET
-    # a native target off its platform auto-emulates...
-    assert B.resolve("tpu") is B.TPU_INTERPRET
-    assert B.resolve("gpu") is B.GPU_INTERPRET
-    # ...unless the caller pins interpret=False (takes responsibility)
+    # a native target stays native off its platform: it fails to lower
+    # on the CPU instead of being emulated unseen
+    assert B.resolve("tpu") is B.TPU
+    assert B.resolve("gpu") is B.GPU
+    assert B.resolve(B.TPU) is B.TPU
     assert not B.resolve("gpu", interpret=False).interpret
     # interpret=True forces emulation; aliases resolve
     assert B.resolve("triton", interpret=True) is B.GPU_INTERPRET
-    assert B.resolve("mosaic") is B.TPU_INTERPRET
+    assert B.resolve("mosaic") is B.TPU
+    assert B.resolve("tpu", interpret=True) is B.TPU_INTERPRET
     assert B.resolve("interpret").interpret
     with pytest.raises(ValueError):
         B.resolve("cuda")
@@ -380,3 +382,23 @@ def test_uncacheable_domain_still_works():
     a = GridPlan(dom, "prefetch_lut").lut_host()
     b = GridPlan(dom, "prefetch_lut").lut_host()
     np.testing.assert_array_equal(a, b)  # rebuilt, but correct
+
+
+def test_prefetch_table_over_smem_is_refused_at_plan_time():
+    """A decode table that would not fit the chip's SMEM is refused
+    while the launch is built, with the limit in the message -- not
+    handed to the compiler (or quietly swapped for another lowering).
+    The compact n=2^16 gasket at block 128 has 19,683 blocks x 28 i32
+    columns: 2.2 MB of table against 1 MiB of SMEM."""
+    n, block = 1 << 16, 128
+    lay = CompactLayout(make_fractal_domain("sierpinski-gasket",
+                                            n // block))
+    m = jax.ShapeDtypeStruct(lay.array_shape(block), jnp.float32)
+    for lowering in ("prefetch_lut", "mma"):
+        with pytest.raises(ValueError, match=str(B.SMEM_TABLE_BYTES)):
+            jax.jit(lambda a, lowering=lowering: sierpinski_write(
+                a, 1.0, block=block, grid_mode=lowering,
+                storage="compact", n=n, coarsen=1, num_stages=1,
+                backend="tpu-interpret")).lower(m)
+    # the embedded table of the same domain (2 columns) rides flat
+    B.check_smem_tables([(19683, 2)])

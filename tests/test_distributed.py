@@ -33,7 +33,8 @@ def test_sharded_train_step_runs_and_matches_single_device():
         tcfg = TrainConfig(steps=3, log_every=100,
                            ckpt_dir="/tmp/rt_mesh_ckpt",
                            optimizer=AdamWConfig(lr=1e-3, total_steps=3))
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         pipe = SyntheticPipeline(DataConfig(vocab_size=cfg.vocab_size,
                                             seq_len=32, global_batch=8))
         tr = Trainer(cfg, tcfg, mesh=mesh)
@@ -89,14 +90,14 @@ def test_compressed_psum_in_shard_map():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.optim.compression import compressed_psum_grads, init_residual
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         grads = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
         res = init_residual(grads)
 
-        @partial(shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P("data", None), P("data", None)),
                  out_specs=(P("data", None), P("data", None)))
         def sync(g, r):

@@ -172,3 +172,47 @@ def test_flash_compact_equals_bounding():
     b = ops.flash_attention(q, k, v, kind="causal", block_q=64, block_k=64,
                             grid_mode="bounding")
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# chip semantics: the TPU-faithful interpreter
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("num_stages", [1, 2])
+def test_bounding_grid_fills_non_member_blocks(chip_semantics, num_stages):
+    """Under ``bounding`` every block of the box is a grid step and its
+    output block is written back; the non-member steps must fill it
+    (input for write, stale buffer for CA) or the chip writes stale
+    VMEM over them."""
+    n, block = 32, 8
+    m = jnp.asarray(RNG.integers(0, 4, (n, n)).astype(np.float32))
+    kw = dict(block=block, grid_mode="bounding", coarsen=1,
+              num_stages=num_stages)
+    out = ops.sierpinski_write(m, 7.0, **kw)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(ref.sierpinski_write_ref(m, 7.0)))
+    s = _fractal_state(n, jnp.float32, binary=True)
+    want = s
+    for _ in range(3):
+        want = ref.ca_step_ref(want, "parity")
+    got = ops.ca_run(s, jnp.zeros_like(s), 3, fuse=2, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_bounding_over_compact_storage_is_refused_on_the_chip():
+    """Its non-member steps map onto member slots, and the chip writes
+    every visited output block back: refused for the native target
+    while the launch is built (lowering it here needs no chip)."""
+    import jax
+    from repro.core.compact import CompactLayout
+    from repro.core.domain import make_fractal_domain
+    n, block = 32, 8
+    shape = CompactLayout(make_fractal_domain(
+        "sierpinski-gasket", n // block)).array_shape(block)
+    a = jax.ShapeDtypeStruct(shape, jnp.float32)
+    kw = dict(block=block, grid_mode="bounding", storage="compact", n=n,
+              coarsen=1, num_stages=1, backend="tpu")
+    with pytest.raises(ValueError, match="bounding"):
+        jax.jit(lambda m: ops.sierpinski_write(m, 1.0, **kw)).lower(a)
+    with pytest.raises(ValueError, match="bounding"):
+        jax.jit(lambda x, y: ops.ca_run(x, y, 2, fuse=1, **kw)).lower(a, a)

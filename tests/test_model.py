@@ -140,3 +140,20 @@ def test_param_count_analytic_close_to_actual():
     actual = sum(np.prod(l.shape) for l in jax.tree.leaves(p))
     analytic = cfg.param_count()
     assert abs(actual - analytic) / actual < 0.05
+
+
+@pytest.mark.parametrize("arch", ["phi3-mini-3.8b", "deepseek-v2-236b",
+                                  "falcon-mamba-7b", "zamba2-2.7b"])
+def test_param_leaves_honour_param_dtype(arch):
+    """bf16 weights stay bf16: a numpy f64 init scale must not promote
+    them (phi3-mini at published widths would then need 15 GB of f32
+    weights and not fit a 16 GB chip).  MoE routers alone are f32, by
+    design: routing scores are taken in f32."""
+    from repro.configs import get_config
+    from repro.models.model import abstract_init
+    cfg = get_config(arch, smoke=True).replace(param_dtype="bfloat16")
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+            abstract_init(cfg)):
+        name = jax.tree_util.keystr(path)
+        want = jnp.float32 if name.endswith("['router']") else jnp.bfloat16
+        assert leaf.dtype == want, (name, leaf.dtype)

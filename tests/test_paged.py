@@ -182,7 +182,8 @@ def test_paged_decode_slot_sharded_bit_identical():
         pool = P.write_prefill_pages(pool, jnp.asarray(table[i]), k, v)
     table = jnp.asarray(table)
     pos = jnp.asarray([17, 31, 5, 24], jnp.int32)
-    mesh = jax.make_mesh((4,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("data",))
     want = A.decode_attention_paged(q, pool, table, pos)
     got = A.decode_attention_paged(q, pool, table, pos, mesh=mesh,
                                    shard_axis="data")
@@ -244,7 +245,8 @@ def test_zigzag_flash_sharding_bit_identical():
     q = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, h, s, d)), jnp.float32)
-    mesh = jax.make_mesh((4,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("data",))
     for gm in ("closed_form", "prefetch_lut", "bounding", "mma"):
         kw = dict(kind="causal", block_q=16, block_k=16, grid_mode=gm)
         want = ops.flash_attention(q, k, v, **kw)

@@ -49,7 +49,8 @@ def _fake_mesh(D):
     only reads ``mesh.shape[axis]``."""
     import jax
     if jax.device_count() >= D:
-        return jax.make_mesh((D,), ("data",))
+        from repro.launch.mesh import make_mesh
+        return make_mesh((D,), ("data",))
     import types
     return types.SimpleNamespace(shape={"data": D})
 
@@ -334,7 +335,8 @@ def test_sharded_overlap_impulse_bit_identical():
                                             n // block))
     checked = 0
     for D in (2, 8):
-        mesh = jax.make_mesh((D,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((D,), ("data",))
         for gm in ("closed_form", "prefetch_lut", "bounding"):
             for storage in ("compact", "embedded"):
                 a = lay.pack(a0, block) if storage == "compact" else a0

@@ -58,6 +58,18 @@ def test_classify_error_taxonomy():
     assert classify_error(RuntimeError("RESOURCE_EXHAUSTED: oom")) == \
         "transient"
     assert classify_error(RuntimeError("boom")) == "fatal"
+    # a run-time allocation failure may clear; a compiler's refusal to
+    # fit the program into HBM / SMEM fails the same way on every retry
+    assert classify_error(JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer")) == \
+        "transient"
+    assert classify_error(JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+        "memory in memory space hbm. Used 28.55G of 15.75G hbm.")) == \
+        "fatal"
+    assert classify_error(JaxRuntimeError(
+        "RESOURCE_EXHAUSTED: Allocation (size=1122304) would exceed "
+        "memory (size=1048576) :: 'prefetched SMEM operand 0'")) == "fatal"
     # programming errors never retry
     assert classify_error(ValueError("shape")) == "fatal"
     assert classify_error(TypeError()) == "fatal"
@@ -476,6 +488,36 @@ def test_server_degradation_ladder_blockspace_to_xla():
     assert np.array_equal(out, want)       # served stream == xla run
     assert any(e["kind"] == "degrade" for e in faulty.events
                if isinstance(e, dict))
+
+
+def test_server_compile_refusal_is_fatal_not_degraded(tmp_path):
+    """A decode step the compiler refuses (here: an HBM overrun) must
+    end the run with a fatal report, not serve on from the xla rung."""
+    from jax.errors import JaxRuntimeError
+    from repro.launch.serve import ServeConfig, Server
+    scfg = ServeConfig(max_len=16, retries=3, backoff_base_s=0.0,
+                       report_dir=str(tmp_path))
+    cfg, params, _ = _server(scfg)
+    server = Server(cfg.replace(attn_decode_kernel="blockspace"), params,
+                    scfg)
+    assert len(server.ladder.rungs) > 1
+    calls = []
+
+    def refused(*args):
+        calls.append(1)
+        raise JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out "
+            "of memory in memory space hbm. Used 28.55G of 15.75G hbm.")
+
+    server._decode_fn = refused
+    prompts = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 4))
+    with pytest.raises(GuardExhausted):
+        server.generate(prompts, max_new=4)
+    assert len(calls) == 1                 # no retry
+    assert server.ladder.level == 0 and not server.ladder.transitions
+    assert server.state == ServerState.HEALTHY
+    rep = json.load(open(tmp_path / "failure_serve_decode.json"))
+    assert rep["classification"] == "fatal"
 
 
 def test_server_ladder_exhausted_writes_failure_report(tmp_path):

@@ -70,7 +70,8 @@ def test_sharded_ca_bit_identical_all_lowerings_and_storages():
                                             n // block))
     checked = 0
     for D in (2, 3, 4):
-        mesh = jax.make_mesh((D,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((D,), ("data",))
         for gm in ("closed_form", "prefetch_lut", "bounding", "mma"):
             for storage in ("embedded", "compact"):
                 for rule, fuse, coarsen in (("parity", 3, 1),
@@ -104,7 +105,8 @@ def test_sharded_ca_larger_domain_uneven_rows():
     a = fractal_state(n, True); b = jnp.zeros_like(a)
     ap, bp = lay.pack(a, block), lay.pack(b, block)
     for D in (2, 8):
-        mesh = jax.make_mesh((D,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((D,), ("data",))
         for fuse, coarsen in ((4, 1), (2, 2)):
             kw = dict(fuse=fuse, rule="parity", block=block,
                       grid_mode="closed_form", storage="compact", n=n,
@@ -131,7 +133,8 @@ def test_sharded_ca_generalized_fractal():
                     .astype(np.float32))
     b = jnp.zeros_like(a)
     ap, bp = lay.pack(a, block), lay.pack(b, block)
-    mesh = jax.make_mesh((3,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((3,), ("data",))
     for gm in ("closed_form", "prefetch_lut"):
         for storage, (x0, y0) in (("embedded", (a, b)),
                                   ("compact", (ap, bp))):
@@ -156,7 +159,8 @@ def test_halo_impulse_crosses_shard_boundary():
     n, block, steps = 32, 8, 6
     dom = make_fractal_domain("sierpinski-gasket", n // block)
     lay = CompactLayout(dom)
-    mesh = jax.make_mesh((2,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2,), ("data",))
     plan = ShardedPlan(dom, "closed_form", storage="compact",
                        mesh=mesh, axis="data", halo=True)
     coords = dom.coords_host()
@@ -193,7 +197,8 @@ def test_sharded_write_and_sum():
     m = fractal_state(n, False)
     mp = lay.pack(m, block)
     for D in (2, 3):
-        mesh = jax.make_mesh((D,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((D,), ("data",))
         for gm in ("closed_form", "prefetch_lut", "bounding"):
             for storage, arr in (("embedded", m), ("compact", mp)):
                 for coarsen in (1, 2):
@@ -216,7 +221,8 @@ def test_sharded_flash_attention_query_axis():
     out = run_sub(_PRELUDE + """
     rng = np.random.default_rng(0)
     b, h, d = 1, 2, 16
-    mesh = jax.make_mesh((4,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("data",))
     for kind, sq, sk, window in (("causal", 128, 128, 0),
                                  ("local", 128, 128, 32),
                                  ("local", 64, 128, 32),
@@ -235,8 +241,8 @@ def test_sharded_flash_attention_query_axis():
     q = jnp.zeros((1, 1, 48, 8), jnp.float32)
     try:
         ops.flash_attention(q, q, q, kind="causal", block_q=16,
-                            block_k=16, mesh=jax.make_mesh((8,),
-                                                           ("data",)))
+                            block_k=16, mesh=make_mesh((8,),
+                                                       ("data",)))
         raise SystemExit("expected ValueError")
     except ValueError as e:
         assert "divisible" in str(e)
@@ -255,7 +261,8 @@ def _fake_mesh(D):
     testable without D real devices."""
     import jax
     if jax.device_count() >= D:
-        return jax.make_mesh((D,), ("data",))
+        from repro.launch.mesh import make_mesh
+        return make_mesh((D,), ("data",))
     import types
     return types.SimpleNamespace(shape={"data": D})
 
@@ -421,7 +428,8 @@ def test_tune_keys_qualified_by_shard_count():
     base = {"fractal": "sierpinski-gasket", "n": 32, "block": 8,
             "rule": "parity"}
     assert tune.shard_params(base, None, "data") == base
-    mesh2 = jax.make_mesh((2,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh2 = make_mesh((2,), ("data",))
     assert tune.shard_params(base, mesh2, "data")["devices"] == 2
     # behavioral: auto resolves per key
     cache = tune.default_cache()
@@ -434,7 +442,8 @@ def test_tune_keys_qualified_by_shard_count():
     assert ca.auto_schedule(n=32, block=8)[0] == "bounding"
     assert ca.auto_schedule(n=32, block=8, mesh=mesh2) == \\
         ("prefetch_lut", 4, 1, 1)
-    mesh4 = jax.make_mesh((4,), ("data",))  # untuned D: defaults
+    from repro.launch.mesh import make_mesh
+    mesh4 = make_mesh((4,), ("data",))  # untuned D: defaults
     assert ca.auto_schedule(n=32, block=8, mesh=mesh4) == \\
         ("closed_form", 1, 1, 1)
     print("OK")

@@ -57,3 +57,41 @@ def test_loss_improves_on_learnable_data(tmp_path):
     _, _, hist = Trainer(cfg, tcfg).run(pipe)
     assert np.mean([h["loss"] for h in hist[-5:]]) < \
         np.mean([h["loss"] for h in hist[:5]])
+
+
+def test_compile_cache_env_var_configures_nothing_else(monkeypatch,
+                                                       tmp_path):
+    import jax
+    from repro.launch import cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(cache.ENV, str(tmp_path))
+    assert cache.enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_fixed_inside_checkout(monkeypatch):
+    import os
+    import subprocess
+    import sys
+    import jax
+    from repro.launch import cache
+    monkeypatch.delenv(cache.ENV, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = cache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert cache.enable_compile_cache() == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")
+    # another process (another pid, time and temporary directory)
+    # derives the same path, so it finds what this one compiled
+    env = dict(os.environ, TMPDIR=os.path.join(root, "src"))
+    other = subprocess.run(
+        [sys.executable, "-c", "from repro.launch.cache import "
+         "CHECKOUT_DIR; print(CHECKOUT_DIR)"], env=env,
+        capture_output=True, text=True, timeout=120, check=True)
+    assert other.stdout.strip() == path
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
