@@ -12,10 +12,13 @@ Phases (one process, any failure exits non-zero):
    bounding, and the table lowerings prefetch_lut / mma) on the embedded
    f32 n=2^14 gasket, compact ``sierpinski_write`` at n=2^16, fused
    ``ca_run`` (parity, fuse=8) at embedded n=2^13 and compact n=2^16,
-   each against ``repro.kernels.ref``.
+   each against ``repro.kernels.ref``.  The compact runs take
+   closed_form and bounding, whose non-member steps revisit the block
+   of the member step before them.
 3. serve -- ``PagedServer`` on phi3-mini-3.8b at its published widths
    (bf16 weights from seed 0) with the block-space paged decode kernel,
-   then one paged decode step against ``logits_fn``'s full forward.
+   then one paged decode step against ``logits_fn``'s full forward, and
+   every token the server chose against the forward over its request.
 
 ``--four-chips`` runs only the halo-sharded compact ``ca_run`` on a
 4-device mesh and compares it bit for bit with the same run on one
@@ -40,6 +43,14 @@ import time
 #: 5% leaves room for the chip's own rounding and still fails a real
 #: error (a wrong page, position or mask moves the logits by O(1)).
 LOGITS_REL_TOL = 5e-2
+#: The served stream is held against the forward too: every greedy
+#: token must be the forward's argmax at its position, or lie within
+#: STREAM_MARGIN times the single step's max |decode - forward| logit
+#: difference of it.  The two paths can only pick different tokens
+#: where the forward's top logits are that close (bf16 near-ties); a
+#: wrong page, position or slot in the batched server moves a chosen
+#: token's logit O(1) below the maximum.
+STREAM_MARGIN = 8.0
 
 
 def log(msg: str) -> None:
@@ -122,7 +133,8 @@ def _window_blocks(layout, n, m, block):
             slots[inw, 1], slots[inw, 0])
 
 
-def _ca_window_check(n, m, block, steps, fuse, seed, **kw):
+def _ca_window_check(n, m, block, steps, fuse, seed,
+                     grid_mode="closed_form", **kw):
     """Fused compact ``ca_run`` at size n, checked on a window.
 
     The packed state holds random parity bits only inside the
@@ -180,15 +192,15 @@ def _ca_window_check(n, m, block, steps, fuse, seed, **kw):
     a = to_packed(win0)
     b = jnp.zeros_like(a)
     out = ops.ca_run(a, b, steps, fuse=fuse, rule="parity", block=block,
-                     grid_mode="closed_form", storage="compact", n=n,
+                     grid_mode=grid_mode, storage="compact", n=n,
                      coarsen=1, **kw)
     got = from_packed(out)
     want = ref_run(win0)
     check(float(jnp.sum(want)) > 0, "CA window evolved to all zeros")
-    check(jnp.array_equal(got, want),
-          f"compact ca_run n={n} differs from ca_step_ref on the window")
+    check(jnp.array_equal(got, want), f"compact ca_run {grid_mode} "
+          f"n={n} differs from ca_step_ref on the window")
     check(float(jnp.sum(out)) == float(jnp.sum(got)),
-          f"compact ca_run n={n} wrote outside the seeded window")
+          f"compact ca_run {grid_mode} n={n} wrote outside the window")
     return out
 
 
@@ -234,15 +246,17 @@ def phase_kernels(*, n_embed: int = 1 << 14, n_ca: int = 1 << 13,
                                             n_compact // block))
     p = jax.random.randint(key, lay.array_shape(block), 0, 4).astype(
         jnp.float32)
-    with Timer(f"compact sierpinski_write {lay.array_shape(block)}"):
-        out = ops.sierpinski_write(p, 7.0, block=block,
-                                   grid_mode="closed_form",
-                                   storage="compact", n=n_compact,
-                                   coarsen=1, num_stages=1)
-        check(jnp.array_equal(out, _compact_write_ref(p, 7.0, lay,
-                                                      n_compact, block)),
-              "compact sierpinski_write differs from the gasket bit test")
-    del p, out
+    want = _compact_write_ref(p, 7.0, lay, n_compact, block)
+    for gm in ("closed_form", "bounding"):
+        with Timer(f"compact sierpinski_write {gm} "
+                   f"{lay.array_shape(block)}"):
+            out = ops.sierpinski_write(p, 7.0, block=block, grid_mode=gm,
+                                       storage="compact", n=n_compact,
+                                       coarsen=1, num_stages=1)
+            check(jnp.array_equal(out, want), f"compact sierpinski_write "
+                  f"{gm} differs from the gasket bit test")
+        del out
+    del p, want
 
     log(f"kernels: fused ca_run parity fuse={fuse}")
     with Timer(f"embedded ca_run n={n_ca}, {ca_steps} steps"):
@@ -259,9 +273,11 @@ def phase_kernels(*, n_embed: int = 1 << 14, n_ca: int = 1 << 13,
         check(jnp.array_equal(got, want),
               "embedded ca_run differs from ca_step_ref")
     del s0, want, got
-    with Timer(f"compact ca_run n={n_compact}, {compact_steps} steps"):
-        _ca_window_check(n_compact, n_ca, block, compact_steps, fuse,
-                         seed, num_stages=1)
+    for gm in ("closed_form", "bounding"):
+        with Timer(f"compact ca_run {gm} n={n_compact}, "
+                   f"{compact_steps} steps"):
+            _ca_window_check(n_compact, n_ca, block, compact_steps, fuse,
+                             seed, grid_mode=gm, num_stages=1)
 
 
 # ---------------------------------------------------------------------------
@@ -366,6 +382,58 @@ def phase_serve(cfg, *, num_slots: int = 4, page_size: int = 16,
         check(np.isfinite(d).all(), "non-finite decode logits")
         check(rel <= LOGITS_REL_TOL,
               f"decode logits off the forward by {rel:.3e}")
+    _check_stream(cfg, params, requests, done, max_new,
+                  STREAM_MARGIN * float(np.max(np.abs(d - f))))
+
+
+def _check_stream(cfg, params, requests, done, max_new, margin):
+    """Each request's greedy tokens, as the server's continuous batching
+    chose them (its own page tables, page growth and slot refill),
+    against one ``logits_fn`` forward over prompt + generated tokens:
+    the chosen token's forward logit must lie within ``margin`` of the
+    forward's maximum at its position.
+
+    The maximum and the chosen logit are taken on the host, from one
+    copy of the logits.  Inside one jit the chip's compiler may take
+    the maximum over the bf16 logits and the chosen value from an f32
+    copy it never rounded (excess precision), so no gap would be 0."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.models import model as model_lib
+
+    @partial(jax.jit, static_argnames=("start",))
+    def window(params, toks, start):
+        logits, _ = model_lib.logits_fn(params, toks, cfg=cfg)
+        return logits[:, start:start + max_new].astype(jnp.float32)
+
+    def gaps(params, toks, start):
+        sl = np.asarray(window(params, jnp.asarray(toks), start=start))
+        chosen = np.take_along_axis(
+            sl, toks[:, start + 1:start + 1 + max_new, None], axis=-1)[..., 0]
+        return sl.max(axis=-1) - chosen
+
+    by_len = {}
+    for rid, prompt in enumerate(requests):
+        by_len.setdefault(len(prompt), []).append(rid)
+    worst, exact, total = 0.0, 0, 0
+    with Timer(f"served tokens vs forward ({len(requests)} requests)"):
+        for plen, rids in sorted(by_len.items()):
+            toks = np.stack([np.concatenate([requests[r], done[r]])
+                             for r in rids]).astype(np.int32)
+            g = gaps(params, toks, plen - 1)
+            check(np.isfinite(g).all(), "non-finite forward logits")
+            worst = max(worst, float(g.max()))
+            exact += int(np.sum(g == 0))
+            total += g.size
+        log(f"  {exact}/{total} served tokens are the forward's argmax; "
+            f"largest gap {worst:.3e} (margin {margin:.3e})")
+        check(worst <= margin,
+              f"a served token is {worst:.3e} below the forward's best "
+              f"logit (margin {margin:.3e})")
 
 
 # ---------------------------------------------------------------------------

@@ -110,6 +110,11 @@ class BackendTarget:
                          Both structures carry the flag (TPUs have the
                          MXU, GPUs tensor cores); a scalar-only target
                          would clear it.
+    smem_table_bytes:    SMEM the scalar-prefetch tables of one launch
+                         may fill together, or None where nothing bounds
+                         them (the interpreter, the gpu structure).
+                         Tables over it are refused when the launch is
+                         traced (:func:`check_smem_tables`).
     """
 
     name: str
@@ -124,6 +129,7 @@ class BackendTarget:
     async_copy: bool
     pipeline_stages: int
     prefers_mma: bool
+    smem_table_bytes: Optional[int]
 
     # -- variants -----------------------------------------------------------
 
@@ -205,6 +211,13 @@ class BackendTarget:
         return {}
 
 
+#: SMEM a kernel's scalar-prefetch tables may fill together: the 1 MiB
+#: of a TPU v5e core, less 16 KiB kept for the compiler's own scalars.
+#: Each table is placed in whole 4 KiB pages.
+SMEM_TABLE_BYTES = (1 << 20) - (16 << 10)
+_SMEM_PAGE = 4096
+
+
 def _mk(name, kind, interpret):
     tpu = kind == "tpu"
     return BackendTarget(
@@ -216,7 +229,10 @@ def _mk(name, kind, interpret):
         # the -interpret variants keep them so the pipelined paths are
         # exercised (and parity-tested) without the hardware.
         async_copy=tpu, pipeline_stages=2 if tpu else 4,
-        prefers_mma=True)
+        prefers_mma=True,
+        # the chip's budget; the interpreter keeps tables in host memory
+        smem_table_bytes=SMEM_TABLE_BYTES if tpu and not interpret
+        else None)
 
 
 TPU = _mk("tpu", "tpu", False)
@@ -369,11 +385,6 @@ def full_spec(shape) -> pl.BlockSpec:
 # scalar-prefetch tables in SMEM
 # ---------------------------------------------------------------------------
 
-#: SMEM a kernel's scalar-prefetch tables may fill together: the 1 MiB
-#: of a TPU v5e core, less 16 KiB kept for the compiler's own scalars.
-#: Each table is placed in whole 4 KiB pages.
-SMEM_TABLE_BYTES = (1 << 20) - (16 << 10)
-_SMEM_PAGE = 4096
 
 
 class RowMajor:
@@ -394,20 +405,20 @@ class RowMajor:
         return self.ref[i * self.shape[1] + j]
 
 
-def check_smem_tables(shapes, plan=None) -> None:
-    """Refuse scalar-prefetch tables that would not fit SMEM, naming
-    the limit, before anything is lowered.  ``shapes`` are the i32
-    table shapes of one launch."""
+def check_smem_tables(shapes, plan=None,
+                      limit: int = SMEM_TABLE_BYTES) -> None:
+    """Refuse scalar-prefetch tables that would not fit ``limit`` bytes
+    of SMEM, naming the limit, before anything is lowered.  ``shapes``
+    are the i32 table shapes of one launch."""
     need = sum(-(-4 * int(np.prod(s)) // _SMEM_PAGE) * _SMEM_PAGE
                for s in shapes)
-    if need > SMEM_TABLE_BYTES:
+    if need > limit:
         what = (f"{plan.lowering} plan over {type(plan.domain).__name__}"
                 f" ({plan.storage} storage)" if plan is not None
                 else "launch")
         raise ValueError(
             f"scalar-prefetch tables {list(shapes)} of this {what} need "
-            f"{need} B of SMEM; the limit is {SMEM_TABLE_BYTES} B "
-            f"(1 MiB per TPU v5e core less 16 KiB).  Use a lowering "
+            f"{need} B of SMEM; the limit is {limit} B.  Use a lowering "
             f"without a table (closed_form, bounding) or a coarser "
             f"block for this size.")
 
@@ -531,7 +542,8 @@ def emit(plan, kernel: Callable, *, in_specs, out_specs, out_shape,
             # only at call time (sharded chunks arrive pre-split by
             # shard_map), so the call is built per trace
             shapes = [tuple(t.shape) for t in args[:nsp]]
-            check_smem_tables(shapes, plan)
+            if target.smem_table_bytes is not None:
+                check_smem_tables(shapes, plan, target.smem_table_bytes)
 
             def views(refs):
                 return tuple(RowMajor(r, shp) if len(shp) == 2 else r

@@ -60,6 +60,19 @@ class BlockDomain:
         """Membership test in the embedded block space (traceable)."""
         raise NotImplementedError
 
+    def nearest_row_member(self, bx, by):
+        """The member of row ``by`` at or before ``bx`` in row-major
+        order, or the row's first member when none precedes ``bx``
+        (traceable; the identity on members).  Each run of non-members
+        between two members of the row-major sweep maps onto those two
+        members only, so under the ``bounding`` grid over compact
+        storage a skipped step revisits the block of the member step
+        next to it in grid order."""
+        if self.always_member:
+            return bx, by
+        raise NotImplementedError(
+            f"{self.name} domain cannot name its row members")
+
     def cell_member(self, gx, gy, n: int):
         """Cell-level membership of the embedded n x n grid (traceable);
         only meaningful for domains with intra-block structure (fractals).
@@ -163,6 +176,9 @@ class SierpinskiDomain(BlockDomain):
     def contains(self, bx, by):
         return F.is_member(bx, by, self.n_b)
 
+    def nearest_row_member(self, bx, by):
+        return F.SIERPINSKI.nearest_row_member(bx, by, self.r_b)
+
     def cell_member(self, gx, gy, n: int):
         return F.is_member(gx, gy, n)
 
@@ -201,6 +217,9 @@ class GeneralizedFractalDomain(BlockDomain):
         # digit test replaces the dense membership_grid(n_b) this used to
         # rebuild on every (traced) call
         return self.spec.is_member(bx, by, self.n_b)
+
+    def nearest_row_member(self, bx, by):
+        return self.spec.nearest_row_member(bx, by, self.r_b)
 
     def cell_member(self, gx, gy, n: int):
         return self.spec.is_member(gx, gy, n)
@@ -269,6 +288,9 @@ class TriangularDomain(BlockDomain):
 
     def contains(self, bx, by):
         return bx <= by
+
+    def nearest_row_member(self, bx, by):
+        return (np.minimum if _is_host(bx) else jnp.minimum)(bx, by), by
 
 
 class BandDomain(BlockDomain):
@@ -354,6 +376,12 @@ class BandDomain(BlockDomain):
 
     def contains(self, bx, by):
         return (bx <= by + self.off) & (bx > by + self.off - self.w)
+
+    def nearest_row_member(self, bx, by):
+        clip = np.clip if _is_host(bx) else jnp.clip
+        lo = (np.maximum if _is_host(bx) else jnp.maximum)(
+            by + self.off - self.w + 1, 0)
+        return clip(bx, lo, by + self.off), by
 
 
 def make_fractal_domain(fractal: str, n_b: int) -> BlockDomain:

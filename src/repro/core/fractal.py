@@ -291,6 +291,54 @@ class FractalSpec:
             i = i + c * self.k ** (mu - 1)
         return i
 
+    def nearest_row_member(self, x, y, r: int):
+        """The member of row ``y`` at or before ``x`` in row-major order,
+        or the row's first member when none precedes ``x`` (traceable).
+
+        Digit by digit from the top: the candidate that keeps the
+        longest member prefix of ``x``, lowers the next digit to the
+        largest allowed one below it and fills the digits under it with
+        their largest allowed values.  Every row must hold a member."""
+        rows = {dy: sorted(ox for ox, oy in self.offsets if oy == dy)
+                for dy in range(self.m)}
+        if not all(rows.values()):
+            raise ValueError(f"{self.name}: some rows hold no member")
+        host = all(isinstance(v, (int, np.integer, np.ndarray))
+                   for v in (x, y))
+        where = np.where if host else jnp.where
+
+        def pick(dy, fn):   # per-row-digit static value as a select chain
+            v = dy * 0 + fn(rows[0])
+            for d in range(1, self.m):
+                v = where(dy == d, fn(rows[d]), v)
+            return v
+
+        dxs = [(x // self.m ** mu) % self.m for mu in range(r)]
+        dys = [(y // self.m ** mu) % self.m for mu in range(r)]
+        low_max = [pick(dys[mu], max) * self.m ** mu for mu in range(r)]
+        out = sum((pick(dys[mu], min) * self.m ** mu for mu in range(r)),
+                  x * 0)
+        prefix_ok, prefix = True, x * 0
+        for lvl in reversed(range(r)):
+            below = x * 0 - 1
+            for d in range(self.m - 1):     # ascending: the largest wins
+                allowed = None
+                for oy in (oy for ox, oy in self.offsets if ox == d):
+                    hit = dys[lvl] == oy
+                    allowed = hit if allowed is None else allowed | hit
+                if allowed is not None:
+                    below = where(allowed & (d < dxs[lvl]), d, below)
+            cand = prefix + below * self.m ** lvl + sum(low_max[:lvl],
+                                                         x * 0)
+            out = where(prefix_ok & (below >= 0), cand, out)
+            ok = None
+            for ox, oy in self.offsets:
+                hit = (dxs[lvl] == ox) & (dys[lvl] == oy)
+                ok = hit if ok is None else ok | hit
+            prefix_ok = prefix_ok & ok
+            prefix = prefix + dxs[lvl] * self.m ** lvl
+        return where(prefix_ok, x, out), y
+
     def orthotope_shape(self, r: int) -> Tuple[int, int]:
         """Packing orthotope (width_x, height_y): k**floor(r/2) wide by
         k**ceil(r/2) tall (Lemma 2 generalized to F^{k,s})."""

@@ -527,6 +527,10 @@ class GridPlan:
                     swap=bool(swap))
                 return sy, sx
         _, bx, by = self._decode(grid_ids, refs)
+        if self.lowering == "bounding":
+            # a skipped step revisits the block of the member step next
+            # to it in grid order (see check_output_writeback)
+            bx, by = self.sched_domain.nearest_row_member(bx, by)
         if self._tiling is not None:
             tx, ty = self._tiling.tile_index(bx, by)
             return ty, tx
@@ -602,25 +606,27 @@ class GridPlan:
         (``coords.valid`` false).  The chip writes every visited output
         block back without reading it first.  Under ``bounding`` over
         embedded storage each skipped step owns its block, so the
-        kernel passes it through.  The other skipped steps (the padding
-        of sharded enumerations) revisit the block of the step before
-        them, which must be left as that step wrote it."""
+        kernel passes it through.  The other skipped steps revisit the
+        block of a member step next to them in grid order (compact
+        storage under ``bounding``, the padding of sharded
+        enumerations), which must be left as that step writes it."""
         return self.lowering == "bounding" and self.storage == "embedded"
 
     def check_output_writeback(self) -> None:
-        """Refuse, on the chip, a plan whose skipped steps write back
-        over blocks that member steps own: ``bounding`` over compact
-        storage maps non-member blocks onto member slots.  The
-        interpreter reads each output block before a step, so it keeps
-        such plans (the lowering equivalence tests use them)."""
-        if (self.lowering == "bounding" and self.storage == "compact"
-                and self.target.block_indexed
-                and not self.target.interpret):
-            raise ValueError(
-                "grid_mode='bounding' with storage='compact' cannot run "
-                "on the chip: non-member steps map onto member slots and "
-                "every visited output block is written back; use "
-                "closed_form, prefetch_lut or mma with compact storage")
+        """Refuse, on every target, a plan whose skipped steps would
+        write back over blocks that member steps own.  ``bounding`` over
+        compact storage sends each non-member step to the slot of the
+        member step next to it in grid order
+        (:meth:`~repro.core.domain.BlockDomain.nearest_row_member`), so
+        its domain must be able to name that member."""
+        if self.lowering == "bounding" and self.storage == "compact":
+            try:
+                self.sched_domain.nearest_row_member(0, 0)
+            except NotImplementedError as e:
+                raise ValueError(
+                    f"grid_mode='bounding' with storage='compact' needs "
+                    f"a domain that names its row members ({e}); use "
+                    f"closed_form, prefetch_lut or mma") from None
 
     # -- in-kernel accessor -------------------------------------------------
 

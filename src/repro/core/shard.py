@@ -823,6 +823,18 @@ class ShardedPlan(GridPlan):
         return (li >= sref[SHARD_LO]) \
             & (li < sref[SHARD_LO] + sref[SHARD_COUNT])
 
+    def check_output_writeback(self) -> None:
+        """Refuse ``bounding`` over compact storage on every target: a
+        device's skipped steps (non-members, and members of other
+        devices' slabs) have no owned member next to them in grid
+        order, so their write-back would land on an owned slot."""
+        if self.lowering == "bounding" and self.storage == "compact":
+            raise ValueError(
+                "grid_mode='bounding' with storage='compact' cannot be "
+                "sharded: skipped steps would write back over owned "
+                "slots; use closed_form, prefetch_lut or mma with "
+                "compact storage, or embedded storage")
+
     # -- storage-array tile indices (local slab addressing) ------------------
 
     def storage_index(self, grid_ids, refs=()):

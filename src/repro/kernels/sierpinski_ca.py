@@ -132,17 +132,25 @@ def _trapezoid_update(tiles, bx, by, steps, *, rule, alpha, block, n,
     plan's storage-supertile shape.  ``(bx, by)``: scheduled (coarse)
     block coords.
 
-    Everything here is a static slice, a concatenation, a roll or an
-    elementwise op, which Mosaic lowers.  The halo ring is widened to
-    the (8, 128) vreg tiling -- ``hr`` rows, ``hc`` columns -- so every
-    concatenated piece and the final interior slice stay tile-aligned;
-    the wider ring changes nothing inside, because after ``steps <=
-    halo`` iterations only the outer ``steps`` rings are stale."""
+    Everything here is a static slice, a concatenation, a select, a
+    roll or an elementwise op, which Mosaic lowers.  The row halo is
+    widened to the 8-row sublane tiling (``hr`` rows above and below).
+    The column halo is one lane-aligned pad group of ``pw`` columns
+    after the interior, read as a ring (the rolls are cyclic): the
+    right neighbour's first columns at its start, the left
+    neighbour's last columns at its end, so every piece and the final
+    interior slice stay tile-aligned.  Whatever sits between them
+    changes nothing inside, because after ``steps <= halo`` iterations
+    only the outer ``steps`` cells of each halo are stale."""
     domain = plan.domain
     span = plan.coarsen * block        # embedded superblock side, cells
     hr = min(span, _round_up(halo, 8))
-    hc = min(span, _round_up(halo, 128))
-    wr, wc = span + 2 * hr, span + 2 * hc   # working (trapezoid base)
+    # both column halos in one pad group: 128 lanes while they fit,
+    # else the whole left and right neighbours side by side
+    pw = _round_up(2 * halo, 128)
+    if pw > span:
+        pw = 2 * span
+    wr, wc = span + 2 * hr, span + pw   # working (trapezoid base)
     tm = plan.tile_map()
 
     def fine(t, r, c):
@@ -172,16 +180,26 @@ def _trapezoid_update(tiles, bx, by, steps, *, rule, alpha, block, n,
     # strip geometry: which rows/cols of a neighbour's embedded view
     # land in the padded working array (relative offset -1/0/+1)
     rows = {-1: slice(span - hr, span), 0: slice(0, span), 1: slice(0, hr)}
-    cols = {-1: slice(span - hc, span), 0: slice(0, span), 1: slice(0, hc)}
     by_offset = {(0, 0): embed(tiles[0])}
     for j, off in enumerate(NEIGHBOR_OFFSETS8):
         by_offset[off] = embed(tiles[1 + j])
-    P = tile_grid([[by_offset[(dx, dy)][rows[dy], cols[dx]]
-                    for dx in (-1, 0, 1)] for dy in (-1, 0, 1)])
+
+    def pad(dy):
+        right = by_offset[(1, dy)][rows[dy]]
+        left = by_offset[(-1, dy)][rows[dy]]
+        if pw == 2 * span:
+            return jnp.concatenate([right, left], axis=1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (right.shape[0], pw), 1)
+        return jnp.where(lane < pw // 2, right[:, :pw],
+                         left[:, span - pw:])
+
+    P = tile_grid([[by_offset[(0, dy)][rows[dy]], pad(dy)]
+                   for dy in (-1, 0, 1)])
 
     iy = jax.lax.broadcasted_iota(jnp.int32, (wr, wc), 0)
     ix = jax.lax.broadcasted_iota(jnp.int32, (wr, wc), 1)
-    gx = bx * span - hc + ix
+    # the pad group's second half holds the left neighbour's columns
+    gx = bx * span + ix - jnp.where(ix >= span + pw // 2, wc, 0)
     gy = by * span - hr + iy
     inr = (gx >= 0) & (gx < n) & (gy >= 0) & (gy < n)
     gxc = jnp.clip(gx, 0, n - 1)
@@ -216,7 +234,7 @@ def _trapezoid_update(tiles, bx, by, steps, *, rule, alpha, block, n,
             return jnp.where(cell_ok, new, 0)
 
     P2 = jax.lax.fori_loop(0, steps, lambda i, pv: one(pv), P)
-    return unembed(P2[hr:hr + span, hc:hc + span])
+    return unembed(P2[hr:hr + span, :span])
 
 
 def _ca_fused_kernel(coords, c_ref, n_ref, s_ref, w_ref, e_ref, nw_ref,

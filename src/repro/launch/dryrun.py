@@ -5,12 +5,28 @@ MUST be run as a module (python -m repro.launch.dryrun ...) so the
 device-count override below precedes any jax initialization.  The run
 pins itself to the CPU: on a machine with a chip, a dry-run child that
 initialized the TPU would take it from the process that owns it.
+Importing the module (for its helpers) changes neither the platform
+nor XLA_FLAGS of the importing process.
 """
 import os
-os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["XLA_FLAGS"] = " ".join(filter(None, (
-    os.environ.get("XLA_FLAGS"),
-    "--xla_force_host_platform_device_count=512")))
+
+_HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count=512"
+
+
+def _cpu_env(env):
+    """`env` pinned to the CPU with the production device count."""
+    env = dict(env)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = " ".join(filter(None, (env.get("XLA_FLAGS"),
+                                              _HOST_DEVICES_FLAG)))
+    return env
+
+
+if __name__ == "__main__":
+    os.environ.update(_cpu_env(os.environ))
+    import jax
+    # the package import already read JAX_PLATFORMS; pin the config too
+    jax.config.update("jax_platforms", "cpu")
 
 import argparse     # noqa: E402
 import json         # noqa: E402
@@ -251,7 +267,7 @@ def run_cell_subprocess(arch, shape, mesh_kind, out_path, opt=None):
            "--shape", shape, "--mesh", mesh_kind, "--json-out", out_path]
     if opt:
         cmd += ["--opt", opt]
-    env = dict(os.environ)
+    env = _cpu_env(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
     env["DRYRUN_HLO_OUT"] = out_path.replace(".json", ".hlo.gz")
     return subprocess.run(cmd, capture_output=True, text=True, env=env,

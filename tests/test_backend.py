@@ -386,10 +386,12 @@ def test_uncacheable_domain_still_works():
 
 def test_prefetch_table_over_smem_is_refused_at_plan_time():
     """A decode table that would not fit the chip's SMEM is refused
-    while the launch is built, with the limit in the message -- not
-    handed to the compiler (or quietly swapped for another lowering).
-    The compact n=2^16 gasket at block 128 has 19,683 blocks x 28 i32
-    columns: 2.2 MB of table against 1 MiB of SMEM."""
+    while the launch is traced for the native target, with the limit in
+    the message -- not handed to the compiler (or quietly swapped for
+    another lowering).  The compact n=2^16 gasket at block 128 has
+    19,683 blocks x 28 i32 columns: 2.2 MB of table against 1 MiB of
+    SMEM.  The interpreter, which holds tables in host memory, has no
+    such budget."""
     n, block = 1 << 16, 128
     lay = CompactLayout(make_fractal_domain("sierpinski-gasket",
                                             n // block))
@@ -399,6 +401,9 @@ def test_prefetch_table_over_smem_is_refused_at_plan_time():
             jax.jit(lambda a, lowering=lowering: sierpinski_write(
                 a, 1.0, block=block, grid_mode=lowering,
                 storage="compact", n=n, coarsen=1, num_stages=1,
-                backend="tpu-interpret")).lower(m)
+                backend="tpu")).trace(m)
+    assert B.TPU.smem_table_bytes == B.SMEM_TABLE_BYTES
+    assert B.TPU_INTERPRET.smem_table_bytes is None
+    assert B.GPU.smem_table_bytes is None
     # the embedded table of the same domain (2 columns) rides flat
-    B.check_smem_tables([(19683, 2)])
+    B.check_smem_tables([(19683, 2)], limit=B.TPU.smem_table_bytes)

@@ -82,3 +82,22 @@ def test_smoke_four_chip_phase_on_virtual_devices():
         """)
     assert r.returncode == 0, r.stderr[-4000:]
     assert "OK" in r.stdout
+
+
+def test_smoke_stream_check_catches_a_wrong_server_position(monkeypatch):
+    """The served-stream comparison must fail when only the server's own
+    batched decode reads and writes the wrong cache position (the
+    single-step check, which builds its own page table, still passes)."""
+    smoke = _smoke()
+    from repro.configs import get_config
+    from repro.launch.serve import PagedServer
+    real = PagedServer._decode_step
+
+    def off_by_one(self, toks, posv, act):
+        return real(self, toks, posv - act.astype(posv.dtype), act)
+
+    monkeypatch.setattr(PagedServer, "_decode_step", off_by_one)
+    with pytest.raises(AssertionError, match="served token"):
+        smoke.phase_serve(get_config("phi3-mini-3.8b", smoke=True),
+                          num_slots=2, page_size=8, prompt_lens=(8, 20),
+                          n_requests=3, max_new=4)

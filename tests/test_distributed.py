@@ -154,3 +154,52 @@ def test_dryrun_single_cell_quickstart_scale():
         print("OK flops=%.2e" % cost.flops)
     """)
     assert "OK" in out
+
+
+def test_dryrun_import_leaves_platform_and_flags_alone():
+    # only the dry-run's own process (and the children it starts) is
+    # pinned to the CPU: a process that imports its helpers keeps the
+    # platform and XLA_FLAGS it was started with
+    code = """
+        import os
+        import jax
+        import repro.launch.dryrun as dryrun
+        assert "JAX_PLATFORMS" not in os.environ, os.environ["JAX_PLATFORMS"]
+        assert os.environ.get("XLA_FLAGS") == "--xla_dump_to=/dev/null"
+        assert jax.config.jax_platforms is None
+        child = dryrun._cpu_env(os.environ)
+        assert child["JAX_PLATFORMS"] == "cpu"
+        assert child["XLA_FLAGS"].split() == [
+            "--xla_dump_to=/dev/null",
+            "--xla_force_host_platform_device_count=512"]
+        print("OK")
+    """
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    env["XLA_FLAGS"] = "--xla_dump_to=/dev/null"
+    env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0 and "OK" in r.stdout, r.stderr
+
+
+def test_dryrun_main_pins_the_cpu():
+    # run as a module, the dry-run pins its own process to the CPU even
+    # though the package import has already loaded jax
+    code = """
+        import runpy, sys
+        sys.argv = ["dryrun", "--help"]
+        try:
+            runpy.run_module("repro.launch.dryrun", run_name="__main__")
+        except SystemExit:
+            pass
+        import jax, os
+        assert jax.config.jax_platforms == "cpu"
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+        assert "device_count=512" in os.environ["XLA_FLAGS"]
+        print("OK")
+    """
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0 and "OK" in r.stdout, r.stderr

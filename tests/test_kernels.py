@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core import fractal as F
+from repro.core.domain import make_fractal_domain
 from repro.kernels import ops, ref
 
 GRID_MODES = (os.environ["REPRO_GRID_MODE"].split(",")
@@ -200,19 +201,65 @@ def test_bounding_grid_fills_non_member_blocks(chip_semantics, num_stages):
 
 
 def test_bounding_over_compact_storage_is_refused_on_the_chip():
-    """Its non-member steps map onto member slots, and the chip writes
-    every visited output block back: refused for the native target
-    while the launch is built (lowering it here needs no chip)."""
+    """Refused on every target where no member step sits next to the
+    skipped ones in grid order: a domain that cannot name its row
+    members, and sharded launches (lowering here needs no chip)."""
     import jax
     from repro.core.compact import CompactLayout
-    from repro.core.domain import make_fractal_domain
-    n, block = 32, 8
-    shape = CompactLayout(make_fractal_domain(
-        "sierpinski-gasket", n // block)).array_shape(block)
-    a = jax.ShapeDtypeStruct(shape, jnp.float32)
-    kw = dict(block=block, grid_mode="bounding", storage="compact", n=n,
-              coarsen=1, num_stages=1, backend="tpu")
-    with pytest.raises(ValueError, match="bounding"):
-        jax.jit(lambda m: ops.sierpinski_write(m, 1.0, **kw)).lower(a)
-    with pytest.raises(ValueError, match="bounding"):
-        jax.jit(lambda x, y: ops.ca_run(x, y, 2, fuse=1, **kw)).lower(a, a)
+    from repro.core.domain import BoundingBoxDomain
+    from repro.core.plan import GridPlan
+    from repro.analysis.verifier import HostMesh
+    from repro.core.shard import ShardedPlan
+    dom = BoundingBoxDomain(4, 4, member=lambda x, y: (x + y) % 2 == 0)
+    block = 8
+    a = jax.ShapeDtypeStruct(CompactLayout(dom).array_shape(block),
+                             jnp.float32)
+    for backend in ("tpu", "tpu-interpret"):
+        kw = dict(block=block, grid_mode="bounding", storage="compact",
+                  domain=dom, coarsen=1, num_stages=1, backend=backend)
+        with pytest.raises(ValueError, match="row members"):
+            jax.jit(lambda m: ops.sierpinski_write(m, 1.0, **kw)).lower(a)
+        with pytest.raises(ValueError, match="row members"):
+            jax.jit(lambda x, y: ops.ca_run(x, y, 2, fuse=1, **kw)).lower(
+                a, a)
+        with pytest.raises(ValueError, match="row members"):
+            GridPlan(dom, "bounding", storage="compact",
+                     backend=backend).check_output_writeback()
+        gasket = make_fractal_domain("sierpinski-gasket", 4)
+        with pytest.raises(ValueError, match="cannot be sharded"):
+            ShardedPlan(gasket, "bounding", storage="compact",
+                        backend=backend, mesh=HostMesh(2),
+                        axis="data").check_output_writeback()
+
+
+@pytest.mark.parametrize("fractal,n,block,coarsen", [
+    ("sierpinski-gasket", 32, 8, 1), ("sierpinski-gasket", 32, 4, 2),
+    ("vicsek-cross", 27, 3, 1)])
+@pytest.mark.parametrize("num_stages", [1, 2])
+def test_bounding_over_compact_storage_revisits_member_blocks(
+        chip_semantics, fractal, n, block, coarsen, num_stages):
+    """Under ``bounding`` over compact storage each non-member step
+    revisits the slot of the member step next to it in grid order, so
+    the chip's write-back of every visited block keeps the members'
+    results (vicsek's first row starts with non-members)."""
+    from repro.core.compact import CompactLayout
+    dom = make_fractal_domain(fractal, n)
+    y, x = np.mgrid[0:n, 0:n]
+    mask = np.asarray(dom.cell_member(x, y, n))
+    m = jnp.asarray(np.where(mask, RNG.integers(0, 4, (n, n)), 0),
+                    jnp.float32)
+    lay = CompactLayout(make_fractal_domain(fractal, n // block))
+    mp = lay.pack(m, block)
+    kw = dict(block=block, fractal=fractal, storage="compact", n=n,
+              coarsen=coarsen, num_stages=num_stages)
+    got = ops.sierpinski_write(mp, 7.0, grid_mode="bounding", **kw)
+    np.testing.assert_array_equal(
+        np.asarray(lay.unpack(got, block))[mask], 7.0)
+    s = jnp.asarray(np.where(mask, RNG.integers(0, 2, (n, n)), 0),
+                    jnp.float32)
+    sp = lay.pack(s, block)
+    want = ops.ca_run(sp, jnp.zeros_like(sp), 3, fuse=2,
+                      grid_mode="closed_form", **kw)
+    got = ops.ca_run(sp, jnp.zeros_like(sp), 3, fuse=2,
+                     grid_mode="bounding", **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -68,7 +68,7 @@ def test_sharded_ca_bit_identical_all_lowerings_and_storages():
     n, block, steps = 32, 8, 5
     lay = CompactLayout(make_fractal_domain("sierpinski-gasket",
                                             n // block))
-    checked = 0
+    checked = refused = 0
     for D in (2, 3, 4):
         from repro.launch.mesh import make_mesh
         mesh = make_mesh((D,), ("data",))
@@ -85,14 +85,23 @@ def test_sharded_ca_bit_identical_all_lowerings_and_storages():
                               grid_mode=gm, storage=storage, n=n,
                               coarsen=coarsen, donate=False)
                     want = ops.ca_run(a, b, steps, **kw)
+                    if (gm, storage) == ("bounding", "compact"):
+                        # skipped steps would write back over owned slots
+                        try:
+                            ops.ca_run(a, b, steps, mesh=mesh, **kw)
+                            raise SystemExit("expected ValueError")
+                        except ValueError as e:
+                            assert "cannot be sharded" in str(e)
+                        refused += 1
+                        continue
                     got = ops.ca_run(a, b, steps, mesh=mesh, **kw)
                     assert np.array_equal(np.asarray(got),
                                           np.asarray(want)), \\
                         (D, gm, storage, rule, fuse, coarsen)
                     checked += 1
-    print("OK", checked)
+    print("OK", checked, refused)
     """)
-    assert "OK 72" in out
+    assert "OK 63 9" in out
 
 
 def test_sharded_ca_larger_domain_uneven_rows():
@@ -205,10 +214,18 @@ def test_sharded_write_and_sum():
                     kw = dict(block=block, grid_mode=gm,
                               storage=storage, n=n, coarsen=coarsen)
                     want = ops.sierpinski_write(arr, 7.0, **kw)
-                    got = ops.sierpinski_write(arr, 7.0, mesh=mesh, **kw)
-                    assert np.array_equal(np.asarray(got),
-                                          np.asarray(want)), \\
-                        ("write", D, gm, storage, coarsen)
+                    if (gm, storage) == ("bounding", "compact"):
+                        try:
+                            ops.sierpinski_write(arr, 7.0, mesh=mesh, **kw)
+                            raise SystemExit("expected ValueError")
+                        except ValueError as e:
+                            assert "cannot be sharded" in str(e)
+                    else:
+                        got = ops.sierpinski_write(arr, 7.0, mesh=mesh,
+                                                   **kw)
+                        assert np.array_equal(np.asarray(got),
+                                              np.asarray(want)), \\
+                            ("write", D, gm, storage, coarsen)
                     sw = float(ops.sierpinski_sum(arr, **kw))
                     sg = float(ops.sierpinski_sum(arr, mesh=mesh, **kw))
                     np.testing.assert_allclose(sg, sw, rtol=1e-5)
