@@ -112,11 +112,10 @@ def run(slot_counts=(2, 4), n_requests: int = 12):
             _paged_drain(server, requests, max_news,
                          rid0=r * len(requests))
             dt_p = min(dt_p, time.perf_counter() - t0)
-        frag = [s["fragmentation"] for s in server.stats_history] or [0]
         row(f"serve_throughput/paged/slots={B}/ps=8",
             dt_p / useful * 1e6,
             f"tok_per_s={useful / dt_p:.1f},"
-            f"frag={float(np.mean(frag)):.2f},"
+            f"frag={server.mean_fragmentation:.2f},"
             f"speedup_vs_contiguous={dt_c / dt_p:.2f}")
 
 
@@ -144,10 +143,9 @@ def run_page_sizes(page_sizes=(4, 8, 16), n_requests: int = 6):
         t0 = time.perf_counter()
         _paged_drain(server, requests, max_news, rid0=len(requests))
         dt = time.perf_counter() - t0
-        frag = [s["fragmentation"] for s in server.stats_history] or [0]
         row(f"serve_paged/page_size={ps}", dt / useful * 1e6,
             f"tok_per_s={useful / dt:.1f},"
-            f"frag={float(np.mean(frag)):.2f}")
+            f"frag={server.mean_fragmentation:.2f}")
 
 
 def run_zigzag_balance(device_counts=(2, 4, 8), nby: int = 32):

@@ -467,7 +467,8 @@ def emit(plan, kernel: Callable, *, in_specs, out_specs, out_shape,
          scratch_shapes=(), input_output_aliases: Optional[dict] = None,
          interpret: Optional[bool] = None,
          num_warps: Optional[int] = None,
-         num_stages: Optional[int] = None, **kwargs) -> Callable:
+         num_stages: Optional[int] = None, name: Optional[str] = None,
+         **kwargs) -> Callable:
     """Build the ``pl.pallas_call`` for ``plan`` on its target.
 
     ``kernel(coords, *refs)`` is lowering- and target-agnostic at the
@@ -490,6 +491,10 @@ def emit(plan, kernel: Callable, *, in_specs, out_specs, out_shape,
     when it returns ``None`` the caller passes the tables first
     (sharded plans, whose tables are per-device ``shard_map``
     operands).
+
+    ``name`` names the kernel: on the TPU it becomes the Mosaic custom
+    call's ``kernel_name`` and the name of its XLA instruction, which
+    the profiler's trace shows.
     """
     target = plan.target
     interp = target.interpret if interpret is None else interpret
@@ -500,7 +505,7 @@ def emit(plan, kernel: Callable, *, in_specs, out_specs, out_shape,
     aliases = {int(i): int(o)
                for i, o in (input_output_aliases or {}).items()}
     nsp = plan.num_scalar_prefetch
-    extra = dict(kwargs)
+    extra = dict(kwargs, name=name)
     extra.update(target.call_kwargs(num_warps, num_stages))
 
     record = None

@@ -52,6 +52,11 @@ from repro.core.domain import make_attention_domain
 from repro.core.plan import GridPlan, normalize_storage
 
 NEG_INF = float(-1e30)  # avoid true -inf so exp() stays nan-free
+#: the paged decode kernel's name.  On the TPU a kernel's name is also
+#: its XLA instruction's name in the profiler's trace; this one keeps the
+#: jitted entry's name, ``_paged_impl``, as its prefix, so that readers
+#: matching the kernel by its entry still find it
+PAGED_KERNEL_NAME = "_paged_impl_decode"
 
 
 def _row_bounds(kind, qb, m_k, wb, off_b):
@@ -316,7 +321,7 @@ def _gpu_flash_call(*, target, domain, lowering, b, h, group, m_q, m_k,
             kern, grid=(b * h, rows), in_specs=specs,
             out_specs=q_spec(),
             out_shape=jax.ShapeDtypeStruct(out_shape, dtype),
-            interpret=interp, **extra)
+            interpret=interp, name="flash_attention", **extra)
         return c(*args)
 
     if n_ext:
@@ -501,6 +506,7 @@ def _flash_impl(q, k, v, seq_pos=None, *, kind, window, scale, block_q,
                 target.scratch((block_q, 1), jnp.float32),
             ],
             num_warps=num_warps, num_stages=num_stages,
+            name="flash_attention",
         )
         if mesh is None:
             return call(q, k, v, *pos_operand)
@@ -753,7 +759,7 @@ def _gpu_paged_call(*, target, b, h, group, m_k, page_size, d, window,
                       full_spec(kv_pool.shape), full_spec((b,))],
             out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct(out_shape, dtype),
-            interpret=target.interpret, **extra)
+            interpret=target.interpret, name=PAGED_KERNEL_NAME, **extra)
         return c(pt, q, kv_pool, pos)
 
     return call
@@ -834,6 +840,7 @@ def _paged_impl(q, kv_pool, page_table, seq_pos, *, window, scale,
             target.scratch((1, 1), jnp.float32),
         ],
         num_warps=num_warps, num_stages=num_stages,
+        name=PAGED_KERNEL_NAME,
     )
     return call(q, kv_pool, pos)
 

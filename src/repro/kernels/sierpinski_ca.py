@@ -57,13 +57,16 @@ from repro.core.backend import full_spec
 from repro.core.compact import NEIGHBOR_OFFSETS8
 from repro.core.domain import BlockDomain
 from repro.core.plan import GridPlan
-from .sierpinski_write import (resolve_auto_schedule, resolve_storage_args,
-                               tile_grid)
+from repro.runtime.trace import span
+from .sierpinski_write import (entry_counters, resolve_auto_schedule,
+                               resolve_storage_args, tile_grid)
 
 #: trace/build telemetry the schedule-equivalence tests read: "kernel"
 #: counts fused-kernel body traces, "build" counts pallas_call
 #: constructions.  A T-step ca_run must bump each exactly once.
 TRACE_COUNTER = {"kernel": 0, "build": 0}
+#: the fused kernel's name in the profiler's trace
+KERNEL_NAME = "sierpinski_ca_fused"
 
 
 def auto_schedule(*, fractal: str = "sierpinski-gasket", n: int,
@@ -364,6 +367,7 @@ def _build_launch(plan, *, rule, alpha, block, n, halo, shape, dtype,
                 target.dma_sems((stages, 9)),
             ],
             input_output_aliases={1: 0},
+            name=KERNEL_NAME,
         )
 
         def launch(a, b, steps_scalar, prefetch=()):
@@ -383,6 +387,7 @@ def _build_launch(plan, *, rule, alpha, block, n, halo, shape, dtype,
             out_specs=tile,
             out_shape=jax.ShapeDtypeStruct(shape, dtype),
             input_output_aliases={9: 0},
+            name=KERNEL_NAME,
         )
 
         def launch(a, b, steps_scalar, prefetch=()):
@@ -398,6 +403,7 @@ def _build_launch(plan, *, rule, alpha, block, n, halo, shape, dtype,
         out_shape=jax.ShapeDtypeStruct(shape, dtype),
         input_output_aliases={1: 0},
         num_stages=stages if stages > 1 else None,
+        name=KERNEL_NAME,
     )
 
     def launch(a, b, steps_scalar, prefetch=()):
@@ -642,22 +648,33 @@ def ca_run(state: jnp.ndarray, stale_buf: jnp.ndarray, steps: int, *,
     :mod:`repro.analysis`) at trace time and raises on any
     violation."""
     target = backend_lib.resolve(backend, interpret)
-    grid_mode, fuse, coarsen, num_stages = auto_schedule(
-        fractal=fractal, n=n or state.shape[0], block=block, rule=rule,
-        grid_mode=grid_mode, fuse=fuse, coarsen=coarsen,
-        num_stages=num_stages, mesh=mesh, shard_axis=shard_axis,
-        target=target)
-    if donate is None:
-        donate = not target.interpret and jax.default_backend() != "cpu"
-    kw = dict(steps=int(steps), fuse=fuse, rule=rule, alpha=alpha,
-              block=block, grid_mode=grid_mode, fractal=fractal,
-              storage=storage, n=n, domain=domain, coarsen=coarsen,
-              backend=target, stages=target.resolve_stages(num_stages),
-              verify=verify)
-    if mesh is not None:
-        return _CA_RUN_SHARD_JIT[bool(donate)](
-            state, stale_buf, mesh=mesh, shard_axis=shard_axis, **kw)
-    return _CA_RUN_JIT[bool(donate)](state, stale_buf, **kw)
+    with span("kernels.ca_run") as entry:
+        with span("kernels.ca_run.schedule"):
+            grid_mode, fuse, coarsen, num_stages = auto_schedule(
+                fractal=fractal, n=n or state.shape[0], block=block,
+                rule=rule, grid_mode=grid_mode, fuse=fuse,
+                coarsen=coarsen, num_stages=num_stages, mesh=mesh,
+                shard_axis=shard_axis, target=target)
+        run_fuse = effective_fuse(fuse, steps, block, coarsen)
+        entry.set_metadata(**entry_counters(
+            state, fractal=fractal, storage=storage, n=n, domain=domain,
+            block=block, grid_mode=grid_mode, coarsen=coarsen, mesh=mesh,
+            steps=int(steps), fuse=run_fuse,
+            launches=len(launch_schedule(steps, run_fuse))))
+        if donate is None:
+            donate = not target.interpret and \
+                jax.default_backend() != "cpu"
+        kw = dict(steps=int(steps), fuse=fuse, rule=rule, alpha=alpha,
+                  block=block, grid_mode=grid_mode, fractal=fractal,
+                  storage=storage, n=n, domain=domain, coarsen=coarsen,
+                  backend=target, stages=target.resolve_stages(num_stages),
+                  verify=verify)
+        with span("kernels.ca_run.dispatch"):
+            if mesh is not None:
+                return _CA_RUN_SHARD_JIT[bool(donate)](
+                    state, stale_buf, mesh=mesh, shard_axis=shard_axis,
+                    **kw)
+            return _CA_RUN_JIT[bool(donate)](state, stale_buf, **kw)
 
 
 def ca_step(state: jnp.ndarray, stale_buf: jnp.ndarray, *,

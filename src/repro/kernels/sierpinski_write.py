@@ -51,6 +51,7 @@ from repro.core import backend as backend_lib
 from repro.core.backend import full_spec
 from repro.core.domain import BlockDomain, make_fractal_domain
 from repro.core.plan import GridPlan, normalize_storage
+from repro.runtime.trace import span
 
 
 def resolve_fractal_domain(fractal: str, n: int, block: int) -> BlockDomain:
@@ -127,6 +128,30 @@ def resolve_auto_schedule(kernel: str, params: dict, **knobs):
     cfg = tune.best(kernel, params) or {}
     return tuple(cfg.get(key, default) if value == "auto" else value
                  for value, key, default in knobs.values())
+
+
+def entry_counters(m, *, fractal: str, storage: str, n, domain,
+                   block: int, grid_mode: str, coarsen, mesh,
+                   **more) -> dict:
+    """The counters of a λ-kernel entry's span (see
+    :mod:`repro.runtime.trace`): ``more``, ``grid_mode``, ``storage``,
+    and ``grid_steps`` where the host knows them from the domain alone:
+    one per member block of a single-device launch, under a lowering
+    that enumerates members, at ``coarsen`` 1."""
+    out = dict(more, grid_mode=grid_mode, storage=storage)
+    if mesh is not None or coarsen != 1 or grid_mode == "bounding":
+        return out
+    if domain is None:
+        if n is None and storage == "embedded":
+            n = m.shape[0]
+        if n is None or n % block:
+            return out
+        try:
+            domain = make_fractal_domain(fractal, n // block)
+        except ValueError:
+            return out
+    out["grid_steps"] = int(domain.num_blocks)
+    return out
 
 
 def _cell_mask(domain: BlockDomain, bx, by, block: int, n: int):
@@ -274,6 +299,7 @@ def _emit_write(plan: GridPlan, shape, dtype, *, value, block, n,
                 target.dma_sems((stages, 1)),
             ],
             input_output_aliases={1: 0},
+            name="sierpinski_write",
         )
         # the state rides twice: ANY (DMA source) + BlockSpec (alias)
         return lambda *args: call(*args[:-1], args[-1], args[-1])
@@ -286,6 +312,7 @@ def _emit_write(plan: GridPlan, shape, dtype, *, value, block, n,
             out_specs=spec,
             out_shape=jax.ShapeDtypeStruct(shape, dtype),
             input_output_aliases={0: 0},
+            name="sierpinski_write",
         )
     return plan.pallas_call(
         functools.partial(_write_kernel_gpu, value=value, block=block,
@@ -295,6 +322,7 @@ def _emit_write(plan: GridPlan, shape, dtype, *, value, block, n,
         out_shape=jax.ShapeDtypeStruct(shape, dtype),
         input_output_aliases={0: 0},
         num_stages=stages if stages > 1 else None,
+        name="sierpinski_write",
     )
 
 
@@ -411,25 +439,31 @@ def sierpinski_write(m: jnp.ndarray, value: float = 1.0, *,
     default."""
     target = backend_lib.resolve(backend, interpret)
     from repro.core import tune
-    grid_mode, coarsen, num_stages = resolve_auto_schedule(
-        "write",
-        tune.target_params(
-            tune.shard_params(
-                {"fractal": fractal, "n": n or m.shape[0],
-                 "block": block},
-                mesh, shard_axis),
-            target),
-        grid_mode=(grid_mode, "lowering", "closed_form"),
-        coarsen=(coarsen, "coarsen", 1),
-        num_stages=(num_stages, "stages", 1))
-    kw = dict(block=block, grid_mode=grid_mode, fractal=fractal,
-              storage=storage, n=n, domain=domain, coarsen=coarsen,
-              backend=target, stages=target.resolve_stages(num_stages),
-              verify=verify)
-    if mesh is not None:
-        return _write_sharded_impl(m, value, mesh=mesh,
-                                   shard_axis=shard_axis, **kw)
-    return _write_impl(m, value, **kw)
+    with span("kernels.sierpinski_write") as entry:
+        with span("kernels.sierpinski_write.schedule"):
+            grid_mode, coarsen, num_stages = resolve_auto_schedule(
+                "write",
+                tune.target_params(
+                    tune.shard_params(
+                        {"fractal": fractal, "n": n or m.shape[0],
+                         "block": block},
+                        mesh, shard_axis),
+                    target),
+                grid_mode=(grid_mode, "lowering", "closed_form"),
+                coarsen=(coarsen, "coarsen", 1),
+                num_stages=(num_stages, "stages", 1))
+        entry.set_metadata(**entry_counters(
+            m, fractal=fractal, storage=storage, n=n, domain=domain,
+            block=block, grid_mode=grid_mode, coarsen=coarsen, mesh=mesh))
+        kw = dict(block=block, grid_mode=grid_mode, fractal=fractal,
+                  storage=storage, n=n, domain=domain, coarsen=coarsen,
+                  backend=target, stages=target.resolve_stages(num_stages),
+                  verify=verify)
+        with span("kernels.sierpinski_write.dispatch"):
+            if mesh is not None:
+                return _write_sharded_impl(m, value, mesh=mesh,
+                                           shard_axis=shard_axis, **kw)
+            return _write_impl(m, value, **kw)
 
 
 def _sum_kernel(coords, m_ref, o_ref, *, block, n, plan):
@@ -510,6 +544,7 @@ def _emit_sum(plan: GridPlan, shape, *, block, n, stages=1,
                 target.scratch((stages, 1, th, tw), dtype),
                 target.dma_sems((stages, 1)),
             ],
+            name="sierpinski_sum",
         )
         return call, lambda out: out
     if target.sequential_grid:
@@ -518,6 +553,7 @@ def _emit_sum(plan: GridPlan, shape, *, block, n, stages=1,
             in_specs=[plan.storage_spec((block, block))],
             out_specs=plan.block_spec((1, 1), lambda bx, by: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            name="sierpinski_sum",
         )
         return call, lambda out: out
     steps = plan.steps_per_launch
@@ -527,6 +563,7 @@ def _emit_sum(plan: GridPlan, shape, *, block, n, stages=1,
         out_specs=full_spec((steps, 1)),
         out_shape=jax.ShapeDtypeStruct((steps, 1), jnp.float32),
         num_stages=stages if stages > 1 else None,
+        name="sierpinski_sum",
     )
 
     def finish(partials):

@@ -48,6 +48,7 @@ from repro.models import model as model_lib
 from repro.runtime.guard import (Backoff, DegradationLadder, GuardedCall,
                                  GuardExhausted, ServerState, sample_key,
                                  spot_check, validate_finite)
+from repro.runtime.trace import span
 
 
 @dataclasses.dataclass
@@ -399,7 +400,10 @@ class PagedServer:
         self.mesh = None
         self.state = ServerState.HEALTHY
         self.events: list = []
-        self.stats_history: list = []
+        # the pool's running aggregates over the decode steps served
+        self.steps_served = 0
+        self.fragmentation_sum = 0.0
+        self.peak_utilization = 0.0
         self._paged_lib = paged_lib
         self.alloc = paged_lib.PagedKVPool(scfg.num_pages, scfg.page_size)
         self.max_pages = -(-scfg.max_len // scfg.page_size)
@@ -417,9 +421,9 @@ class PagedServer:
                 {"kind": "degrade", **rec}))
         self._base_key = jax.random.PRNGKey(scfg.seed)
         self._canary_ref = None
-        self._prefill_fn = jax.jit(partial(prefill, cfg=cfg))
-        self._scatter_fn = jax.jit(
-            partial(model_lib.scatter_prefill_pages, cfg=cfg))
+        self._prefill_fn = _jit_named(prefill, cfg=cfg)
+        self._scatter_fn = _jit_named(model_lib.scatter_prefill_pages,
+                                      cfg=cfg)
         self._decode_fn = None
         self._apply_rung(self.ladder.current())
         self._prefill = self._guarded("serve.prefill",
@@ -437,8 +441,7 @@ class PagedServer:
 
     def _apply_rung(self, rung: dict) -> None:
         cfg = self.cfg.replace(attn_decode_kernel=rung["decode_kernel"])
-        self._decode_fn = jax.jit(
-            partial(model_lib.decode_step_paged, cfg=cfg))
+        self._decode_fn = _jit_named(model_lib.decode_step_paged, cfg=cfg)
 
     # -- host bookkeeping ----------------------------------------------------
 
@@ -457,6 +460,13 @@ class PagedServer:
     def pool_stats(self) -> dict:
         return self.alloc.stats(
             [r.next_pos for r in self.slots if r is not None])
+
+    @property
+    def mean_fragmentation(self) -> float:
+        """The pool's fragmentation after each decode step, averaged
+        over the steps served (0 before the first)."""
+        return (self.fragmentation_sum / self.steps_served
+                if self.steps_served else 0.0)
 
     # -- request lifecycle ---------------------------------------------------
 
@@ -499,12 +509,18 @@ class PagedServer:
         if not self.alloc.can_alloc(need):
             return False
         self.pending.popleft()
-        pages = self.alloc.alloc(need)
-        slot = free_slots[0]
+        with span("serve.admit", rid=req.rid, prompt_tokens=len(tokens),
+                  pages=need, replayed=len(req.out)):
+            return self._admit(req, tokens, self.alloc.alloc(need),
+                               free_slots[0])
+
+    def _admit(self, req: _PagedRequest, tokens: np.ndarray, pages: list,
+               slot: int) -> bool:
         logits, caches = self._prefill(
             self.params, jnp.asarray(tokens[None]))
-        self.pools = self._scatter_fn(
-            self.pools, caches, jnp.asarray(pages, jnp.int32))
+        with span("serve.scatter"):
+            self.pools = self._scatter_fn(
+                self.pools, caches, jnp.asarray(pages, jnp.int32))
         req.pages = list(pages)
         req.seq = self._admit_seq
         self._admit_seq += 1
@@ -512,12 +528,14 @@ class PagedServer:
         self.table[slot] = self._paged_lib.NULL_PAGE
         self.table[slot, :len(pages)] = pages
         self.slots[slot] = req
-        self._verify_table()
-        tok = self._sample_token(np.asarray(logits)[0, 0], req.rid,
-                                 len(tokens) - 1)
+        with span("serve.sample", slots=1):
+            tok = self._sample_token(np.asarray(logits)[0, 0], req.rid,
+                                     len(tokens) - 1)
         req.out.append(tok)
-        if self._finished(slot, tok):
-            return True
+        with span("serve.table"):
+            self._verify_table()
+            if self._finished(slot, tok):
+                return True
         self.events.append({"kind": "admit", "rid": req.rid,
                             "slot": slot, "pages": len(pages),
                             "replayed": len(req.out) - 1})
@@ -564,27 +582,90 @@ class PagedServer:
             req.pages += got
         return True
 
-    def _decode_step(self, toks, posv, act):
+    def _decode_step(self, toks, table, posv, act):
         while True:
             try:
-                return self._decode(self.params, toks, self.pools,
-                                    jnp.asarray(self.table), posv, act)
+                return self._decode(self.params, toks, self.pools, table,
+                                    posv, act)
             except GuardExhausted as e:
                 self._degrade_or_raise(e)
+
+    def _active(self) -> list:
+        return [i for i in range(len(self.slots))
+                if self.slots[i] is not None]
 
     def step(self) -> bool:
         """One decode step for every active slot.  Returns False when
         nothing is active."""
-        active = [i for i in range(len(self.slots))
-                  if self.slots[i] is not None]
+        active = self._active()
         if not active:
             return False
-        # on-demand page growth, oldest slots first; preempt the
-        # youngest active request until the survivors fit
+        with span("serve.step", step=self.steps_served,
+                  active=len(active)) as step_span:
+            return self._step(active, step_span)
+
+    def _step(self, active: list, step_span) -> bool:
+        with span("serve.grow"):
+            preempted = self._grow_all(active)
+        active = self._active()
+        if not active:
+            step_span.set_metadata(preempted=preempted)
+            return False
+        with span("serve.inputs"):
+            B = self.scfg.num_slots
+            toks = np.zeros((B, 1), np.int32)
+            posv = np.zeros((B,), np.int32)
+            act = np.zeros((B,), bool)
+            for i in active:
+                req = self.slots[i]
+                toks[i, 0] = req.out[-1]
+                posv[i] = req.next_pos
+                act[i] = True
+            inputs = (jnp.asarray(toks), jnp.asarray(self.table),
+                      jnp.asarray(posv), jnp.asarray(act))
+        logits, pools = self._decode_step(*inputs)
+        with span("serve.release"):
+            # frees the previous pools, and with them the host copy the
+            # guard's NaN screen left on the array (``np.asarray`` keeps
+            # what it copied from a device)
+            self.pools = pools
+        # advance every slot before any finish check: the decode step
+        # already wrote position next_pos for all of them, so a
+        # mid-loop _verify_table must not see a stale next_pos
+        with span("serve.sample", slots=len(active)):
+            logits = np.asarray(logits)
+            sampled = []
+            for i in active:
+                req = self.slots[i]
+                tok = self._sample_token(logits[i, 0], req.rid,
+                                         req.next_pos)
+                req.next_pos += 1
+                req.out.append(tok)
+                sampled.append((i, tok))
+        with span("serve.table"):
+            for i, tok in sampled:
+                self._finished(i, tok)
+            self._verify_table()
+            stats = self.pool_stats()
+            self.steps_served += 1
+            self.fragmentation_sum += stats["fragmentation"]
+            self.peak_utilization = max(self.peak_utilization,
+                                        stats["utilization"])
+        step_span.set_metadata(
+            preempted=preempted, pages_in_use=stats["used_pages"],
+            free_pages=stats["free_pages"],
+            live_tokens=stats["live_tokens"],
+            alloc_tokens=stats["alloc_tokens"])
+        return True
+
+    def _grow_all(self, active: list) -> int:
+        """On-demand page growth, oldest slots first; preempts the
+        youngest active request until the survivors fit.  Returns the
+        number preempted."""
+        preempted = 0
         for i in sorted(active, key=lambda j: self.slots[j].seq):
             while self.slots[i] is not None and not self._grow(i):
-                victims = [j for j in range(len(self.slots))
-                           if self.slots[j] is not None]
+                victims = self._active()
                 victim = max(victims, key=lambda j: self.slots[j].seq)
                 if victim == i and len(victims) == 1:
                     raise RuntimeError(
@@ -592,37 +673,8 @@ class PagedServer:
                         f"hold a single request; raise num_pages or "
                         f"page_size")
                 self._preempt(victim)
-        active = [i for i in range(len(self.slots))
-                  if self.slots[i] is not None]
-        if not active:
-            return False
-        B = self.scfg.num_slots
-        toks = np.zeros((B, 1), np.int32)
-        posv = np.zeros((B,), np.int32)
-        act = np.zeros((B,), bool)
-        for i in active:
-            req = self.slots[i]
-            toks[i, 0] = req.out[-1]
-            posv[i] = req.next_pos
-            act[i] = True
-        logits, self.pools = self._decode_step(
-            jnp.asarray(toks), jnp.asarray(posv), jnp.asarray(act))
-        logits = np.asarray(logits)
-        # advance every slot before any finish check: the decode step
-        # already wrote position next_pos for all of them, so a
-        # mid-loop _verify_table must not see a stale next_pos
-        sampled = []
-        for i in active:
-            req = self.slots[i]
-            tok = self._sample_token(logits[i, 0], req.rid, req.next_pos)
-            req.next_pos += 1
-            req.out.append(tok)
-            sampled.append((i, tok))
-        for i, tok in sampled:
-            self._finished(i, tok)
-        self._verify_table()
-        self.stats_history.append(self.pool_stats())
-        return True
+                preempted += 1
+        return preempted
 
     def run(self, requests, max_new: int = 32) -> dict:
         """Serve ``requests`` (a list of 1-D prompt token arrays) to
@@ -645,15 +697,13 @@ def paged_throughput_report(server: PagedServer, requests,
     out = server.run(requests, max_new=max_new)
     dt = time.perf_counter() - t0
     tokens = int(sum(len(v) for v in out.values()))
-    frag = [s["fragmentation"] for s in server.stats_history] or [0.0]
-    util = [s["utilization"] for s in server.stats_history] or [0.0]
     return {"tokens": tokens, "seconds": dt, "tok_per_s": tokens / dt,
             "requests": len(out),
             "preemptions": sum(1 for e in server.events
                                if isinstance(e, dict)
                                and e.get("kind") == "preempt"),
-            "mean_fragmentation": float(np.mean(frag)),
-            "peak_utilization": float(np.max(util))}
+            "mean_fragmentation": server.mean_fragmentation,
+            "peak_utilization": server.peak_utilization}
 
 
 def throughput_report(server: Server, batch: int, prompt_len: int,
@@ -665,6 +715,15 @@ def throughput_report(server: Server, batch: int, prompt_len: int,
     dt = time.perf_counter() - t0
     return {"tokens": int(out.size), "seconds": dt,
             "tok_per_s": out.size / dt}
+
+
+def _jit_named(fn, **bound):
+    """``jax.jit`` of ``fn`` with the keyword arguments ``bound`` fixed,
+    under ``fn``'s own name: the trace shows the program as
+    ``jit_<name>``, where a bare ``partial`` shows ``jit__unknown``."""
+    bound_fn = partial(fn, **bound)
+    bound_fn.__name__ = fn.__name__
+    return jax.jit(bound_fn)
 
 
 class _null:

@@ -44,6 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.errors import JaxRuntimeError
 
+from repro.runtime.trace import span
+
 
 # ---------------------------------------------------------------------------
 # error taxonomy
@@ -170,13 +172,23 @@ class Backoff:
 # validation
 # ---------------------------------------------------------------------------
 
-def validate_finite(out: Any, what: str = "output") -> None:
+def validate_finite(out: Any, what: str = "output") -> Dict[str, int]:
     """NaN/inf screen over every floating leaf of ``out``; raises
-    :class:`ValidationError` naming the first offending leaf."""
-    for path, leaf in jax.tree_util.tree_leaves_with_path(out):
+    :class:`ValidationError` naming the first offending leaf.
+
+    Returns the screen's counters, which :class:`GuardedCall` puts on
+    its ``guard.validate`` span: ``leaves``; ``bytes_to_host``, the
+    bytes of every leaf, each copied to the host (a device array keeps
+    that copy until it is freed); ``bytes_screened``, those of the
+    leaves NumPy screens (its floating dtypes, which bfloat16 is not)."""
+    leaves = jax.tree_util.tree_leaves_with_path(out)
+    to_host = screened = 0
+    for path, leaf in leaves:
         arr = np.asarray(leaf)
+        to_host += arr.nbytes
         if not np.issubdtype(arr.dtype, np.floating):
             continue
+        screened += arr.nbytes
         if not np.isfinite(arr).all():
             key = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                            for k in path) or "<leaf>"
@@ -184,6 +196,8 @@ def validate_finite(out: Any, what: str = "output") -> None:
             raise ValidationError(
                 f"{what}: {bad} non-finite values in leaf {key} "
                 f"(shape {arr.shape})")
+    return {"leaves": len(leaves), "bytes_to_host": int(to_host),
+            "bytes_screened": int(screened)}
 
 
 def spot_check(reference: Any, what: str = "output",
@@ -296,7 +310,9 @@ class GuardedCall:
        ``deadline`` event (and, with ``enforce_deadline``, treat it as
        a transient failure);
     3. run every validator over the output (raising
-       :class:`ValidationError` counts as a transient failure);
+       :class:`ValidationError` counts as a transient failure); a
+       validator may return a dict of counters for the
+       ``guard.validate`` span;
     4. on a transient failure: sleep the backoff, call
        ``before_retry`` (the chaos/fault-injection path uses it to
        drop poisoned executable caches), and re-execute -- up to
@@ -306,7 +322,9 @@ class GuardedCall:
     6. on exhaustion: raise :class:`GuardExhausted` with the report.
 
     The event log (``.events``) persists across calls; ``on_event``
-    observes each event as it happens.
+    observes each event as it happens.  Each call is a ``guard.call``
+    span, each attempt a ``guard.run`` span and each round of
+    validators a ``guard.validate`` span (:mod:`repro.runtime.trace`).
     """
 
     def __init__(self, fn: Callable, name: str = "call", *,
@@ -356,13 +374,18 @@ class GuardedCall:
 
     def __call__(self, *args, **kwargs):
         self.calls += 1
+        with span("guard.call", site=self.name):
+            return self._attempts(args, kwargs)
+
+    def _attempts(self, args, kwargs):
         attempt = 0
         while True:
             attempt += 1
             t0 = time.perf_counter()
             try:
-                out = self.fn(*args, **kwargs)
-                out = jax.block_until_ready(out)
+                with span("guard.run", attempt=attempt):
+                    out = self.fn(*args, **kwargs)
+                    out = jax.block_until_ready(out)
                 elapsed = time.perf_counter() - t0
                 if self.deadline_s is not None and elapsed > self.deadline_s:
                     self._event("deadline", attempt,
@@ -372,8 +395,11 @@ class GuardedCall:
                         raise DeadlineExceeded(
                             f"{self.name}: {elapsed:.3f}s exceeded the "
                             f"{self.deadline_s:.3f}s deadline")
-                for v in self.validators:
-                    v(out)
+                with span("guard.validate") as validating:
+                    for v in self.validators:
+                        counters = v(out)
+                        if isinstance(counters, dict):
+                            validating.set_metadata(**counters)
                 self._event("ok", attempt, elapsed=elapsed)
                 if attempt > 1:
                     self.recoveries += 1

@@ -93,8 +93,8 @@ def test_smoke_stream_check_catches_a_wrong_server_position(monkeypatch):
     from repro.launch.serve import PagedServer
     real = PagedServer._decode_step
 
-    def off_by_one(self, toks, posv, act):
-        return real(self, toks, posv - act.astype(posv.dtype), act)
+    def off_by_one(self, toks, table, posv, act):
+        return real(self, toks, table, posv - act.astype(posv.dtype), act)
 
     monkeypatch.setattr(PagedServer, "_decode_step", off_by_one)
     with pytest.raises(AssertionError, match="served token"):

@@ -428,3 +428,27 @@ def test_paged_throughput_report_fields():
     assert rep["tok_per_s"] > 0
     assert 0.0 <= rep["mean_fragmentation"] <= 1.0
     assert 0.0 < rep["peak_utilization"] <= 1.0
+
+
+def test_pool_aggregates_match_per_step_stats():
+    """The server's running aggregates equal the mean fragmentation and
+    the peak utilisation of the pool statistics read after each step."""
+    from repro.launch.serve import PagedServeConfig, PagedServer
+    cfg, params = _paged_setup(decode_kernel="xla")
+    srv = PagedServer(cfg, params, PagedServeConfig(
+        max_len=32, temperature=0.0, num_slots=2, page_size=4,
+        num_pages=16, guard=False))
+    for rid, prompt in enumerate(_mixed_prompts(cfg)):
+        srv.submit(rid, prompt, 6)
+    frag, util = [], []
+    while srv.pending or any(s is not None for s in srv.slots):
+        while srv._admit_one():
+            pass
+        if srv.step():
+            s = srv.pool_stats()
+            frag.append(s["fragmentation"])
+            util.append(s["utilization"])
+    assert srv.steps_served == len(frag) >= 4
+    assert srv.mean_fragmentation == pytest.approx(np.mean(frag), abs=1e-12)
+    assert srv.peak_utilization == max(util)
+    assert len(set(util)) > 1          # the pool's use moved across steps
