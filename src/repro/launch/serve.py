@@ -625,9 +625,8 @@ class PagedServer:
                       jnp.asarray(posv), jnp.asarray(act))
         logits, pools = self._decode_step(*inputs)
         with span("serve.release"):
-            # frees the previous pools, and with them the host copy the
-            # guard's NaN screen left on the array (``np.asarray`` keeps
-            # what it copied from a device)
+            # frees the previous pools on the device, in a span of its
+            # own so that the step's bookkeeping shows what it costs
             self.pools = pools
         # advance every slot before any finish check: the decode step
         # already wrote position next_pos for all of them, so a

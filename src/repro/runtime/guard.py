@@ -172,32 +172,86 @@ class Backoff:
 # validation
 # ---------------------------------------------------------------------------
 
+def _leaf_key(path) -> str:
+    return "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                    for k in path) or "<leaf>"
+
+
+@jax.jit
+def _finite_flags(leaves):
+    """One flag per leaf: every value finite.  Runs where the leaves
+    live; only the flags leave the device."""
+    return jnp.stack([jnp.isfinite(x).all() for x in leaves])
+
+
+@jax.jit
+def _nonfinite_count(x):
+    """The non-finite values of a leaf that failed the screen, counted
+    in one fused program (no leaf-sized mask is kept)."""
+    return jnp.sum(~jnp.isfinite(x))
+
+
+def _device_verdicts(arrays: Sequence[jax.Array]) -> Tuple[List[bool], int]:
+    """The screen of ``arrays`` (inexact device arrays) on their
+    devices: one jitted program per set of devices the arrays live on,
+    the flags copied back with one ``np.asarray`` each.  Returns the
+    flags in order and the bytes copied."""
+    groups: Dict[frozenset, List[int]] = {}
+    for i, x in enumerate(arrays):
+        groups.setdefault(frozenset(x.devices()), []).append(i)
+    flags = [True] * len(arrays)
+    to_host = 0
+    for idx in groups.values():
+        got = np.asarray(_finite_flags([arrays[i] for i in idx]))
+        to_host += got.nbytes
+        for i, ok in zip(idx, got):
+            flags[i] = bool(ok)
+    return flags, to_host
+
+
 def validate_finite(out: Any, what: str = "output") -> Dict[str, int]:
-    """NaN/inf screen over every floating leaf of ``out``; raises
+    """NaN/inf screen over every floating leaf of ``out`` (every
+    ``jnp.inexact`` dtype, bfloat16 included); raises
     :class:`ValidationError` naming the first offending leaf.
 
+    Device leaves are screened where they live and only the verdict,
+    one flag a leaf, is copied to the host; the count of non-finite
+    values is taken only for a leaf that fails.  Host leaves (NumPy
+    arrays, Python scalars) are screened on the host.
+
     Returns the screen's counters, which :class:`GuardedCall` puts on
-    its ``guard.validate`` span: ``leaves``; ``bytes_to_host``, the
-    bytes of every leaf, each copied to the host (a device array keeps
-    that copy until it is freed); ``bytes_screened``, those of the
-    leaves NumPy screens (its floating dtypes, which bfloat16 is not)."""
+    its ``guard.validate`` span: ``leaves``; ``bytes_screened``, the
+    bytes of every screened leaf; ``bytes_to_host``, the bytes copied
+    from a device (the verdicts); ``screened_on_device``, the leaves
+    screened on the device."""
     leaves = jax.tree_util.tree_leaves_with_path(out)
-    to_host = screened = 0
-    for path, leaf in leaves:
-        arr = np.asarray(leaf)
-        to_host += arr.nbytes
-        if not np.issubdtype(arr.dtype, np.floating):
-            continue
-        screened += arr.nbytes
-        if not np.isfinite(arr).all():
-            key = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
-                           for k in path) or "<leaf>"
+    on_device = [i for i, (_, x) in enumerate(leaves)
+                 if isinstance(x, jax.Array)
+                 and jnp.issubdtype(x.dtype, jnp.inexact)]
+    flags, to_host = _device_verdicts([leaves[i][1] for i in on_device])
+    verdict = dict(zip(on_device, flags))
+    screened = 0
+    for i, (path, leaf) in enumerate(leaves):
+        if i in verdict:
+            screened += leaf.nbytes
+            bad = 0 if verdict[i] else int(_nonfinite_count(leaf))
+        elif isinstance(leaf, jax.Array):
+            continue                       # an integer or bool device leaf
+        else:
+            arr = np.asarray(leaf)
+            if not jnp.issubdtype(arr.dtype, jnp.inexact):
+                continue
+            screened += arr.nbytes
+            if not np.issubdtype(arr.dtype, np.inexact):
+                arr = arr.astype(np.float32)   # bfloat16, float8, ...
             bad = int(arr.size - np.isfinite(arr).sum())
+        if bad:
             raise ValidationError(
-                f"{what}: {bad} non-finite values in leaf {key} "
-                f"(shape {arr.shape})")
-    return {"leaves": len(leaves), "bytes_to_host": int(to_host),
-            "bytes_screened": int(screened)}
+                f"{what}: {bad} non-finite values in leaf "
+                f"{_leaf_key(path)} (shape {np.shape(leaf)})")
+    return {"leaves": len(leaves), "bytes_screened": int(screened),
+            "bytes_to_host": int(to_host),
+            "screened_on_device": len(on_device)}
 
 
 def spot_check(reference: Any, what: str = "output",

@@ -19,11 +19,10 @@ Spans, parents first, and what their counters mean:
     ``serve.grow``: page growth and preemption.  ``serve.inputs``: the
     host arrays of tokens, positions and the active mask, and their
     uploads with the page table.  ``guard.call`` (below) around the
-    decode call.  ``serve.release``: the previous pools dropped, which
-    frees the host copy of them that the guard's screen left cached on
-    the array.  ``serve.sample`` (``slots``): the logits' copy to the
-    host and each slot's sampling.  ``serve.table``: finished requests
-    freed, ``verify_page_table`` and the pool's statistics.
+    decode call.  ``serve.release``: the previous pools dropped.
+    ``serve.sample`` (``slots``): the logits' copy to the host and each
+    slot's sampling.  ``serve.table``: finished requests freed,
+    ``verify_page_table`` and the pool's statistics.
 
 ``serve.admit`` -- one admission by ``PagedServer._admit_one``
     ``rid``: the request; ``prompt_tokens``: tokens prefilled (prompt
@@ -40,8 +39,10 @@ Spans, parents first, and what their counters mean:
     ``block_until_ready``, so dispatch and the wait on the device.
     ``guard.validate``: the output's validators; where
     :func:`~repro.runtime.guard.validate_finite` is one, ``leaves``,
-    ``bytes_to_host`` (every leaf is copied to the host) and
-    ``bytes_screened`` (the floating leaves NumPy screens).
+    ``bytes_screened`` (every floating leaf, bfloat16 included),
+    ``bytes_to_host`` (what the screen copies from the device: one flag
+    per device leaf, no leaf itself) and ``screened_on_device`` (the
+    leaves the device screens).
 
 ``kernels.ca_run`` / ``kernels.sierpinski_write`` -- one call of the
 λ-kernel entry

@@ -101,6 +101,111 @@ def test_validate_finite_and_spot_check():
         spot_check(ref)({"w": np.arange(6, dtype=np.float32) + 1})
 
 
+@pytest.mark.parametrize("place", ["device", "host"])
+def test_validate_finite_screens_bfloat16_leaves(place):
+    x = np.ones((4, 8), jnp.bfloat16)
+    x[2, 5] = np.nan
+    leaf = jnp.asarray(x) if place == "device" else x
+    out = {"logits": jnp.ones((2, 3), jnp.float32),
+           "pools": {"blocks": [jnp.zeros((5,), jnp.bfloat16), leaf]}}
+    with pytest.raises(ValidationError,
+                       match=r"decode: 1 non-finite values in leaf "
+                             r"pools/blocks/1 \(shape \(4, 8\)\)"):
+        validate_finite(out, "decode")
+    x[2, 5] = np.inf                       # inf is caught as NaN is
+    out["pools"]["blocks"][1] = jnp.asarray(x) if place == "device" else x
+    with pytest.raises(ValidationError, match="pools/blocks/1"):
+        validate_finite(out)
+
+
+def test_validate_finite_names_the_first_offending_leaf():
+    bad = jnp.array([np.nan, 1.0, np.inf], jnp.float32)
+    out = [np.arange(3), bad, np.array([np.nan]), jnp.asarray(bad)]
+    with pytest.raises(ValidationError,
+                       match=r"2 non-finite values in leaf 1 "):
+        validate_finite(out)
+
+
+def test_validate_finite_copies_only_the_verdict():
+    out = {"a": jnp.ones((64, 64), jnp.bfloat16),
+           "b": jnp.ones((8,), jnp.float32),
+           "c": jnp.arange(6, dtype=jnp.int32),     # not screened
+           "d": np.ones((3,), np.float32),          # on the host
+           "e": 2.5}                                # a Python scalar
+    c = validate_finite(out)
+    assert c == {"leaves": 5, "screened_on_device": 2,
+                 "bytes_screened": 64 * 64 * 2 + 8 * 4 + 3 * 4 + 8,
+                 "bytes_to_host": 2}       # one bool flag a device leaf
+    assert validate_finite({"i": np.arange(4)}) == {
+        "leaves": 1, "screened_on_device": 0, "bytes_screened": 0,
+        "bytes_to_host": 0}
+
+
+def test_validate_finite_across_device_sets():
+    """Leaves on different devices, and sharded over a mesh, are each
+    screened where they live: one program per set of devices."""
+    out = run_sub("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.runtime.guard import ValidationError, validate_finite
+        d = jax.devices()
+        mesh = Mesh(np.array(d[:4]), ("x",))
+        sharded = jax.device_put(jnp.ones((8, 4), jnp.bfloat16),
+                                 NamedSharding(mesh, P("x")))
+        out = {"s": sharded,
+               "a": jax.device_put(jnp.ones(3), d[0]),
+               "b": jax.device_put(jnp.ones(3), d[1])}
+        c = validate_finite(out)
+        assert c["screened_on_device"] == 3, c
+        assert c["bytes_to_host"] == 3, c
+        bad = sharded.at[7, 3].set(jnp.nan)
+        try:
+            validate_finite({**out, "s": bad}, "sharded")
+        except ValidationError as e:
+            assert "1 non-finite values in leaf s " in str(e), e
+        else:
+            raise AssertionError("the sharded NaN was not caught")
+        print("ok")
+    """)
+    assert out.strip().endswith("ok")
+
+
+def test_paged_server_guard_screens_the_bfloat16_pool():
+    """A NaN in a page no request holds leaves the logits finite; the
+    guard still refuses the decode step's bf16 pool, retries, and walks
+    the ladder (the poison stays in the input pool, so nothing
+    recovers)."""
+    from repro.configs import get_config
+    from repro.launch.serve import PagedServeConfig, PagedServer
+    from repro.models import init
+    cfg = get_config("quickstart", smoke=True).replace(
+        attn_decode_kernel="blockspace", dtype="bfloat16",
+        param_dtype="bfloat16")
+    srv = PagedServer(cfg, init(jax.random.PRNGKey(0), cfg),
+                      PagedServeConfig(max_len=32, num_slots=2, page_size=8,
+                                       num_pages=16, retries=1,
+                                       backoff_base_s=0.0))
+    srv.submit(0, np.arange(7) % cfg.vocab_size, 4)
+    assert srv._admit_one()
+    free = srv.alloc._free[0]
+    assert all(free not in r.pages for r in srv.slots if r is not None)
+    leaves, tree = jax.tree_util.tree_flatten(srv.pools)
+    assert leaves[0].dtype == jnp.bfloat16
+    pool = leaves[0]                  # ([groups,] pages, 2*Hkv, ps, d)
+    leaves[0] = pool.at[(0,) * (pool.ndim - 4) + (free, 0, 0, 0)].set(
+        jnp.nan)
+    srv.pools = jax.tree_util.tree_unflatten(tree, leaves)
+    with pytest.raises(GuardExhausted, match="non-finite"):
+        srv.step()
+    bad = [e for e in srv.events
+           if getattr(e, "kind", None) == "validation"]
+    assert bad and all(e.name == "serve.decode" for e in bad)
+    assert "1 non-finite values in leaf 1/" in bad[0].error
+    assert len(bad) == 2 * len(srv.ladder.rungs)   # 1 + retries per rung
+    assert [e["kind"] for e in srv.events if isinstance(e, dict)] == \
+        ["admit", "degrade"]
+
+
 # ---------------------------------------------------------------------------
 # GuardedCall
 # ---------------------------------------------------------------------------

@@ -126,11 +126,12 @@ def test_guard_validate_counts_the_host_copy(served_trace):
               for c in _inside(spans, s, {"guard.call"})
               for v in _inside(spans, c, {"guard.validate"})]
     assert decode
+    logits = srv.scfg.num_slots * srv.cfg.vocab_size * 4    # (B,1,V) f32
     for v in decode:
         c = v[3]
-        assert c["bytes_to_host"] > pools      # the pools and the logits
-        assert 0 <= c["bytes_screened"] <= c["bytes_to_host"]
-        assert c["leaves"] >= 2
+        assert c["bytes_screened"] == pools + logits
+        assert c["screened_on_device"] == c["leaves"] >= 2
+        assert c["bytes_to_host"] == c["leaves"]  # one flag a leaf
 
 
 def test_program_spans_are_documented_and_not_the_benchmarks(served_trace):
@@ -271,5 +272,6 @@ def test_validate_finite_counts_what_it_copies_and_screens():
            "h": jnp.ones((16,), jnp.bfloat16),
            "i": np.arange(5, dtype=np.int32)}
     assert validate_finite(out) == {"leaves": 3,
-                                    "bytes_to_host": 128 + 32 + 20,
-                                    "bytes_screened": 128}
+                                    "bytes_to_host": 2,
+                                    "bytes_screened": 128 + 32,
+                                    "screened_on_device": 2}
