@@ -28,6 +28,8 @@ ARCHS = [
 
 _MODULES = {a: a.replace("-", "_").replace(".", "_") for a in ARCHS}
 _MODULES["quickstart"] = "quickstart"
+# served on one chip by the benchmark, outside the dry-run's assignment
+_MODULES["deepseek-v2-lite-16b"] = "deepseek_v2_lite_16b"
 
 # input shapes assigned to the LM-family pool (seq_len x global_batch)
 SHAPES = {

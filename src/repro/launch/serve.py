@@ -382,7 +382,13 @@ class PagedServer:
     :class:`Server`.  Repeated decode failure walks the degradation
     ladder paged-blockspace -> paged-xla (the
     :func:`~repro.models.attention.decode_attention_paged_xla` gather
-    rung), re-jitting the step like :meth:`Server._apply_rung` does.
+    rung), re-jitting the step like :meth:`Server._apply_rung` does;
+    for latent (MLA) pools that rung is the XLA decode over the gathered
+    latents (:func:`~repro.kernels.latent_decode.latent_decode_xla`).
+
+    Under a held-experts share (``cfg.experts_held``) the decode step
+    also returns the routes each held expert computed, per MoE layer;
+    ``moe_routes_held`` keeps their sum for every step served.
     """
 
     _guarded = Server._guarded
@@ -404,6 +410,7 @@ class PagedServer:
         self.steps_served = 0
         self.fragmentation_sum = 0.0
         self.peak_utilization = 0.0
+        self.moe_routes_held: list = []   # per step, held experts
         self._paged_lib = paged_lib
         self.alloc = paged_lib.PagedKVPool(scfg.num_pages, scfg.page_size)
         self.max_pages = -(-scfg.max_len // scfg.page_size)
@@ -623,7 +630,7 @@ class PagedServer:
                 act[i] = True
             inputs = (jnp.asarray(toks), jnp.asarray(self.table),
                       jnp.asarray(posv), jnp.asarray(act))
-        logits, pools = self._decode_step(*inputs)
+        logits, pools, *loads = self._decode_step(*inputs)
         with span("serve.release"):
             # frees the previous pools on the device, in a span of its
             # own so that the step's bookkeeping shows what it costs
@@ -631,8 +638,14 @@ class PagedServer:
         # advance every slot before any finish check: the decode step
         # already wrote position next_pos for all of them, so a
         # mid-loop _verify_table must not see a stale next_pos
+        moe = {}
         with span("serve.sample", slots=len(active)):
             logits = np.asarray(logits)
+            if loads:
+                loads = np.asarray(loads[0])
+                moe = {"moe_routes_held": int(loads.sum()),
+                       "moe_max_load": int(loads.max())}
+                self.moe_routes_held.append(moe["moe_routes_held"])
             sampled = []
             for i in active:
                 req = self.slots[i]
@@ -654,7 +667,7 @@ class PagedServer:
             preempted=preempted, pages_in_use=stats["used_pages"],
             free_pages=stats["free_pages"],
             live_tokens=stats["live_tokens"],
-            alloc_tokens=stats["alloc_tokens"])
+            alloc_tokens=stats["alloc_tokens"], **moe)
         return True
 
     def _grow_all(self, active: list) -> int:

@@ -33,6 +33,14 @@ class ModelConfig:
     local_window: int = 1024
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    # YaRN rope scaling (arXiv:2309.00071, as DeepSeek-V2 publishes it);
+    # yarn_factor 0 means plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # MLA (DeepSeek-V2)
     use_mla: bool = False
@@ -53,6 +61,17 @@ class ModelConfig:
     first_dense: int = 0           # first K layers use dense FFN regardless
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.001
+    # routing: "greedy" top-k over softmax scores is the one implemented;
+    # the top-k gates are renormalised to sum 1 only under norm_topk_prob
+    topk_method: str = "greedy"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the held-experts share (expert parallelism): this chip holds the
+    # routed experts [experts_first, experts_first + experts_held) and
+    # computes their part of the layer, dropless; 0 = the capacity-
+    # dispatched layer over all n_experts
+    experts_first: int = 0
+    experts_held: int = 0
 
     # SSM
     ssm_kind: Optional[str] = None  # None | mamba1 | mamba2
@@ -128,6 +147,39 @@ class ModelConfig:
         via attn_schedule_resolved)."""
         return self.grid_lowering or "closed_form"
 
+    @property
+    def yarn(self):
+        """The YaRN parameters as a hashable tuple (factor, original max
+        positions, beta_fast, beta_slow, mscale, mscale_all_dim), or
+        None for plain rope."""
+        if not self.yarn_factor:
+            return None
+        return (float(self.yarn_factor), int(self.yarn_original_max_pos),
+                float(self.yarn_beta_fast), float(self.yarn_beta_slow),
+                float(self.yarn_mscale), float(self.yarn_mscale_all_dim))
+
+    @property
+    def mla_softmax_scale(self) -> float:
+        """(qk_nope + qk_rope)^-1/2, times mscale(factor,
+        mscale_all_dim)^2 under YaRN (DeepSeek-V2's attention scale)."""
+        from .layers import yarn_get_mscale
+        scale = (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+        if self.yarn and self.yarn_mscale_all_dim:
+            m = yarn_get_mscale(self.yarn_factor, self.yarn_mscale_all_dim)
+            scale *= m * m
+        return float(scale)
+
+    @property
+    def latent_width(self) -> int:
+        """One token's row in an MLA layer's paged pool: the compressed
+        kv latent and the shared roped key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def n_experts_local(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.experts_held or self.n_experts
+
     def attn_kind(self, layer: int) -> str:
         return self.attn_pattern[layer % len(self.attn_pattern)]
 
@@ -199,7 +251,7 @@ class ModelConfig:
             else:
                 fe = self.d_ff_expert or self.d_ff
                 total += d * self.n_experts  # router
-                total += self.n_experts * 3 * d * fe
+                total += self.n_experts_local * 3 * d * fe
                 total += self.n_shared_experts * 3 * d * fe
         if self.hybrid_attn_period:
             hq = self.n_heads * self.hd

@@ -8,6 +8,8 @@ name-pattern based.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -46,34 +48,83 @@ def rmsnorm(params, x, eps=1e-6):
 
 
 # ---------------------------------------------------------------------------
-# RoPE (rotate-half convention)
+# RoPE (rotate-half convention), optionally YaRN-scaled
 # ---------------------------------------------------------------------------
 
-def rope(x, positions, theta=10000.0):
-    """x: (B,H,S,D) with even D; positions: (S,) int."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
-    angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
-    cos = jnp.cos(angles)[None, None]        # (1,1,S,D/2)
-    sin = jnp.sin(angles)[None, None]
+def yarn_get_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention factor 0.1 * mscale * ln(factor) + 1 (1 at
+    factor <= 1)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(d: int, theta: float, yarn) -> np.ndarray:
+    """(d/2,) float32 inverse frequencies of YaRN (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``): the plain frequencies above the
+    correction range, those divided by ``factor`` below it, a linear
+    ramp between.  ``yarn`` is ``ModelConfig.yarn``."""
+    factor, orig, beta_fast, beta_slow = yarn[:4]
+
+    def corr_dim(rotations):
+        return (d * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    expo = np.arange(0, d, 2, dtype=np.float32) / d
+    extra = 1.0 / theta ** expo
+    inter = 1.0 / (factor * theta ** expo)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def _rope_tables(d, theta, yarn):
+    """(inverse frequencies (d/2,), the cos/sin scale)."""
+    if yarn is None:
+        return (theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32)
+                          / (d // 2)), None)
+    factor, _, _, _, mscale, mscale_all = yarn
+    att = yarn_get_mscale(factor, mscale) / yarn_get_mscale(factor,
+                                                            mscale_all)
+    return jnp.asarray(yarn_inv_freq(d, theta, yarn)), (
+        None if att == 1.0 else att)
+
+
+def _rotate(x, cos, sin):
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.astype(x.dtype)
 
 
-def rope_rows(x, positions, theta=10000.0):
+def rope(x, positions, theta=10000.0, yarn=None):
+    """x: (B,H,S,D) with even D; positions: (S,) int; ``yarn``: the
+    config's YaRN tuple, or None for plain rope."""
+    freqs, att = _rope_tables(x.shape[-1], theta, yarn)
+    angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
+    cos = jnp.cos(angles)[None, None]        # (1,1,S,D/2)
+    sin = jnp.sin(angles)[None, None]
+    if att is not None:
+        cos, sin = cos * att, sin * att
+    return _rotate(x, cos, sin)
+
+
+def rope_rows(x, positions, theta=10000.0, yarn=None):
     """Per-batch-row RoPE for single-token decode: x (B,H,1,D) with even
     D; positions (B,) int, one decode position per slot.  Equals
     :func:`rope` broadcast when every row sits at the same position
     (same elementwise ops, so bitwise equal)."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
+    freqs, att = _rope_tables(x.shape[-1], theta, yarn)
     angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]
     cos = jnp.cos(angles)[:, None, None, :]      # (B,1,1,D/2)
     sin = jnp.sin(angles)[:, None, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-    return out.astype(x.dtype)
+    if att is not None:
+        cos, sin = cos * att, sin * att
+    return _rotate(x, cos, sin)
 
 
 # ---------------------------------------------------------------------------

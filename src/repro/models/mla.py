@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import attention as attn_lib
-from .layers import dense_init, rmsnorm, rope, split
+from .layers import dense_init, rmsnorm, rope, rope_rows, split
 
 
 def mla_init(key, cfg, dtype=None):
@@ -33,7 +33,9 @@ def mla_init(key, cfg, dtype=None):
     return p
 
 
-def _queries(p, x, cfg, positions):
+def _queries(p, x, cfg, positions, rows=False):
+    """(q_nope, q_rope), each (B,H,S,*); ``rows``: ``positions`` is a
+    (B,) vector, one decode position per row (S == 1)."""
     b, s, _ = x.shape
     h, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
     if cfg.q_lora_rank:
@@ -44,17 +46,19 @@ def _queries(p, x, cfg, positions):
         q = x @ p["wq"].astype(x.dtype)
     q = q.reshape(b, s, h, dn + dr).transpose(0, 2, 1, 3)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = (rope_rows if rows else rope)(q_rope, positions,
+                                           cfg.rope_theta, cfg.yarn)
     return q_nope, q_rope
 
 
-def _latents(p, x, cfg, positions):
+def _latents(p, x, cfg, positions, rows=False):
     """Compressed kv latent + roped shared key.  c_kv: (B,S,L); k_rope
     (B,1,S,dr)."""
     kv_a = x @ p["wkv_a"].astype(x.dtype)
     c_kv, k_rope = kv_a[..., :cfg.kv_lora_rank], kv_a[..., cfg.kv_lora_rank:]
     c_kv = rmsnorm({"scale": p["kv_norm"]}, c_kv, cfg.norm_eps)
-    k_rope = rope(k_rope[:, None], positions, cfg.rope_theta)  # (B,1,S,dr)
+    k_rope = (rope_rows if rows else rope)(
+        k_rope[:, None], positions, cfg.rope_theta, cfg.yarn)  # (B,1,S,dr)
     return c_kv, k_rope
 
 
@@ -74,7 +78,7 @@ def mla_block(p, x, cfg, positions, *, return_cache=False):
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
 
     o = attn_lib.attention(
-        q, k, v, kind="causal", scale=1.0 / np.sqrt(dn + dr),
+        q, k, v, kind="causal", scale=cfg.mla_softmax_scale,
         chunk=cfg.attn_chunk, schedule=cfg.attn_schedule_resolved,
         flash_threshold=cfg.flash_threshold)
     o = o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
@@ -109,7 +113,7 @@ def mla_decode(p, x, cfg, cache, pos):
                    c_cache.astype(jnp.float32))
     s += jnp.einsum("bhqd,bsd->bhqs", q_rope.astype(jnp.float32),
                     r_cache.astype(jnp.float32))
-    s *= 1.0 / np.sqrt(dn + dr)
+    s *= cfg.mla_softmax_scale
     kpos = jnp.arange(c_cache.shape[1])[None, None, None, :]
     s = jnp.where(kpos <= pos, s, attn_lib.NEG_INF)
     pr = jax.nn.softmax(s, axis=-1)
@@ -117,3 +121,44 @@ def mla_decode(p, x, cfg, cache, pos):
     o = jnp.einsum("bhql,lhd->bhqd", ctx, w_uv)      # (B,H,1,dv)
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, h * dv)
     return o @ p["wo"].astype(x.dtype), (c_cache, r_cache)
+
+
+def _absorbed_query(p, x, cfg, pos):
+    """(B,H,L+dr) decode queries at per-slot positions ``pos`` (B,):
+    ``q_nope W_uk`` beside the roped part, one row per head."""
+    h, dn = cfg.n_heads, cfg.qk_nope_dim
+    q_nope, q_rope = _queries(p, x, cfg, pos, rows=True)  # (B,H,1,dn/dr)
+    w_uk = p["wkv_b"].astype(x.dtype).reshape(
+        cfg.kv_lora_rank, h, dn + cfg.v_head_dim)[..., :dn]
+    q_abs = jnp.einsum("bhqd,lhd->bhql", q_nope, w_uk)
+    return jnp.concatenate([q_abs, q_rope.astype(q_abs.dtype)],
+                           axis=-1)[:, :, 0]
+
+
+def mla_decode_paged(p, x, cfg, pool, page_table, pos, active=None):
+    """Absorbed decode of one token per slot against a paged latent pool
+    (:func:`repro.core.paged.init_latent_pool`), every slot at its own
+    position.  x: (B,1,D); pool: (P, page_size, L+dr); page_table: (B,
+    max_pages); pos: (B,); inactive slots write to the null page.  The
+    attention runs in the ``paged_latent_decode`` kernel, or over the
+    gathered latents in XLA when ``cfg.attn_decode_kernel == "xla"``.
+    Returns (out (B,1,D), updated pool)."""
+    from repro.core import paged as paged_lib
+    from repro.kernels import latent_decode
+
+    b = x.shape[0]
+    h, dv, L = cfg.n_heads, cfg.v_head_dim, cfg.kv_lora_rank
+    q = _absorbed_query(p, x, cfg, pos)
+    c_new, kr_new = _latents(p, x, cfg, pos, rows=True)
+    row = jnp.concatenate([c_new[:, 0], kr_new[:, 0, 0].astype(
+        c_new.dtype)], axis=-1)                           # (B, L+dr)
+    pool = paged_lib.append_latent(pool, page_table, pos, row, active)
+    decode = (latent_decode.paged_latent_decode
+              if cfg.attn_decode_kernel == "blockspace"
+              else latent_decode.latent_decode_xla)
+    ctx = decode(q, pool, page_table, pos, scale=cfg.mla_softmax_scale,
+                 v_dim=L)                                 # (B,H,L)
+    w_uv = p["wkv_b"].astype(x.dtype).reshape(
+        L, h, cfg.qk_nope_dim + dv)[..., cfg.qk_nope_dim:]
+    o = jnp.einsum("bhl,lhd->bhd", ctx.astype(x.dtype), w_uv)
+    return o.reshape(b, 1, h * dv) @ p["wo"].astype(x.dtype), pool

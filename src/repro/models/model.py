@@ -189,6 +189,8 @@ def apply_layer(p, h, sig, cfg, positions, *, mode="train", cache=None,
         hn = L.rmsnorm(p["norm2"], h, cfg.norm_eps)
         if ffn == "dense":
             h = h + L.mlp(p["ffn"], hn, megatron_sp=cfg.megatron_sp)
+        elif cfg.experts_held:
+            h = h + moe_lib.moe_block_held(p["ffn"], hn, cfg)[0]
         else:
             out, a = moe_lib.moe_block(p["ffn"], hn, cfg)
             h = h + out
@@ -456,86 +458,115 @@ def prefill(params, inputs, cfg: ModelConfig, max_len: int | None = None):
 # ---------------------------------------------------------------------------
 
 def _check_paged(cfg: ModelConfig) -> None:
-    """Paged serving covers plain-attention stacks (every mixer 'attn',
-    no shared block): MLA/SSM caches are not (K, V) pages."""
+    """Paged serving covers stacks whose every mixer is plain attention
+    (fused (K, V) pages) or latent attention (one latent row per
+    token), with no weight-shared block: SSM state is not paged."""
     for i in range(cfg.n_layers):
         mixer, _, _, shared = layer_sig(cfg, i)
-        if mixer != "attn" or shared:
+        if mixer not in ("attn", "mla") or shared:
+            what = f"{mixer!r}" + (" + the weight-shared attention block"
+                                   if shared else "")
             raise ValueError(
-                f"paged serving needs an attention-only stack; layer "
-                f"{i} is {mixer!r}" + (" + shared block" if shared
-                                       else ""))
+                f"paged serving needs attention or latent-attention "
+                f"layers without a shared block; layer {i} is {what} "
+                f"(SSM state and shared blocks are not paged)")
 
 
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int):
-    """Per-layer fused-KV page pools, the paged analogue of
-    :func:`init_cache`.  One *shared* (B, max_pages) page table (built
-    by the scheduler) addresses every layer's pool: the layers hold
-    different values at identical page indices."""
+    """Per-layer page pools, the paged analogue of :func:`init_cache`:
+    fused-KV pools for attention layers, latent pools (one ``kv_lora +
+    qk_rope`` row per token) for MLA layers.  One *shared* (B,
+    max_pages) page table (built by the scheduler) addresses every
+    layer's pool: the layers hold different values at identical page
+    indices."""
     from repro.core import paged as paged_lib
 
     _check_paged(cfg)
     prefix, period, n_groups = group_layout(cfg)
     dt = cfg.jdtype()
 
-    def one():
+    def one(i):
+        if layer_sig(cfg, i)[0] == "mla":
+            return {"mixer": paged_lib.init_latent_pool(
+                num_pages, page_size, cfg.latent_width, dt)}
         return {"mixer": paged_lib.init_pool(
             num_pages, cfg.n_kv_heads, page_size, cfg.hd, dt)}
 
     cache: Dict[str, Any] = {}
     for i in range(prefix):
-        cache[f"prefix_{i}"] = one()
+        cache[f"prefix_{i}"] = one(i)
     if n_groups:
         cache["blocks"] = {
             f"slot_{s}": jax.tree.map(
                 lambda x: jnp.broadcast_to(
-                    x[None], (n_groups,) + x.shape), one())
+                    x[None], (n_groups,) + x.shape), one(prefix + s))
             for s in range(period)}
     return cache
 
 
+def _write_pages(pool, pages, cache, mixer):
+    """One layer: a batch-1 prefill cache into its pages."""
+    from repro.core import paged as paged_lib
+    if mixer == "mla":
+        c_kv, k_rope = cache
+        return paged_lib.write_latent_pages(
+            pool, pages, jnp.concatenate(
+                [c_kv[0], k_rope[0].astype(c_kv.dtype)], axis=-1))
+    k, v = cache
+    return paged_lib.write_prefill_pages(pool, pages, k[0], v[0])
+
+
 def scatter_prefill_pages(pools, caches, pages, cfg: ModelConfig):
-    """Admission: scatter one request's prefill KV (a batch-1
+    """Admission: scatter one request's prefill cache (a batch-1
     :func:`prefill` cache pytree, S tokens) into its allocated pages
     across every layer pool.  ``pages``: (n,) i32 physical page ids,
     ``n * page_size >= S`` (tail pages zero-padded, masked by seq_pos
     at read time).  Returns the updated pools pytree."""
-    from repro.core import paged as paged_lib
-
     prefix, period, n_groups = group_layout(cfg)
     out: Dict[str, Any] = {}
     for i in range(prefix):
-        k, v = caches[f"prefix_{i}"]["mixer"]
-        out[f"prefix_{i}"] = {"mixer": paged_lib.write_prefill_pages(
-            pools[f"prefix_{i}"]["mixer"], pages, k[0], v[0])}
+        out[f"prefix_{i}"] = {"mixer": _write_pages(
+            pools[f"prefix_{i}"]["mixer"], pages,
+            caches[f"prefix_{i}"]["mixer"], layer_sig(cfg, i)[0])}
     if n_groups:
         blocks: Dict[str, Any] = {}
         for s in range(period):
-            k, v = caches["blocks"][f"slot_{s}"]["mixer"]
+            mixer = layer_sig(cfg, prefix + s)[0]
             blocks[f"slot_{s}"] = {"mixer": jax.vmap(
-                lambda p, kk, vv: paged_lib.write_prefill_pages(
-                    p, pages, kk[0], vv[0]))(
-                pools["blocks"][f"slot_{s}"]["mixer"], k, v)}
+                lambda p, c, m=mixer: _write_pages(p, pages, c, m))(
+                pools["blocks"][f"slot_{s}"]["mixer"],
+                caches["blocks"][f"slot_{s}"]["mixer"])}
         out["blocks"] = blocks
     return out
 
 
 def _paged_layer(p, h, sig, cfg, pool, page_table, pos, active):
+    """One layer of the paged decode step: (h, pool, the held experts'
+    route counts (E_held,) i32 of an MoE layer under a held share, else
+    None)."""
     mixer, akind, ffn, shared = sig
     hn = L.rmsnorm(p["norm1"], h, cfg.norm_eps)
-    out, pool = L.attn_block_decode_paged(
-        p["mixer"], hn, cfg, akind, pool, page_table, pos, active)
+    if mixer == "mla":
+        out, pool = mla_lib.mla_decode_paged(
+            p["mixer"], hn, cfg, pool, page_table, pos, active)
+    else:
+        out, pool = L.attn_block_decode_paged(
+            p["mixer"], hn, cfg, akind, pool, page_table, pos, active)
     h = h + out
     h = constrain(h, "residual")
+    loads = None
     if ffn != "none":
         hn = L.rmsnorm(p["norm2"], h, cfg.norm_eps)
         if ffn == "dense":
             h = h + L.mlp(p["ffn"], hn, megatron_sp=cfg.megatron_sp)
+        elif cfg.experts_held:
+            out, loads = moe_lib.moe_block_held(p["ffn"], hn, cfg)
+            h = h + out
         else:
             out, _ = moe_lib.moe_block(p["ffn"], hn, cfg)
             h = h + out
         h = constrain(h, "residual")
-    return h, pool
+    return h, pool, loads
 
 
 def decode_step_paged(params, inputs, pools, page_table, pos, active,
@@ -545,35 +576,49 @@ def decode_step_paged(params, inputs, pools, page_table, pos, active,
     inputs: (B,1) tokens; page_table: (B, max_pages) i32; pos: (B,)
     per-slot positions; active: (B,) bool (inactive slots write to the
     null page and their logits are garbage the scheduler ignores).
-    Returns (logits (B,1,V), updated pools)."""
+    Returns (logits (B,1,V), updated pools); under a held-experts share
+    (``cfg.experts_held``) also the routes each held expert computed,
+    (MoE layers, E_held) i32, in layer order."""
     _check_paged(cfg)
     prefix, period, n_groups = group_layout(cfg)
     h = _embed_inputs(params, inputs, cfg)
     new_pools: Dict[str, Any] = {}
+    loads = []
 
     for i in range(prefix):
-        h, pool = _paged_layer(
+        h, pool, ld = _paged_layer(
             params[f"prefix_{i}"], h, layer_sig(cfg, i), cfg,
             pools[f"prefix_{i}"]["mixer"], page_table, pos, active)
         new_pools[f"prefix_{i}"] = {"mixer": pool}
+        if ld is not None:
+            loads.append(ld[None])
 
     if n_groups:
         sigs = [layer_sig(cfg, prefix + s_) for s_ in range(period)]
 
         def body(h, xs):
             pslots, cslots = xs
-            out_c = {}
+            out_c, out_l = {}, []
             for s_ in range(period):
-                h, pool = _paged_layer(
+                h, pool, ld = _paged_layer(
                     pslots[f"slot_{s_}"], h, sigs[s_], cfg,
                     cslots[f"slot_{s_}"]["mixer"], page_table, pos,
                     active)
                 out_c[f"slot_{s_}"] = {"mixer": pool}
-            return h, out_c
+                if ld is not None:
+                    out_l.append(ld)
+            return h, (out_c, out_l)
 
-        h, blocks_cache = jax.lax.scan(
+        h, (blocks_cache, block_loads) = jax.lax.scan(
             body, h, (params["blocks"], pools["blocks"]))
         new_pools["blocks"] = blocks_cache
+        if block_loads:
+            # (groups, E) per MoE slot -> layer order g * period + slot
+            loads.append(jnp.stack(block_loads, axis=1).reshape(
+                -1, block_loads[0].shape[-1]))
 
     h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    return L.lm_head(params["lm_head"], h), new_pools
+    logits = L.lm_head(params["lm_head"], h)
+    if cfg.experts_held:
+        return logits, new_pools, jnp.concatenate(loads, axis=0)
+    return logits, new_pools

@@ -14,14 +14,19 @@ Spans, parents first, and what their counters mean:
     ``step``: steps this server has run before this one; ``active``:
     slots decoding when the step begins; at its end ``preempted``:
     requests preempted to make room; ``pages_in_use``, ``free_pages``,
-    ``live_tokens``, ``alloc_tokens``: the pool after the step.
+    ``live_tokens``, ``alloc_tokens``: the pool after the step; under a
+    held-experts share (``ModelConfig.experts_held``) also
+    ``moe_routes_held``, the step's routes that landed on held experts
+    (every slot, every MoE layer), and ``moe_max_load``, the most routes
+    one held expert of one layer computed.
 
     ``serve.grow``: page growth and preemption.  ``serve.inputs``: the
     host arrays of tokens, positions and the active mask, and their
     uploads with the page table.  ``guard.call`` (below) around the
     decode call.  ``serve.release``: the previous pools dropped.
-    ``serve.sample`` (``slots``): the logits' copy to the host and each
-    slot's sampling.  ``serve.table``: finished requests freed,
+    ``serve.sample`` (``slots``): the logits' copy to the host (then,
+    under a held-experts share, the decode step's per-layer route
+    counts) and each slot's sampling.  ``serve.table``: finished requests freed,
     ``verify_page_table`` and the pool's statistics.
 
 ``serve.admit`` -- one admission by ``PagedServer._admit_one``

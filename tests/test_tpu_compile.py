@@ -111,3 +111,20 @@ def test_paged_decode_compiles_for_v5e_at_phi3_widths(one_chip):
         _spec(one_chip, (slots, pages), jnp.int32),
         _spec(one_chip, (slots,), jnp.int32))
     assert "tpu_custom_call" in txt
+
+
+def test_paged_latent_decode_compiles_for_v5e_at_deepseek_v2_lite_widths(
+        one_chip):
+    # DeepSeek-V2-Lite: 16 heads, latent rows of 512 + 64; 16 slots of
+    # 384 pages of 16 tokens in a 6145-page pool
+    from repro.kernels.latent_decode import paged_latent_decode
+    slots, heads, width, pages, page = 16, 16, 576, 384, 16
+    txt = _compile_text(
+        lambda q, pool, pt, pos: paged_latent_decode(
+            q, pool, pt, pos, scale=0.1, v_dim=512, backend="tpu"),
+        _spec(one_chip, (slots, heads, width), jnp.bfloat16),
+        _spec(one_chip, (slots * pages + 1, page, width), jnp.bfloat16),
+        _spec(one_chip, (slots, pages), jnp.int32),
+        _spec(one_chip, (slots,), jnp.int32))
+    assert "tpu_custom_call" in txt
+    assert "paged_latent_decode" in txt
