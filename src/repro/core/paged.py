@@ -29,11 +29,12 @@ This module supplies the three pieces:
 Device-side layout helpers
     The pool array is ``(num_pages, 2*Hkv, page_size, d)`` with the K
     and V heads *interleaved* on the head axis (``[K0,V0,K1,V1,...]``):
-    one page-tile read of head-block ``h`` (a ``(1, 2, page_size, d)``
-    BlockSpec block at head index ``h``) feeds both attention operands,
-    halving the page-table resolves and keeping K/V of one head in one
-    contiguous DMA.  :func:`fuse_kv` / :func:`split_kv` convert between
-    this layout and the separate ``(B, Hkv, S, d)`` caches;
+    one physical page holds every head's K and V of its tokens, so the
+    TPU decode kernel copies it in one ``(1, 2*Hkv, page_size, d)``
+    block per (slot, page), and the gpu structure reads one head's K/V
+    as one contiguous ``(2, page_size, d)`` load.  :func:`fuse_kv` /
+    :func:`split_kv` convert between this layout and the separate
+    ``(B, Hkv, S, d)`` caches;
     :func:`gather_kv` is the XLA gather that reconstructs a contiguous
     cache from the pool (the oracle the bit-identity tests and the
     degradation ladder's paged-xla rung share); :func:`append_token` /
@@ -65,28 +66,37 @@ class PagedPlan(GridPlan):
     leading HBM operand on gpu structures.  Index maps reach it as
     ``refs[0]`` (see :meth:`GridPlan._index_spec`); the base LUT, when
     the lowering is table-backed, remains ``refs[-1]`` so the inherited
-    decode is untouched."""
+    decode is untouched.  ``seq_pos``, the ``(num_slots,)`` i32 decode
+    positions, when given rides as ``refs[1]``, so index maps can bound
+    a slot's pages by its length."""
 
-    def __init__(self, *args, page_table=None, **kwargs):
+    def __init__(self, *args, page_table=None, seq_pos=None, **kwargs):
         super().__init__(*args, **kwargs)
         if page_table is None:
             raise ValueError("PagedPlan requires page_table=")
         self.page_table = page_table
+        self.seq_pos = seq_pos
+
+    @property
+    def _lead(self) -> tuple:
+        if self.seq_pos is None:
+            return (self.page_table,)
+        return (self.page_table, self.seq_pos)
 
     @property
     def num_scalar_prefetch(self) -> int:
-        return super().num_scalar_prefetch + 1
+        return super().num_scalar_prefetch + len(self._lead)
 
     def bound_prefetch(self):
-        # not super(): the base implementation keys off the (now +1)
+        # not super(): the base implementation keys off the (now larger)
         # num_scalar_prefetch and would bind a table for non-table
         # lowerings too.  The base LUT binds iff the base decode is
-        # table-backed, and always *after* the page table.
+        # table-backed, and always *after* the leading operands.
         base = ()
         if self._table_backed:
             base = (self.mma_table() if self.lowering == "mma"
                     else self.lut(),)
-        return (self.page_table,) + base
+        return self._lead + base
 
 
 # ---------------------------------------------------------------------------

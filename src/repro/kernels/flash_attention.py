@@ -656,22 +656,79 @@ def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
 # paged decode: the page table rides the scalar-prefetch LUT mechanism
 # ---------------------------------------------------------------------------
 
-def _paged_attn_kernel(coords, *refs, window, scale, page_size, h,
-                       has_window):
-    """Block-indexed (TPU) paged decode kernel.  One grid step per
-    (slot*head, logical key block); the *physical* page was already
-    resolved by the KV BlockSpec index map reading the prefetched page
-    table, so the kernel sees a ``(1, 2, page_size, d)`` fused tile:
-    row 0 of the head-pair axis is K, row 1 is V.  Masking uses the
-    *logical* block id (``coords.bx``), so results are bit-identical to
-    the contiguous ``seq_pos`` path."""
-    q_ref, kv_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    kb = coords.bx
-    pos = pos_ref[coords.batch[0] // h]
-    start = 0 * kb
+def paged_decode_grid(slots: int, max_pages: int):
+    """The block-indexed paged decode kernel's grid: one batch dimension
+    over the slots and, per slot, the domain of its logical pages -- one
+    grid step per (slot, page), each reading every head of that page.
+    Returns ``(batch_dims, domain)`` for the kernel's
+    :class:`~repro.core.paged.PagedPlan`."""
+    return (int(slots),), make_attention_domain("full", 1, int(max_pages), 0)
+
+
+def paged_decode_grid_steps(slots: int, max_pages: int,
+                            layers: int = 1) -> int:
+    """Grid steps the block-indexed paged decode kernel runs for one
+    decode step of ``layers`` paged attention layers (every lowering
+    launches the same count: the domain is one full row of pages)."""
+    batch_dims, domain = paged_decode_grid(slots, max_pages)
+    return int(layers) * int(np.prod(batch_dims)) * domain.num_blocks
+
+
+def _paged_live_pages(pos, page_size, window):
+    """First and last logical page holding keys a slot at decode
+    position ``pos`` attends to (``window`` 0: no sliding window)."""
     end = pos // page_size
-    if has_window:
-        start = jnp.maximum(pos - window + 1, 0) // page_size
+    if window:
+        return jnp.maximum(pos - window + 1, 0) // page_size, end
+    return 0 * end, end
+
+
+def _paged_tile_vmem_bytes(h, hkv, page_size, d, itemsize):
+    """VMEM one grid step of the block-indexed paged kernel holds: the
+    double-buffered fused page tile and query block in the pool's dtype,
+    and the float32 K and V every query head reads, all at the chip's
+    tiled layout (lanes padded to 128, sublanes to the dtype's tile)."""
+    lanes = -(-d // 128) * 128
+    sub = 8 * max(1, 4 // itemsize)
+    rows = -(-page_size // sub) * sub
+    tile = 2 * hkv * rows * lanes * itemsize
+    qblk = -(-h // sub) * sub * lanes * itemsize
+    f32 = 2 * h * (-(-page_size // 8) * 8) * lanes * 4
+    return 2 * (tile + qblk) + f32
+
+
+#: VMEM the block-indexed paged kernel may plan for one grid step: the
+#: default scoped VMEM limit of a TPU v5e core
+PAGED_VMEM_BUDGET = 16 << 20
+
+
+def _paged_attn_kernel(coords, q_ref, kv_ref, o_ref, acc_ref, m_ref,
+                       l_ref, *, window, scale, page_size, group,
+                       unroll_heads):
+    """Block-indexed (TPU) paged decode kernel.  One grid step per
+    (slot, logical page), reading every head: the query block is the
+    slot's ``(1, h, d)`` rows and the KV block the page's whole
+    ``(1, 2*hkv, page_size, d)`` fused tile, whose *physical* page the
+    BlockSpec index map already resolved from the prefetched page table
+    (``coords.refs[0]``).  K of KV head ``j`` is row ``2j`` of the tile
+    and V row ``2j + 1``; query head ``i`` reads KV head ``i // group``.
+    The index map clamps the logical page into the slot's live pages, so
+    a step past the slot's last page (or before its window's first)
+    repeats a live block index and starts no copy; its compute is
+    predicated off here.  Masking uses the *logical* page id
+    (``coords.bx``).
+
+    Each head runs :func:`_attn_tile_update` on its own ``(1, d)`` row
+    and ``(page_size, d)`` K/V: the contiguous kernel's step, in its
+    shape and order, so results are bit-identical to the contiguous
+    ``seq_pos`` path.  ``unroll_heads`` unrolls the head loop where
+    Mosaic compiles it (it needs static sublane offsets); the
+    interpreter keeps it rolled, so that XLA's CPU compiler builds each
+    head's step once, as the contiguous kernel's, rather than
+    vectorising the unrolled copies' arithmetic differently."""
+    kb = coords.bx
+    pos = coords.refs[1][coords.batch[0]]
+    start, end = _paged_live_pages(pos, page_size, window)
 
     def body():
         @pl.when(kb == start)
@@ -680,22 +737,29 @@ def _paged_attn_kernel(coords, *refs, window, scale, page_size, h,
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = kv_ref[0, 0].astype(jnp.float32)
-        v = kv_ref[0, 1].astype(jnp.float32)
-        acc_new, m_new, l_new = _attn_tile_update(
-            q, k, v, acc_ref[...], m_ref[...], l_ref[...], kind="full",
-            window=window if has_window else 0, qb=0 * kb, kb=kb,
-            block_q=1, block_k=page_size, off=0, seq_pos=pos)
-        acc_ref[...] = acc_new
-        m_ref[...] = m_new
-        l_ref[...] = l_new
+        def head(i, carry):
+            j = i // group
+            rows = pl.ds(i, 1)
+            q = q_ref[0, rows].astype(jnp.float32) * scale
+            k = kv_ref[0, 2 * j].astype(jnp.float32)
+            v = kv_ref[0, 2 * j + 1].astype(jnp.float32)
+            acc_new, m_new, l_new = _attn_tile_update(
+                q, k, v, acc_ref[rows], m_ref[rows], l_ref[rows],
+                kind="full", window=window, qb=0 * kb, kb=kb, block_q=1,
+                block_k=page_size, off=0, seq_pos=pos)
+            acc_ref[rows] = acc_new
+            m_ref[rows] = m_new
+            l_ref[rows] = l_new
+            return carry
+
+        jax.lax.fori_loop(0, acc_ref.shape[0], head, 0,
+                          unroll=unroll_heads)
 
         @pl.when(kb == end)
         def _():
             l = l_ref[...]
             l = jnp.where(l == 0, 1.0, l)
-            o_ref[0, 0, ...] = (acc_ref[...] / l).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
     live = (kb <= end) & (kb >= start)
     if coords.valid is None:
@@ -794,10 +858,10 @@ def _paged_impl(q, kv_pool, page_table, seq_pos, *, window, scale,
     pos = jnp.broadcast_to(
         jnp.asarray(seq_pos, jnp.int32).reshape(-1), (b,))
 
-    domain = make_attention_domain("full", 1, m_k, 0)
+    batch_dims, domain = paged_decode_grid(b, m_k)
     if verify:
         from repro.analysis import verify_or_raise
-        verify_or_raise(GridPlan(domain, grid_mode, batch_dims=(b * h,),
+        verify_or_raise(GridPlan(domain, grid_mode, batch_dims=batch_dims,
                                  backend=target), kernel="flash")
 
     if not target.block_indexed:
@@ -808,41 +872,53 @@ def _paged_impl(q, kv_pool, page_table, seq_pos, *, window, scale,
             num_stages=num_stages)
         return call(page_table, q, kv_pool, pos)
 
-    plan = PagedPlan(domain, grid_mode, batch_dims=(b * h,),
-                     backend=target, page_table=page_table)
+    need = _paged_tile_vmem_bytes(h, hkv, page_size, d,
+                                  jnp.dtype(kv_pool.dtype).itemsize)
+    if need > PAGED_VMEM_BUDGET:
+        raise ValueError(
+            f"one step of the paged decode kernel reads every head of a "
+            f"page: {h} query heads over a (2*{hkv}, {page_size}, {d}) "
+            f"{kv_pool.dtype} page tile need {need} B of VMEM; the budget "
+            f"is PAGED_VMEM_BUDGET = {PAGED_VMEM_BUDGET} B.  Use a "
+            f"smaller page_size.")
+    plan = PagedPlan(domain, grid_mode, batch_dims=batch_dims,
+                     backend=target, page_table=page_table, seq_pos=pos)
 
-    def q_place(bx, by, bh):
-        return (bh // h, bh % h, 0, 0)
+    def slot_place(bx, by, slot):
+        return (slot, 0, 0)
 
     def kv_index(grid_ids, refs):
-        # refs[0] is the prefetched page table; the decoded bx is the
-        # *logical* key block, translated here to its physical page.
+        # refs[0] is the prefetched page table, refs[1] the positions;
+        # the decoded bx is the *logical* page, clamped into the slot's
+        # live pages (a dead step repeats its neighbour's block index,
+        # so it starts no copy) and translated to its physical page.
         _, bx, _ = plan._decode(grid_ids, refs)
-        bh = grid_ids[0]
-        page = refs[0][bh // h, bx]
-        return (page, (bh % h) // group, 0, 0)
+        slot = grid_ids[0]
+        start, end = _paged_live_pages(refs[1][slot], page_size, window)
+        page = refs[0][slot, jnp.minimum(jnp.maximum(bx, start), end)]
+        return (page, 0, 0, 0)
 
     kernel = functools.partial(
         _paged_attn_kernel, window=window, scale=scale,
-        page_size=page_size, h=h, has_window=bool(window))
+        page_size=page_size, group=group,
+        unroll_heads=not target.interpret)
     call = plan.pallas_call(
         kernel,
         in_specs=[
-            plan.block_spec((1, 1, 1, d), q_place),
-            plan._index_spec((1, 2, page_size, d), kv_index),
-            target.scalar_spec(),
+            plan.block_spec((1, h, d), slot_place),
+            plan._index_spec((1, h2, page_size, d), kv_index),
         ],
-        out_specs=plan.block_spec((1, 1, 1, d), q_place),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=plan.block_spec((1, h, d), slot_place),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         scratch_shapes=[
-            target.scratch((1, d), jnp.float32),
-            target.scratch((1, 1), jnp.float32),
-            target.scratch((1, 1), jnp.float32),
+            target.scratch((h, d), jnp.float32),
+            target.scratch((h, 1), jnp.float32),
+            target.scratch((h, 1), jnp.float32),
         ],
         num_warps=num_warps, num_stages=num_stages,
         name=PAGED_KERNEL_NAME,
     )
-    return call(q, kv_pool, pos)
+    return call(q.reshape(b, h, d), kv_pool).reshape(q.shape)
 
 
 def paged_flash_attention(q, kv_pool, page_table, seq_pos, *,
@@ -862,18 +938,23 @@ def paged_flash_attention(q, kv_pool, page_table, seq_pos, *,
                 per slot (null-page entries beyond each slot's length).
     seq_pos:    (B,) int32 per-slot decode positions (a scalar
                 broadcasts).  Keys beyond a slot's position are masked;
-                pages beyond ``pos // page_size`` are never touched on
-                the gpu structure and compute-predicated off on the TPU
-                structure.
+                pages beyond ``pos // page_size`` are never read on
+                either structure.
     window:     optional run-time sliding window anchored at seq_pos.
 
-    The page table travels exactly like the engine's decode LUT: a
-    scalar-prefetch operand on block-indexed targets (resolved in the
-    KV BlockSpec index map -- the lambda-map indirection of the paper,
-    pointed at physical memory), a leading HBM operand read in-kernel
-    on gpu structures.  Bit-identical to the contiguous
-    ``flash_attention(..., kind="full", seq_pos=...)`` path with
-    ``block_k == page_size`` when the mapped pages hold the same
+    On block-indexed (TPU) targets the grid is ``B x max_pages``
+    (:func:`paged_decode_grid`): one step per (slot, logical page), each
+    reading the page's whole fused tile for every head, so a page is
+    copied once per slot, not once per head.  The page table and the
+    positions are scalar-prefetch operands, resolved in the KV BlockSpec
+    index map -- the lambda-map indirection of the paper, pointed at
+    physical memory -- which clamps each step's logical page into the
+    slot's live pages: steps past a slot's end repeat its last block
+    index and copy nothing.  A page tile over ``PAGED_VMEM_BUDGET`` is
+    refused.  On gpu structures the table is a leading HBM operand read
+    in-kernel, one program per (slot, head).  Bit-identical to the
+    contiguous ``flash_attention(..., kind="full", seq_pos=...)`` path
+    with ``block_k == page_size`` when the mapped pages hold the same
     values."""
     target = backend_lib.resolve(backend, interpret)
     from repro.core.plan import normalize_lowering

@@ -449,6 +449,19 @@ class PagedServer:
     def _apply_rung(self, rung: dict) -> None:
         cfg = self.cfg.replace(attn_decode_kernel=rung["decode_kernel"])
         self._decode_fn = _jit_named(model_lib.decode_step_paged, cfg=cfg)
+        self.decode_grid_steps = self._grid_steps(cfg)
+
+    def _grid_steps(self, cfg: ModelConfig) -> int:
+        """Grid steps the block-indexed paged decode kernel runs in one
+        decode step under ``cfg``: its grid times the fused-KV attention
+        layers (0 where no layer runs it)."""
+        if cfg.attn_decode_kernel != "blockspace":
+            return 0
+        from repro.kernels.flash_attention import paged_decode_grid_steps
+        layers = sum(model_lib.layer_sig(cfg, i)[0] == "attn"
+                     for i in range(cfg.n_layers))
+        return paged_decode_grid_steps(self.scfg.num_slots, self.max_pages,
+                                       layers)
 
     # -- host bookkeeping ----------------------------------------------------
 
@@ -608,7 +621,8 @@ class PagedServer:
         if not active:
             return False
         with span("serve.step", step=self.steps_served,
-                  active=len(active)) as step_span:
+                  active=len(active),
+                  decode_grid_steps=self.decode_grid_steps) as step_span:
             return self._step(active, step_span)
 
     def _step(self, active: list, step_span) -> bool:

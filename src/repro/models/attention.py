@@ -408,7 +408,10 @@ def decode_attention_paged(q, kv_pool, page_table, pos, *,
 
     q: (B,H,1,D) slot queries; kv_pool: (P, 2*Hkv, page_size, D) fused
     page pool; page_table: (B, max_pages) i32; pos: (B,) per-slot
-    positions (a scalar broadcasts).  See
+    positions (a scalar broadcasts).  On the TPU the kernel runs one
+    grid step per (slot, page), reading every head of the page, and a
+    slot's steps past its last page repeat that page's block index, so
+    they copy nothing.  See
     :func:`repro.kernels.flash_attention.paged_flash_attention`.
 
     ``mesh`` (default: the registered serving mesh) shards the *slot*

@@ -127,12 +127,25 @@ def _paged_case(b, h, hkv, smax, d, ps, lens):
     return q, k, v, pool, jnp.asarray(table), pos
 
 
+#: (slots, heads, kv heads, max length, head size) of the bit-identity
+#: cases: GQA at a small head size, and at the chip's 128 lanes both
+#: MHA (heads == kv heads, phi3's proportions) and GQA
+_GQA_D16 = (3, 4, 2, 64, 16)
+_MHA_D128 = (3, 4, 4, 64, 128)
+_GQA_D128 = (3, 4, 2, 64, 128)
+
+
 @pytest.mark.parametrize("backend", ["tpu-interpret", "gpu-interpret"])
 @pytest.mark.parametrize("gm", ["closed_form", "prefetch_lut",
                                 "bounding", "mma"])
-@pytest.mark.parametrize("ps", [8, 16])
-def test_paged_decode_bit_identical_to_contiguous(backend, gm, ps):
-    b, h, hkv, smax, d = 3, 4, 2, 64, 16
+@pytest.mark.parametrize("ps,shape", [
+    pytest.param(8, _GQA_D16, id="8"),
+    pytest.param(16, _GQA_D16, id="16"),
+    pytest.param(16, _MHA_D128, id="16-mha-d128"),
+    pytest.param(16, _GQA_D128, id="16-gqa-d128"),
+])
+def test_paged_decode_bit_identical_to_contiguous(backend, gm, ps, shape):
+    b, h, hkv, smax, d = shape
     q, k, v, pool, table, pos = _paged_case(
         b, h, hkv, smax, d, ps, lens=[37, 63, 9])
     # bitwise oracle: the contiguous flash decode at the same block
@@ -150,17 +163,120 @@ def test_paged_decode_bit_identical_to_contiguous(backend, gm, ps):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("backend", ["tpu-interpret", "gpu-interpret"])
-def test_paged_decode_local_window(backend):
+class _EmitLog:
+    """Emit hook that keeps every interpreted launch's record."""
+
+    def __init__(self):
+        self.records = []
+
+    def instrument(self, record, kernel, in_specs, out_specs):
+        self.records.append(record)
+        return kernel, in_specs, out_specs
+
+    def wrap_call(self, record, fn):
+        return fn
+
+
+def _emitted(fn):
+    """Run ``fn`` with the paged kernel re-traced; the emit records."""
+    import importlib
+
+    from repro.core import backend as backend_lib
+    FA = importlib.import_module("repro.kernels.flash_attention")
+    FA._paged_impl.clear_cache()
+    log = _EmitLog()
+    prev = backend_lib.set_emit_hook(log)
+    try:
+        out = fn()
+    finally:
+        backend_lib.set_emit_hook(prev)
+    return out, log.records
+
+
+def _nan_dead_pages(pool, table, pos, ps, window):
+    """Point every table entry outside each slot's live pages (past its
+    last, or before its window's first) at a page of NaNs."""
+    table = np.array(table)
+    nan_page = pool.shape[0]
+    pool = jnp.concatenate(
+        [pool, jnp.full((1,) + pool.shape[1:], jnp.nan, pool.dtype)])
+    live = []
+    for i, p in enumerate(np.asarray(pos)):
+        first = max(int(p) - window + 1, 0) // ps if window else 0
+        last = int(p) // ps
+        live.append(set(table[i, first:last + 1].tolist()))
+        table[i, :first] = nan_page
+        table[i, last + 1:] = nan_page
+    return pool, jnp.asarray(table), nan_page, live
+
+
+@pytest.mark.parametrize("backend,dead", [
+    pytest.param("tpu-interpret", None, id="tpu-interpret"),
+    pytest.param("gpu-interpret", None, id="gpu-interpret"),
+    pytest.param("tpu-interpret", "nan", id="tpu-interpret-nan-dead-pages"),
+    pytest.param("gpu-interpret", "nan", id="gpu-interpret-nan-dead-pages"),
+])
+def test_paged_decode_local_window(backend, dead):
     b, h, hkv, smax, d, ps = 2, 2, 2, 64, 16, 8
     q, k, v, pool, table, pos = _paged_case(
         b, h, hkv, smax, d, ps, lens=[50, 23])
     want = A.decode_attention_flash(q, k, v, pos, kind="local",
                                     window=16, block_k=ps,
                                     backend=backend)
-    got = A.decode_attention_paged(q, pool, table, pos, window=16,
-                                   backend=backend)
+    if dead:
+        pool, table, nan_page, live = _nan_dead_pages(
+            pool, table, pos, ps, window=16)
+    got, records = _emitted(lambda: A.decode_attention_paged(
+        q, pool, table, pos, window=16, backend=backend))
+    assert np.isfinite(np.asarray(got)).all()
     assert np.array_equal(np.asarray(got), np.asarray(want))
+    if dead and backend == "tpu-interpret":
+        # the KV operand's index map, evaluated at every grid step,
+        # names only the slot's live pages: a dead step repeats a live
+        # block index, so the NaN page is never copied in
+        rec, = records
+        plan, kv_spec = rec.plan, rec.in_specs[1]
+        refs = (np.asarray(table), np.asarray(pos))
+        for slot, t in np.ndindex(*plan.grid):
+            page = int(kv_spec.index_map(slot, t, *refs)[0])
+            assert page != nan_page and page in live[slot], (slot, t)
+
+
+@pytest.mark.parametrize("gm", ["closed_form", "prefetch_lut",
+                                "bounding", "mma"])
+def test_paged_decode_grid_is_one_step_per_slot_page(gm):
+    from repro.kernels.flash_attention import (paged_decode_grid,
+                                               paged_decode_grid_steps)
+    # the phi3 cell: 8 slots x 32 pages, every head in one step
+    assert paged_decode_grid_steps(8, 32) == 8 * 32
+    assert paged_decode_grid_steps(8, 32, layers=32) == 8_192
+    b, h, hkv, smax, d, ps = 3, 4, 2, 32, 16, 8
+    q, k, v, pool, table, pos = _paged_case(
+        b, h, hkv, smax, d, ps, lens=[20, 31, 3])
+    _, records = _emitted(lambda: A.decode_attention_paged(
+        q, pool, table, pos, grid_mode=gm, backend="tpu-interpret"))
+    rec, = records
+    batch_dims, domain = paged_decode_grid(b, smax // ps)
+    assert rec.plan.batch_dims == batch_dims == (b,)
+    assert rec.plan.domain.bounding_box == domain.bounding_box \
+        == (smax // ps, 1)
+    assert rec.plan.num_steps == paged_decode_grid_steps(b, smax // ps) \
+        == b * (smax // ps)
+
+
+def test_paged_decode_refuses_a_tile_over_the_vmem_budget():
+    from repro.kernels.flash_attention import (PAGED_VMEM_BUDGET,
+                                               paged_flash_attention)
+    b, h, ps, d = 2, 64, 512, 128
+    with pytest.raises(ValueError, match="PAGED_VMEM_BUDGET"):
+        jax.eval_shape(
+            lambda q, kv, pt, pos: paged_flash_attention(
+                q, kv, pt, pos, backend="tpu-interpret"),
+            jax.ShapeDtypeStruct((b, h, 1, d), jnp.float32),
+            jax.ShapeDtypeStruct((9, 2 * h, ps, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, 4), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32))
+    assert PAGED_VMEM_BUDGET == 16 << 20
 
 
 def test_paged_decode_slot_sharded_bit_identical():
@@ -414,6 +530,28 @@ def test_paged_server_ladder_blockspace_to_xla():
     assert faulty.ladder.current()["decode_kernel"] == "xla"
     for rid in want:
         assert np.array_equal(out[rid], want[rid]), rid
+
+
+@pytest.mark.parametrize("arch,kernel,attn_layers", [
+    ("quickstart", "blockspace", 2),
+    ("quickstart", "xla", 0),
+    ("deepseek-v2-lite-16b", "blockspace", 0),   # latent layers only
+])
+def test_paged_server_counts_the_decode_kernels_grid_steps(
+        arch, kernel, attn_layers):
+    from repro.configs import get_config
+    from repro.kernels.flash_attention import paged_decode_grid_steps
+    from repro.launch.serve import PagedServeConfig, PagedServer
+    from repro.models import init
+    cfg = get_config(arch, smoke=True).replace(attn_decode_kernel=kernel)
+    params = init(jax.random.PRNGKey(0), cfg)
+    srv = PagedServer(cfg, params, PagedServeConfig(
+        max_len=40, num_slots=3, page_size=8, num_pages=16))
+    # 3 slots x 5 pages a layer, for each fused-KV attention layer
+    assert srv.decode_grid_steps == paged_decode_grid_steps(
+        3, 5, attn_layers) == 15 * attn_layers
+    srv._apply_rung({"decode_kernel": "xla"})     # the ladder's next rung
+    assert srv.decode_grid_steps == 0
 
 
 def test_paged_throughput_report_fields():

@@ -113,6 +113,25 @@ def test_paged_decode_compiles_for_v5e_at_phi3_widths(one_chip):
     assert "tpu_custom_call" in txt
 
 
+@pytest.mark.parametrize("hd", [96, 128])
+def test_paged_decode_compiles_for_v5e_at_the_phi3_cell(one_chip, hd):
+    # phi3.chat-decode-c8: 8 slots of 32 pages of 16 tokens in a
+    # 265-page pool, 32 query and kv heads.  Each grid step takes a
+    # slot's (32, hd) queries and a page's whole fused tile, 64 rows of
+    # 16 x hd bf16: 256 KB at the chip's 128 lanes (head size 96 is laid
+    # out at 128, and 128 is the padded size itself)
+    slots, heads, pages, page, pool = 8, 32, 32, 16, 265
+    txt = _compile_text(
+        lambda q, kv, pt, pos: paged_flash_attention(
+            q, kv, pt, pos, grid_mode="closed_form", backend="tpu"),
+        _spec(one_chip, (slots, heads, 1, hd), jnp.bfloat16),
+        _spec(one_chip, (pool, 2 * heads, page, hd), jnp.bfloat16),
+        _spec(one_chip, (slots, pages), jnp.int32),
+        _spec(one_chip, (slots,), jnp.int32))
+    assert "tpu_custom_call" in txt
+    assert "_paged_impl_decode" in txt
+
+
 def test_paged_latent_decode_compiles_for_v5e_at_deepseek_v2_lite_widths(
         one_chip):
     # DeepSeek-V2-Lite: 16 heads, latent rows of 512 + 64; 16 slots of

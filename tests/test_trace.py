@@ -105,6 +105,8 @@ def test_serving_spans_nest_and_cover_each_step(served_trace):
                                - sum(k[2] - k[1] for k in kids)))
         assert {"step", "active", "pages_in_use", "preempted"} \
             <= set(st[3]), st[3]
+        # the xla decode rung runs no paged kernel grid
+        assert st[3]["decode_grid_steps"] == srv.decode_grid_steps == 0
     first = srv.steps_served - len(steps)     # the warm-up's steps
     assert [st[3]["step"] for st in steps] == \
         list(range(first, srv.steps_served))
