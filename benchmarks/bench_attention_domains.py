@@ -52,19 +52,14 @@ def run_kernel_lowerings(iters: int = 5):
 
 
 def run_backend_ab(iters: int = 5):
-    """Flash-kernel backend A/B: compact vs bounding lowering per
-    emission target (the gpu rows time the row-loop Triton structure;
-    under the interpreter they validate it, on CUDA they measure
-    it)."""
+    """Flash-kernel A/B on the default emission target: compact vs
+    bounding lowering."""
     from repro.core import backend as backend_lib
-    default = backend_lib.resolve(None)
-    other = (backend_lib.GPU if default.kind == "tpu"
-             else backend_lib.TPU).emulated()
     print("# Pallas flash kernel: backend x lowering A/B (causal)")
     rng = np.random.default_rng(0)
     s, bq = 256, 64
     q = jnp.asarray(rng.normal(size=(1, 2, s, 32)), jnp.float32)
-    for tname in (default.name, other.name):
+    for tname in (backend_lib.resolve(None).name,):
         times = {}
         for low in ("closed_form", "bounding"):
             fn = functools.partial(ops.flash_attention, kind="causal",

@@ -117,8 +117,7 @@ def run_sched_ab(iters: int = 3, steps: int = 16, cases=((128, 8),)):
 def run_overlap_ab(iters: int = 3, steps: int = 8,
                    cases=((1024, 128), (4096, 128))):
     """Pipelining A/B: the fused CA launch with ``num_stages=2`` (DMA
-    double buffers on the TPU structure; Triton stage knob on a
-    compiled gpu) vs the synchronous ``num_stages=1`` path, at sizes
+    double buffers) vs the synchronous ``num_stages=1`` path, at sizes
     where tile traffic matters.  Outputs are asserted bit-identical
     before timing.  With >= 2 devices the sharded run is also A/B'd --
     there ``num_stages=2`` additionally overlaps the ppermute halo

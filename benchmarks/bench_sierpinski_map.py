@@ -121,19 +121,13 @@ def run_storage_ab(iters: int = 5):
 
 
 def run_backend_ab(iters: int = 5):
-    """Per-backend lambda(omega)-vs-bounding A/B on the Pallas write
-    and CA kernels -- the paper's figure-level comparison, once per
-    emission structure (:mod:`repro.core.backend`).  Rows cover the
-    platform-default target plus the *other* structure emulated, so the
-    artifact always carries both; on a CUDA machine the ``gpu`` rows
-    time compiled Triton."""
+    """lambda(omega)-vs-bounding A/B on the Pallas write and CA kernels
+    -- the paper's figure-level comparison -- on the default emission
+    target (:mod:`repro.core.backend`)."""
     from repro.core import backend as backend_lib
     from repro.kernels.sierpinski_ca import ca_run
 
-    default = backend_lib.resolve(None)
-    other = (backend_lib.GPU if default.kind == "tpu"
-             else backend_lib.TPU).emulated()
-    targets = (default.name, other.name)
+    targets = (backend_lib.resolve(None).name,)
     print("# backend A/B: lambda(omega) compact grids vs bounding-box,")
     print(f"#   per emission target ({', '.join(targets)})")
     n, rho = 64, 8
@@ -170,7 +164,7 @@ def run_backend_ab(iters: int = 5):
 def run_map_mma_ab(iters: int = 5):
     """``map_mma/*``: the raw lambda decode itself, digit-basis matmul
     (:mod:`repro.core.mma`, what the ``mma`` lowering computes on the
-    MXU / tensor cores) vs the integer closed form -- the map cost in
+    MXU) vs the integer closed form -- the map cost in
     isolation, without a kernel around it."""
     from repro.core import mma
 

@@ -75,8 +75,7 @@ def run_metadata() -> dict:
         "jaxlib": jaxlib.__version__,
         "backend": jax.default_backend(),
         # the kernel-emission target (repro.core.backend) the run's
-        # Pallas calls defaulted to -- gpu-interpret CI rows stay
-        # distinguishable from tpu-interpret ones
+        # Pallas calls defaulted to
         "kernel_target": backend_lib.resolve(None).name,
         "device_count": len(devs),
         "device_kinds": sorted({d.device_kind for d in devs}),

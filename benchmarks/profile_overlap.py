@@ -1,18 +1,13 @@
-"""DMA-vs-compute overlap profile for the pipelined kernels.
+"""DMA-vs-compute overlap profile for the pipelined fused CA kernel.
 
-For each kernel the harness separates the launch time into a *traffic*
-estimate and a *compute* estimate, then measures how much of the
-traffic the ``num_stages >= 2`` software pipeline actually hides:
+The harness separates the launch time into a *traffic* estimate and a
+*compute* estimate, then measures how much of the traffic the
+``num_stages >= 2`` software pipeline actually hides.  Traffic is
+measured directly: the fused launch is rerun with ``steps_scalar = 0``,
+which streams every supertile through the same DMA path but runs zero
+trapezoid iterations; ``compute = sync - traffic``.
 
-  * ca:     traffic is measured directly -- the fused launch is rerun
-    with ``steps_scalar = 0``, which streams every supertile through
-    the same DMA path but runs zero trapezoid iterations;
-    ``compute = sync - traffic``.
-  * flash:  traffic is the pure-bandwidth lower bound of one K + V
-    sweep (a timed XLA reduction over both operands);
-    ``compute = sync - traffic``.
-
-Reported per kernel:
+Reported:
 
   ``occupancy = (traffic + compute) / pipelined`` -- how many seconds
   of serialized work each pipelined second retires (1.0 = nothing
@@ -21,7 +16,7 @@ Reported per kernel:
   fraction of the traffic estimate the pipeline removed from the
   critical path.
 
-Interpret-mode numbers characterize the emulated structures (the
+Interpret-mode numbers characterize the emulated kernel (the
 interpreter serializes real DMA), so on CPU the value of this harness
 is the trend across stages and sizes, not absolute microseconds; on
 real accelerators the same rows measure true overlap.
@@ -97,45 +92,11 @@ def profile_ca(n: int = 1024, block: int = 128, fuse: int = 8,
                     t_sync, t_pipe, f"fuse={fuse};stages={stages}")
 
 
-def profile_flash(sq: int = 1024, d: int = 64, block: int = 128,
-                  heads: int = 2, stages=(2, 4), iters: int = 3):
-    from repro.kernels.flash_attention import flash_attention
-
-    print(f"# profile_overlap flash: sq={sq} d={d} block={block} "
-          f"(gpu structure KV FIFO)")
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(size=(1, heads, sq, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(1, heads, sq, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(1, heads, sq, d)), jnp.float32)
-
-    def run1(s):
-        return flash_attention(q, k, v, kind="causal", block_q=block,
-                               block_k=block, num_stages=s,
-                               backend="gpu-interpret")
-
-    ref = np.asarray(run1(1))
-    t_sync = time_fn(run1, 1, warmup=1, iters=iters)
-    # pure-bandwidth lower bound of one K + V sweep
-    sweep = jax.jit(lambda k, v: jnp.sum(k) + jnp.sum(v))
-    t_traffic = min(time_fn(sweep, k, v, warmup=1, iters=iters), t_sync)
-    best = t_sync, 1
-    for s in stages:
-        assert np.allclose(np.asarray(run1(s)), ref, atol=0, rtol=0)
-        t = time_fn(run1, s, warmup=1, iters=iters)
-        row(f"profile_overlap/flash/sq={sq}/d={d}/stages={s}", t,
-            f"speedup={t_sync / t:.2f}")
-        best = min(best, (t, s))
-    _occupancy_rows(f"profile_overlap/flash/sq={sq}/d={d}", t_traffic,
-                    t_sync, best[0], f"best_stages={best[1]}")
-
-
 def run(quick: bool = False):
     if quick:
         profile_ca(n=256, block=32, fuse=4, steps=4)
-        profile_flash(sq=256, block=64)
     else:
         profile_ca()
-        profile_flash()
 
 
 def main(argv=None):

@@ -3,10 +3,10 @@
 ``verifier``  -- host-side static checks over any GridPlan/ShardedPlan:
                  race freedom, exactly-once coverage, table fidelity,
                  index bounds, aliasing safety.
-``sanitizer`` -- interpret-mode access sanitizer: instruments emitted
-                 ``pallas_call``s (BlockSpec index maps, ``backend.load`` /
-                 ``backend.store``) and cross-checks the recorded traces
-                 against the statically computed read/write sets.
+``sanitizer`` -- interpret-mode access sanitizer: instruments the
+                 BlockSpec index maps of emitted ``pallas_call``s and
+                 cross-checks the recorded traces against their static
+                 evaluation.
 ``verify``    -- the CLI: ``python -m repro.analysis.verify --matrix``
                  sweeps the feature matrix and emits a JSON report.
 """

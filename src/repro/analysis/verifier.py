@@ -16,9 +16,9 @@ is also evaluable on host numpy arrays, so the verifier enumerates the
 
 ``race``
     The storage tile write-set is pairwise disjoint across live steps
-    of one launch (per device): the gpu structure stores at computed
-    offsets from unordered program ids, so a storage-index collision is
-    a data race, not just a perf bug.
+    of one launch (per device): every visited output block is written
+    back, so a storage-index collision lets one live step overwrite
+    another's tile.
 
 ``table``
     Host-built decode tables -- the 28-column LUT, packed-slot and
@@ -30,8 +30,8 @@ is also evaluable on host numpy arrays, so the verifier enumerates the
 
 ``bounds``
     ``storage_index`` / ``neighbor_index`` are evaluated over **all**
-    grid steps (dead and pad steps still drive index maps and
-    ``backend.load``) and the exact [min, max] hull per axis is checked
+    grid steps (dead and pad steps still drive index maps and DMA
+    addressing) and the exact [min, max] hull per axis is checked
     against the operand tile grid.
 
 ``alias``
@@ -180,7 +180,7 @@ def host_prefetch_refs(plan: GridPlan, device: int = 0) -> Tuple:
     if not _is_sharded(plan):
         if plan.lowering == "prefetch_lut":
             return (np.asarray(plan.lut_host()),)
-        if plan._table_backed:  # mma on a block-indexed structure
+        if plan._table_backed:  # mma
             return (np.asarray(plan.mma_table_host()),)
         return ()
     refs: Tuple = (np.asarray(plan.shard_table_host()[device]),)
@@ -359,7 +359,7 @@ def _check_bounds(plan, refs_per_device, per_device, model, findings):
                 "bounds", f"storage index hull "
                 f"rows [{r.min()}, {r.max()}] x cols "
                 f"[{c.min()}, {c.max()}] exceeds the ({nr}, {nc}) "
-                f"tile grid (some backend.load/store may go OOB)",
+                f"tile grid (some tile copy may go OOB)",
                 device=d))
         if not model["neighbors"]:
             continue
@@ -430,8 +430,7 @@ def _check_tables(plan, findings):
     exp[li, 0] = gx
     exp[li, 1] = gy
     # for mma plans, verify the digit-basis matmul table -- the exact
-    # decode both structures consume (the gpu structure evaluates the
-    # same chains in-kernel, so the table *is* the chain output)
+    # decode the kernels consume
     lut = np.asarray(plan.mma_table_host() if plan.lowering == "mma"
                      else plan.lut_host())
     bad = np.nonzero((lut[:, _LUT_BX] != exp[:, 0])
@@ -731,7 +730,7 @@ def _check_sharded_lut(plan, findings):
     """Each device's decode-table chunk must decode its slab row-major:
     chunk row t (t < count) is the member block whose packed slot is
     (t % ncols, lo + t // ncols).  Applies to every table-backed
-    lowering (prefetch_lut, or mma on block-indexed structures)."""
+    lowering (prefetch_lut, mma)."""
     D = plan.num_shards
     if plan.partition != "storage-rows":
         return
@@ -804,20 +803,14 @@ def _check_phase_views(plan, findings):
 
 
 def _check_flash_hulls(plan, findings):
-    """Flash q/k window hulls.  The gpu-structured flash kernel walks
-    key blocks ``start..end`` of each query row with an in-kernel
-    ``fori_loop``, so correctness needs (a) every block row of the
-    domain to be a *contiguous* span -- a hole would be visited and
-    attended to -- and (b) the row-extents source the lowering consumes
-    to equal the hull re-derived from membership: the host
-    ``row_extents`` table (bound under ``prefetch_lut``) and, for
-    ``mma`` plans, the device digit-basis chain
-    (:func:`repro.core.mma.row_extents_chain`).  ``closed_form``
-    computes the bounds analytically in-kernel; its hull is implied by
-    (a) plus the coverage check, and ``bounding`` walks the full range
-    with where-guards.  Both hull sources must also stay inside the
-    block grid (an out-of-range extent would clamp KV loads onto wrong
-    tiles)."""
+    """Flash q/k window hulls.  The XLA-level ``triangular`` flash
+    schedule (:mod:`repro.models.attention`) walks key blocks
+    ``start..end`` of each query row, so correctness needs (a) every
+    block row of the domain to be a *contiguous* span -- a hole would
+    be visited and attended to -- and (b) the host ``row_extents``
+    table it consumes to equal the hull re-derived from membership and
+    stay inside the block grid (an out-of-range extent would slice the
+    wrong keys)."""
     dom = plan.sched_domain
     gx, gy = members_host(dom)
     nbx, nby = dom.bounding_box
@@ -833,36 +826,26 @@ def _check_flash_hulls(plan, findings):
                 "hull", f"block row {row} has holes: the flash key "
                 f"loop over [{exp[row, 0]}, {exp[row, 1]}] would "
                 f"attend to non-member tiles"))
-    sources = [("row_extents", plan.row_extents())]
-    if plan.lowering == "mma":
-        import jax
-
-        from repro.core import mma
-        # eager: this check runs inside kernel jit traces
-        with jax.ensure_compile_time_eval():
-            chain = np.asarray(mma.row_extents_chain(plan.domain))
-        sources.append(("mma.row_extents_chain", chain))
-    for name, ext in sources:
-        ext = np.asarray(ext).astype(np.int64)
-        if ext.shape != (nby, 2):
-            findings.append(Finding(
-                "hull", f"{name} has shape {ext.shape}, expected "
-                f"{(nby, 2)}"))
-            continue
-        occ = exp[:, 1] >= exp[:, 0]
-        if np.any((ext[occ, 0] < 0) | (ext[occ, 1] >= nbx)):
-            findings.append(Finding(
-                "hull", f"{name} leaves the {nbx}-wide block grid"))
-        bad = np.nonzero((ext[:, 0] != exp[:, 0])
-                         | (ext[:, 1] != exp[:, 1]))[0]
-        for row in bad[:3]:
-            findings.append(Finding(
-                "hull", f"{name} row {row} = "
-                f"[{ext[row, 0]}, {ext[row, 1]}] but the membership "
-                f"hull is [{exp[row, 0]}, {exp[row, 1]}]"))
-        if len(bad) > 3:
-            findings.append(Finding(
-                "hull", f"... {len(bad)} wrong {name} rows"))
+    ext = np.asarray(plan.row_extents()).astype(np.int64)
+    if ext.shape != (nby, 2):
+        findings.append(Finding(
+            "hull", f"row_extents has shape {ext.shape}, expected "
+            f"{(nby, 2)}"))
+        return
+    occ = exp[:, 1] >= exp[:, 0]
+    if np.any((ext[occ, 0] < 0) | (ext[occ, 1] >= nbx)):
+        findings.append(Finding(
+            "hull", f"row_extents leaves the {nbx}-wide block grid"))
+    bad = np.nonzero((ext[:, 0] != exp[:, 0])
+                     | (ext[:, 1] != exp[:, 1]))[0]
+    for row in bad[:3]:
+        findings.append(Finding(
+            "hull", f"row_extents row {row} = "
+            f"[{ext[row, 0]}, {ext[row, 1]}] but the membership "
+            f"hull is [{exp[row, 0]}, {exp[row, 1]}]"))
+    if len(bad) > 3:
+        findings.append(Finding(
+            "hull", f"... {len(bad)} wrong row_extents rows"))
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,7 @@ domain zoo across every lowering, storage, coarsening factor and
 (emulated) shard count, runs the five static checks of
 :mod:`repro.analysis.verifier` on each resulting plan, then drives the
 interpret-mode access sanitizer (:mod:`repro.analysis.sanitizer`) over
-real kernel launches on both interpret targets.  The result is a JSON
+real kernel launches under the interpreter.  The result is a JSON
 report (``--out``) and a nonzero exit status when any combination
 produced a finding -- which is what lets CI gate merges on it.
 
@@ -117,8 +117,8 @@ def run_static_matrix(smoke: bool = False, verbose: bool = True) -> list:
 
 
 def run_sanitizer_smoke(smoke: bool = False, verbose: bool = True) -> list:
-    """Drive real kernel launches under the access sanitizer on both
-    interpret targets; returns ``[(label, findings)]``."""
+    """Drive real kernel launches under the access sanitizer on the
+    interpreter; returns ``[(label, findings)]``."""
     import jax.numpy as jnp
 
     from repro.core.compact import compact_layout
@@ -135,25 +135,23 @@ def run_sanitizer_smoke(smoke: bool = False, verbose: bool = True) -> list:
     grid_modes = ("closed_form", "mma") if smoke \
         else ("closed_form", "prefetch_lut", "bounding", "mma")
     out = []
-    for bk in ("gpu-interpret", "tpu-interpret"):
-        for storage in ("embedded", "compact"):
-            for gm in grid_modes:
-                label = f"write/{bk}/{storage}/{gm}"
-                _, findings = verify_launches(
-                    sierpinski_write, operands[storage], 1.0, block=block,
-                    grid_mode=gm, storage=storage, domain=dom,
-                    num_stages=1, backend=bk, kernel="write",
-                    strict=False)
-                out.append((label, findings))
-                _say(label, findings, verbose)
-        state = operands["compact"]
-        label = f"ca/{bk}/compact/closed_form"
-        _, findings = verify_launches(
-            ca_run, state, jnp.zeros_like(state), 2, fuse=1, block=block,
-            grid_mode="closed_form", storage="compact", domain=dom,
-            num_stages=1, backend=bk, kernel="ca", strict=False)
-        out.append((label, findings))
-        _say(label, findings, verbose)
+    for storage in ("embedded", "compact"):
+        for gm in grid_modes:
+            label = f"write/{storage}/{gm}"
+            _, findings = verify_launches(
+                sierpinski_write, operands[storage], 1.0, block=block,
+                grid_mode=gm, storage=storage, domain=dom, num_stages=1,
+                backend="tpu-interpret", strict=False)
+            out.append((label, findings))
+            _say(label, findings, verbose)
+    state = operands["compact"]
+    label = "ca/compact/closed_form"
+    _, findings = verify_launches(
+        ca_run, state, jnp.zeros_like(state), 2, fuse=1, block=block,
+        grid_mode="closed_form", storage="compact", domain=dom,
+        num_stages=1, backend="tpu-interpret", strict=False)
+    out.append((label, findings))
+    _say(label, findings, verbose)
     return out
 
 

@@ -7,7 +7,7 @@ Following *Accelerating Compact Fractals with Tensor Core GPUs* (arXiv
 matrix contraction: encode the digit stream of an index as a one-hot
 matrix ``O`` of shape (levels, k) and contract it with a precomputed
 *digit-basis* matrix ``B`` of shape (levels, k, width) --
-``lambda = O . B`` rides the MXU / tensor cores instead of the scalar
+``lambda = O . B`` rides the MXU instead of the scalar
 ALUs the ``closed_form`` lowering burns.
 
 Mixed-precision contract
@@ -23,12 +23,10 @@ within that bound the chains are bit-identical to the integer
 ``closed_form`` decode (asserted by ``tests/test_mma.py`` and the plan
 verifier's table re-derivation).
 
-Everything here is pure jnp so the same chains run (a) on host for
-table construction (``GridPlan.mma_table``), (b) inside jit, and (c)
-inside gpu-structured Pallas kernel bodies, which compute their block
-coordinates in-kernel per program.  The TPU structure instead binds the
-chain *output* as a scalar-prefetch table (Mosaic index maps cannot run
-``dot_general``), so the decoded coordinates ride the existing
+Everything here is pure jnp, so the same chains run on host for table
+construction (``GridPlan.mma_table``) and inside jit.  The kernels bind
+the chain *output* as a scalar-prefetch table (Mosaic index maps cannot
+run ``dot_general``), so the decoded coordinates ride the existing
 BlockCoords plumbing.
 """
 from __future__ import annotations
@@ -143,15 +141,15 @@ def pair_basis(spec: F.FractalSpec) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Chain evaluators (jnp; host numpy inputs, jitted arrays, and
-# gpu-structured Pallas kernel scalars all take the same path)
+# Chain evaluators (jnp; host numpy inputs and jitted arrays take the
+# same path)
 # ---------------------------------------------------------------------------
 
 def _lift(a: np.ndarray) -> jnp.ndarray:
     """Lift a host basis array into the trace as *ops* (a stack of
-    scalar constants): Pallas kernel bodies reject captured array
-    constants, and the gpu structure evaluates these chains in-kernel.
-    Scalar constants fold into the program; the stack/reshape
+    scalar constants), which Pallas kernel bodies accept where they
+    reject captured array constants.  Scalar constants fold into the
+    program; the stack/reshape
     re-materializes the (tiny) basis per trace, a fixed prologue cost
     next to the dot itself.  Outside a kernel (host table builds under
     ``ensure_compile_time_eval``, plain jit) this is just an eager
@@ -207,28 +205,6 @@ def slots_of_linear(spec: F.FractalSpec, r: int, i, swap: bool = False):
     sx = out[..., 0].astype(jnp.int32)
     sy = out[..., 1].astype(jnp.int32)
     return (sy, sx) if swap else (sx, sy)
-
-
-def decode_orthotope(spec: F.FractalSpec, r: int, wx, wy):
-    """lambda over orthotope coords: MMA replica of
-    :meth:`FractalSpec.lambda_map`.  The per-level one-hots interleave
-    digits of w_y (odd levels) and w_x (even levels) -- a static
-    restack, then one contraction with the coords basis."""
-    ohy = digit_onehot(wy, spec.k, (r + 1) // 2)
-    ohx = digit_onehot(wx, spec.k, r // 2)
-    parts = []
-    for mu in range(1, r + 1):
-        if mu % 2 == 1:
-            parts.append(ohy[..., (mu - 1) // 2, :])
-        else:
-            parts.append(ohx[..., mu // 2 - 1, :])
-    if not parts:
-        z = jnp.zeros(jnp.shape(jnp.asarray(wx)) + (0, spec.k),
-                      jnp.bfloat16)
-        out = _contract(z, coords_basis(spec, r))
-    else:
-        out = _contract(jnp.stack(parts, axis=-2), coords_basis(spec, r))
-    return out[..., 0].astype(jnp.int32), out[..., 1].astype(jnp.int32)
 
 
 def copy_rows(spec: F.FractalSpec, r: int, x, y) -> jnp.ndarray:
@@ -366,38 +342,3 @@ def decode_rows(domain, t):
     by = dot(ge_lo, ones) - np.float32(1.0)
     bx = t.astype(jnp.float32) + dot(ge_lo - ge_hi, diff)
     return bx.astype(jnp.int32), by.astype(jnp.int32)
-
-
-def row_extents_chain(domain) -> jnp.ndarray:
-    """Device (nby, 2) i32 of [min_bx, max_bx] per block row -- the
-    flash q/k window hulls -- via membership matmuls: prefix/suffix
-    member counts are the 0/1 membership matrix contracted with
-    triangular ones matrices; the min (max) column is the number of
-    leading (trailing) zero prefix (suffix) counts.  Empty rows give
-    [0, -1], bit-identical to ``GridPlan.row_extents``."""
-    nbx, nby = domain.bounding_box
-    if nbx >= DIGIT_BOUND:
-        raise ValueError(
-            f"mma row extents: width {nbx} reaches 2^24; f32 "
-            f"accumulation would stop being exact")
-    x, y = np.mgrid[0:nbx, 0:nby]
-    mem = np.broadcast_to(
-        np.asarray(domain.contains(x.T, y.T)), (nby, nbx))
-    m = jnp.asarray(mem).astype(jnp.bfloat16)
-    tri = np.triu(np.ones((nbx, nbx), np.float32))
-
-    def dot(a, b):
-        return lax.dot_general(
-            a, jnp.asarray(b),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    prefix = dot(m, tri)          # (nby, nbx): members at cols <= x
-    suffix = dot(m, tri.T)        # members at cols >= x
-    lead = jnp.sum((prefix == 0).astype(jnp.float32), axis=1)
-    trail = jnp.sum((suffix == 0).astype(jnp.float32), axis=1)
-    count = prefix[:, -1]
-    lo = jnp.where(count == 0, np.float32(0.0), lead)
-    hi = np.float32(nbx - 1) - trail
-    return jnp.stack(
-        [lo.astype(jnp.int32), hi.astype(jnp.int32)], axis=-1)

@@ -11,10 +11,8 @@ This module supplies the three pieces:
     are led by the page table.  A page-table row per query slot is the
     same shape as the 28-col neighbour LUT the engine already prefetches
     (one i32 row per scheduled block), so the table rides the existing
-    mechanism unchanged: on block-indexed (TPU) targets it is prefetch
-    operand 0, readable from BlockSpec index maps; on gpu structures it
-    becomes the leading HBM operand read in-kernel at ``pl.program_id``
-    -- exactly how the decode LUT already travels
+    mechanism unchanged: it is prefetch operand 0, readable from
+    BlockSpec index maps -- exactly how the decode LUT already travels
     (:mod:`repro.core.backend`).  The base plan's own LUT (when the
     lowering is table-backed) stays the *last* prefetch ref, so
     ``GridPlan._decode`` works untouched.
@@ -30,11 +28,9 @@ Device-side layout helpers
     The pool array is ``(num_pages, 2*Hkv, page_size, d)`` with the K
     and V heads *interleaved* on the head axis (``[K0,V0,K1,V1,...]``):
     one physical page holds every head's K and V of its tokens, so the
-    TPU decode kernel copies it in one ``(1, 2*Hkv, page_size, d)``
-    block per (slot, page), and the gpu structure reads one head's K/V
-    as one contiguous ``(2, page_size, d)`` load.  :func:`fuse_kv` /
-    :func:`split_kv` convert between this layout and the separate
-    ``(B, Hkv, S, d)`` caches;
+    decode kernel copies it in one ``(1, 2*Hkv, page_size, d)`` block
+    per (slot, page).  :func:`fuse_kv` / :func:`split_kv` convert
+    between this layout and the separate ``(B, Hkv, S, d)`` caches;
     :func:`gather_kv` is the XLA gather that reconstructs a contiguous
     cache from the pool (the oracle the bit-identity tests and the
     degradation ladder's paged-xla rung share); :func:`append_token` /
@@ -62,13 +58,13 @@ class PagedPlan(GridPlan):
     (or tracer: the plan is built inside the kernel's jit trace, where
     the table is an argument).  ``num_scalar_prefetch`` grows by one and
     ``bound_prefetch`` prepends the table, so the emitter routes it
-    exactly like the decode LUT: scalar prefetch on TPU structures, a
-    leading HBM operand on gpu structures.  Index maps reach it as
-    ``refs[0]`` (see :meth:`GridPlan._index_spec`); the base LUT, when
-    the lowering is table-backed, remains ``refs[-1]`` so the inherited
-    decode is untouched.  ``seq_pos``, the ``(num_slots,)`` i32 decode
-    positions, when given rides as ``refs[1]``, so index maps can bound
-    a slot's pages by its length."""
+    exactly like the decode LUT, through scalar prefetch.  Index maps
+    reach it as ``refs[0]`` (see :meth:`GridPlan._index_spec`); the
+    base LUT, when the lowering is table-backed, remains ``refs[-1]``
+    so the inherited decode is untouched.  ``seq_pos``, the
+    ``(num_slots,)`` i32 decode positions, when given rides as
+    ``refs[1]``, so index maps can bound a slot's pages by its
+    length."""
 
     def __init__(self, *args, page_table=None, seq_pos=None, **kwargs):
         super().__init__(*args, **kwargs)

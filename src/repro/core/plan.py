@@ -26,11 +26,9 @@ Lowerings
     The lookup-table realization (Navarro et al., "Efficient GPU Thread
     Mapping on Embedded 2D Fractals"): the host ``coords_host()`` table
     makes each decode an O(1) table read instead of the O(r) digit
-    unrolling / integer-sqrt chain.  How the table travels is the
-    backend's business (:mod:`repro.core.backend`): scalar prefetch on
-    TPU, a regular HBM operand read at ``pl.program_id`` on GPU.
-    Bit-identical to ``closed_form`` by construction: the table *is*
-    the closed form, evaluated on host.
+    unrolling / integer-sqrt chain.  The table rides scalar prefetch
+    (:mod:`repro.core.backend`).  Bit-identical to ``closed_form`` by
+    construction: the table *is* the closed form, evaluated on host.
 
 ``bounding``
     The paper's baseline: launch the full bounding-box grid and discard
@@ -125,12 +123,10 @@ class BlockCoords:
     ``first_step``  -- predicate for "is this the first grid step",
                        usable for one-time init of revisited outputs.
     ``grid_ids``    -- the raw grid indices of this step.
-    ``refs``        -- the plan's decode-table refs, in operand order
-                       (scalar-prefetch refs on TPU, leading HBM
-                       operand refs on GPU).  gpu-structured kernels
-                       pass these back into ``plan.storage_index`` /
-                       ``plan.neighbor_index`` to address state tiles
-                       themselves.
+    ``refs``        -- the plan's scalar-prefetch refs, in operand
+                       order.  Pipelined kernels pass these back into
+                       ``plan.storage_index`` / ``plan.neighbor_index``
+                       to address the tiles they copy themselves.
     """
 
     __slots__ = ("batch", "bx", "by", "valid", "first_step", "grid_ids",
@@ -168,10 +164,8 @@ class GridPlan:
     lowering:    "closed_form" | "prefetch_lut" | "bounding" | "mma"
                  (or the legacy alias "compact").  "mma" computes the
                  lambda decode as mixed-precision ``dot_general``
-                 digit-basis chains (see :mod:`repro.core.mma`): on
-                 block-indexed targets the chain output is bound as the
-                 scalar-prefetch table, on gpu structures the chains
-                 run in-kernel per program.
+                 digit-basis chains (see :mod:`repro.core.mma`), whose
+                 output is bound as the scalar-prefetch table.
     batch_dims:  leading grid dimensions iterated outside the domain
                  (e.g. ``(batch * heads,)`` for attention).
     storage:     "embedded" (state arrays are the dense bounding-box
@@ -188,11 +182,9 @@ class GridPlan:
                  an s x s tile of fine blocks (the decode amortization
                  of Quezada et al.'s coarsening, on the block level).
     backend:     a :class:`~repro.core.backend.BackendTarget` (or its
-                 name, or None = platform default): the emission
-                 structure every ``pallas_call`` of this plan uses --
-                 "tpu" (Mosaic scalar-prefetch), "gpu" (Triton,
-                 in-kernel HBM addressing), or either "-interpret"
-                 variant.
+                 name, or None = platform default): the target every
+                 ``pallas_call`` of this plan is emitted for -- "tpu"
+                 or "tpu-interpret".
     """
 
     def __init__(self, domain: BlockDomain, lowering: str = "closed_form",
@@ -247,15 +239,11 @@ class GridPlan:
 
     @property
     def _table_backed(self) -> bool:
-        """Whether this plan's decode rides a bound table ref.
-
-        ``prefetch_lut`` always does.  ``mma`` does only on
-        block-indexed (TPU-structured) targets: Mosaic index maps
-        cannot run ``dot_general``, so the chain output is bound as a
-        scalar-prefetch table and read like a LUT; the gpu structure
-        runs the chains in-kernel per program instead."""
-        return self.lowering == "prefetch_lut" or (
-            self.lowering == "mma" and self.target.block_indexed)
+        """Whether this plan's decode rides a bound table ref:
+        ``prefetch_lut``, and ``mma``, whose chain output is bound as a
+        scalar-prefetch table and read like a LUT (Mosaic index maps
+        cannot run ``dot_general``)."""
+        return self.lowering in ("prefetch_lut", "mma")
 
     @property
     def num_scalar_prefetch(self) -> int:
@@ -317,9 +305,9 @@ class GridPlan:
         """Decode table of the ``mma`` lowering -- the same row/column
         layout as :meth:`lut_host`, but every lambda / lambda^-1 entry
         is a :mod:`repro.core.mma` digit-basis ``dot_general`` chain
-        instead of a host integer loop.  On block-indexed targets this
-        is the bound scalar-prefetch operand (index maps read it like a
-        LUT); the verifier re-derives it from ``linear_index`` ground
+        instead of a host integer loop.  This is the bound
+        scalar-prefetch operand (index maps read it like a LUT); the
+        verifier re-derives it from ``linear_index`` ground
         truth, so a corrupted digit-basis matrix surfaces as table
         findings.  The memoized build runs the chains eagerly
         (``ensure_compile_time_eval``) so a first call inside a jit
@@ -406,26 +394,14 @@ class GridPlan:
         batch = tuple(grid_ids[:nb])
         if self.lowering == "bounding":
             by, bx = grid_ids[nb], grid_ids[nb + 1]
-        elif self._table_backed:  # prefetch_lut, or mma on TPU structures
+        elif self._table_backed:  # prefetch_lut, mma
             t = grid_ids[nb]
             lut_ref = prefetch_refs[-1]
             bx = self._lut_read(lut_ref, t, _LUT_BX)
             by = self._lut_read(lut_ref, t, _LUT_BY)
-        elif self.lowering == "mma":  # gpu structure: chains in-kernel
-            bx, by = self._mma_decode(grid_ids[nb])
         else:  # closed_form
             bx, by = self.sched_domain.block_coords(grid_ids[nb])
         return batch, bx, by
-
-    def _mma_decode(self, t):
-        """Linear step -> scheduled (bx, by) via the digit-basis matmul
-        chains (fractal domains) or the row-comparison chain (generic
-        row-major domains)."""
-        from . import mma
-        frac = mma.fractal_of(self.sched_domain)
-        if frac is not None:
-            return mma.decode_linear(frac[0], frac[1], t)
-        return mma.decode_rows(self.sched_domain, t)
 
     def _place_coords(self, bx, by, prefetch_refs=()):
         """The (bx, by) an operand's ``place`` callback receives; the
@@ -500,12 +476,11 @@ class GridPlan:
         grid step: embedded -> the (super)block's (by, bx) in the
         bounding-box array; compact -> the packed slot (sy, sx) of the
         layout (the supertile sub-rectangle index under coarsening).
-        Under ``prefetch_lut`` the slot is read from the extended LUT;
-        the other lowerings evaluate ``layout.slot`` (lambda^-1)
-        inline.  Shared by the ``BlockSpec`` index maps (TPU, where
-        ``refs`` are scalar-prefetch refs) and the gpu-structured
-        kernel bodies (where ``refs`` are the leading HBM operand refs
-        and the returned index drives ``backend.load``/``backend.store``)."""
+        Under the table-backed lowerings the slot is read from the
+        extended LUT; the others evaluate ``layout.slot`` (lambda^-1)
+        inline.  Shared by the ``BlockSpec`` index maps and the
+        pipelined kernels' DMA addressing (``refs`` are the
+        scalar-prefetch refs)."""
         if self.storage == "embedded":
             _, bx, by = self._decode(grid_ids, refs)
             bx, by = self._place_coords(bx, by, refs)
@@ -515,17 +490,6 @@ class GridPlan:
             lut_ref = refs[-1]
             return (self._lut_read(lut_ref, t, _LUT_SY),
                     self._lut_read(lut_ref, t, _LUT_SX))
-        if self.lowering == "mma":
-            from . import mma
-            frac = mma.fractal_of(self.sched_domain)
-            if frac is not None:
-                # the compact enumeration is lambda-linear: the own slot
-                # comes straight from the step id, one digit contraction
-                swap = self._tiling is not None and self._tiling.j % 2
-                sx, sy = mma.slots_of_linear(
-                    frac[0], frac[1], grid_ids[len(self.batch_dims)],
-                    swap=bool(swap))
-                return sy, sx
         _, bx, by = self._decode(grid_ids, refs)
         if self.lowering == "bounding":
             # a skipped step revisits the block of the member step next
@@ -558,15 +522,6 @@ class GridPlan:
             return (self._lut_read(lut_ref, t, _LUT_NBR + 3 * j + 1),
                     self._lut_read(lut_ref, t, _LUT_NBR + 3 * j))
         _, bx, by = self._decode(grid_ids, refs)
-        if self.lowering == "mma":
-            from . import mma
-            frac = mma.fractal_of(self.sched_domain)
-            if frac is not None:
-                swap = self._tiling is not None and self._tiling.j % 2
-                sx, sy, _ok = mma.neighbor_slots(
-                    frac[0], frac[1], self.sched_domain, bx, by, dx, dy,
-                    swap=bool(swap))
-                return sy, sx
         if self._tiling is not None:
             tx, ty, _ok = self._tiling.neighbor_tile(bx, by, dx, dy)
             return ty, tx
@@ -658,11 +613,8 @@ class GridPlan:
         """Emit the ``pl.pallas_call`` for this plan on its
         :class:`~repro.core.backend.BackendTarget` (see
         :func:`repro.core.backend.emit`, which owns all grid-spec
-        construction).  ``kernel(coords, *refs)`` is lowering- and
-        backend-agnostic at the signature level; gpu-structured kernels
-        additionally address state through ``coords.grid_ids`` /
-        ``coords.refs`` and :meth:`storage_index` /
-        :meth:`neighbor_index`.  ``interpret=None`` defers to the
+        construction).  ``kernel(coords, *refs)`` is lowering-agnostic
+        at the signature level.  ``interpret=None`` defers to the
         target's interpret flag (an explicit bool overrides, for
         tests)."""
         return backend_lib.emit(
@@ -671,13 +623,12 @@ class GridPlan:
             input_output_aliases=input_output_aliases,
             interpret=interpret, **kwargs)
 
-    # -- grid-step helpers for gpu-structured kernels ------------------------
+    # -- grid-step helpers for the DMA-pipelined kernels ---------------------
 
     @property
     def steps_per_launch(self) -> int:
-        """Grid steps per batch element (the domain grid volume): the
-        partial-result axis gpu-structured reductions emit, one slot
-        per step, before the deterministic host-side combine."""
+        """Grid steps per batch element (the domain grid volume): how
+        far ahead a pipelined kernel may address its next copies."""
         nb = len(self.batch_dims)
         out = 1
         for d in self.grid[nb:]:

@@ -574,9 +574,8 @@ class ShardedPlan(GridPlan):
         (:meth:`GridPlan.mma_table`), permuted/chunked/padded into the
         per-device enumeration order by a host-built gather index that
         replicates :meth:`_lut_sharded_host` exactly -- so the chunks
-        carry chain-derived entries in LUT layout.  ``None`` when this
-        plan binds no mma table (other lowerings, or gpu structures
-        which run the chains in-kernel)."""
+        carry chain-derived entries in LUT layout.  ``None`` under the
+        other lowerings."""
         tbl = self.mma_table_sharded_host()
         return None if tbl is None else jnp.asarray(tbl)
 
@@ -584,7 +583,7 @@ class ShardedPlan(GridPlan):
         """Host numpy copy of :meth:`mma_table_sharded` (the verifier
         runs inside kernel jit traces, where the device gather would be
         a tracer)."""
-        if not (self.lowering == "mma" and self._table_backed):
+        if self.lowering != "mma":
             return None
         idx = memo.cached(
             "shard-mma-index", self.domain,
@@ -715,26 +714,15 @@ class ShardedPlan(GridPlan):
         coords, the sharded closed-form decode (lambda on the orthotope
         coordinate; linear-order block_coords for block-linear
         layouts)."""
-        mma_lib = None
-        if self.lowering == "mma":
-            from . import mma as mma_lib
         if self._tiling is not None:
             t = self._tiling
             wx, wy = (col, row) if t.j % 2 == 0 else (row, col)
-            if mma_lib is not None:
-                return mma_lib.decode_orthotope(t.spec, t.coarse.r_b,
-                                                wx, wy)
             return t.spec.lambda_map(wx, wy, t.coarse.r_b)
         spec = self.layout._fractal_spec()
         if spec is not None:
-            if mma_lib is not None:
-                return mma_lib.decode_orthotope(spec, self.domain.r_b,
-                                                col, row)
             return spec.lambda_map(col, row, self.domain.r_b)
         i = jnp.clip(row * self.ncols + col, 0,
                      self.sched_domain.num_blocks - 1)
-        if mma_lib is not None:
-            return mma_lib.decode_rows(self.sched_domain, i)
         return self.sched_domain.block_coords(i)
 
     def _storage_row(self, bx, by):
@@ -755,7 +743,7 @@ class ShardedPlan(GridPlan):
                 by = self._zz_global_row(by, sref[SHARD_DEV])
             return batch, bx, by
         t = self._phase_step(grid_ids[nb], prefetch_refs)
-        if self._table_backed:  # prefetch_lut, or mma on TPU structures
+        if self._table_backed:  # prefetch_lut, mma
             lut_ref = prefetch_refs[1]
             return batch, lut_ref[t, 0], lut_ref[t, 1]
         if self.partition == "zigzag":
@@ -775,8 +763,6 @@ class ShardedPlan(GridPlan):
         i = jnp.clip(sref[SHARD_LO]
                      + jnp.minimum(t, sref[SHARD_COUNT] - 1),
                      0, self.sched_domain.num_blocks - 1)
-        if self.lowering == "mma":  # gpu structure: chains in-kernel
-            return batch, *self._mma_decode(i)
         return batch, *self.sched_domain.block_coords(i)
 
     def _zz_global_row(self, local, dev):
@@ -839,8 +825,8 @@ class ShardedPlan(GridPlan):
 
     def storage_index(self, grid_ids, refs=()):
         """Local-slab tile index of the state operand (shared by the
-        BlockSpec index maps and the gpu-structured kernel bodies, as
-        in :meth:`GridPlan.storage_index`)."""
+        BlockSpec index maps and the pipelined kernels' DMA addressing,
+        as in :meth:`GridPlan.storage_index`)."""
         if self.storage == "embedded":
             return super().storage_index(grid_ids, refs)
         if self.lowering == "bounding":
@@ -871,16 +857,7 @@ class ShardedPlan(GridPlan):
             nsy = lut_ref[t, _LUT_NBR + 3 * j + 1]
         else:
             _, bx, by = self._decode(grid_ids, refs)
-            frac = None
-            if self.lowering == "mma":
-                from . import mma
-                frac = mma.fractal_of(self.sched_domain)
-            if frac is not None:
-                swap = self._tiling is not None and self._tiling.j % 2
-                nsx, nsy, _ok = mma.neighbor_slots(
-                    frac[0], frac[1], self.sched_domain, bx, by, dx, dy,
-                    swap=bool(swap))
-            elif self._tiling is not None:
+            if self._tiling is not None:
                 nsx, nsy, _ok = self._tiling.neighbor_tile(bx, by, dx, dy)
             else:
                 nsx, nsy, _ok = self.layout.neighbor_slot(bx, by, dx, dy)
@@ -931,7 +908,7 @@ def zigzag_row_order(nby: int, num_shards: int) -> np.ndarray:
 def device_tables(plan: ShardedPlan):
     """(shard_table, lut_tuple) device arrays for a driver's shard_map:
     the (D, L) shard table plus, under the table-backed lowerings
-    (prefetch_lut, or mma on TPU structures), the per-device decode
+    (prefetch_lut, mma), the per-device decode
     table -- both sharded ``P(axis, None)`` on their leading axis so
     each device receives its own row/chunk.  One builder shared by
     every sharded kernel driver so the prefetch-operand plumbing cannot

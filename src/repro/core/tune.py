@@ -71,11 +71,9 @@ def _sane_config(config: dict) -> bool:
         "fuse": _pos_int,
         "coarsen": _pos_int,
         "stages": _pos_int,
-        "num_stages": _pos_int,
         "block_q": _pos_int,
         "block_k": _pos_int,
         "page_size": _pos_int,
-        "num_warps": lambda v: v is None or _pos_int(v, 64),
     }
     for k, v in config.items():
         check = checks.get(k)
@@ -199,15 +197,15 @@ def _with_backend(params: dict) -> dict:
 
 def target_params(params: dict, target) -> dict:
     """Qualify a tuning key with the emission target
-    (:mod:`repro.core.backend`), so e.g. a gpu-interpret winner never
-    answers for the tpu-interpret structure and vice versa.  ``target``
-    may be a BackendTarget, a name, or None (= the process default,
-    resolved here).  The reference point is the bare *platform* default
-    -- not the process default -- because the cache file is shared
-    across processes: a run steered onto another target via
-    ``REPRO_BACKEND``/``set_default`` must stamp its entries even
-    though that target is its own default.  True platform-default
-    entries keep the unqualified key, so existing caches stay valid."""
+    (:mod:`repro.core.backend`), so e.g. an interpreter winner never
+    answers for the chip and vice versa.  ``target`` may be a
+    BackendTarget, a name, or None (= the process default, resolved
+    here).  The reference point is the bare *platform* default -- not
+    the process default -- because the cache file is shared across
+    processes: a run steered onto another target via ``set_default``
+    must stamp its entries even though that target is its own default.
+    True platform-default entries keep the unqualified key, so existing
+    caches stay valid."""
     from . import backend as backend_lib
     target = backend_lib.resolve(target)
     if target == backend_lib.platform_default():
@@ -377,35 +375,25 @@ def _coarsen_axis(fractal: str, n: int, block: int,
     return out or [1]
 
 
-def _lowering_axis(target=None) -> tuple:
+def _lowering_axis() -> tuple:
     """:data:`~repro.core.plan.LOWERINGS`, with ``mma`` hoisted to the
-    front on targets whose matrix units make the digit-basis decode
-    profitable (``prefers_mma``): candidate order is measurement order,
-    so the likely winner warms the jit caches first and the sharded
-    warm-start explores its one-knob neighbourhood."""
-    from . import backend as backend_lib
+    front, where the MXU makes the digit-basis decode profitable:
+    candidate order is measurement order, so the likely winner warms
+    the jit caches first and the sharded warm-start explores its
+    one-knob neighbourhood."""
     from .plan import LOWERINGS
-    target = backend_lib.resolve(target)
-    if target.prefers_mma:
-        return ("mma",) + tuple(lo for lo in LOWERINGS if lo != "mma")
-    return tuple(LOWERINGS)
+    return ("mma",) + tuple(lo for lo in LOWERINGS if lo != "mma")
 
 
 def ca_candidates(fractal: str, n: int, block: int, *,
                   storages=("embedded", "compact"), max_fuse: int = 8,
-                  max_coarsen: int = 4, target=None):
-    from . import backend as backend_lib
-    target = backend_lib.resolve(target)
-    # pipelining depth is a real axis where the emission can use it:
-    # the TPU structure's DMA double buffers, or a compiled gpu's
-    # Triton scheduler.  The emulated gpu target ignores it for CA.
-    stages_axis = (1, 2) if target.block_indexed \
-        or (target.kind == "gpu" and not target.interpret) else (1,)
+                  max_coarsen: int = 4):
+    # pipelining depth: the synchronous launch or the DMA double buffer
     for storage in storages:
-        for lowering in _lowering_axis(target):
+        for lowering in _lowering_axis():
             for coarsen in _coarsen_axis(fractal, n, block, max_coarsen):
                 for fuse in _fuse_axis(block, coarsen, max_fuse):
-                    for stages in stages_axis:
+                    for stages in (1, 2):
                         yield {"lowering": lowering, "storage": storage,
                                "fuse": fuse, "coarsen": coarsen,
                                "stages": stages}
@@ -484,17 +472,16 @@ def autotune_ca(*, fractal: str = "sierpinski-gasket", n: int = 256,
         # warm-start the D>1 search from the single-device winner
         seed = best("ca", base, cache=cache)
     cands = ca_candidates(fractal, n, block, storages=storages,
-                          max_fuse=max_fuse, max_coarsen=max_coarsen,
-                          target=backend)
+                          max_fuse=max_fuse, max_coarsen=max_coarsen)
     return autotune("ca", params, cands, build, cache=cache, force=force,
                     verbose=verbose, seed_config=seed, verify=vfy)
 
 
 def write_candidates(fractal: str, n: int, block: int, *,
                      storages=("embedded", "compact"),
-                     max_coarsen: int = 4, target=None):
+                     max_coarsen: int = 4):
     for storage in storages:
-        for lowering in _lowering_axis(target):
+        for lowering in _lowering_axis():
             for coarsen in _coarsen_axis(fractal, n, block, max_coarsen):
                 yield {"lowering": lowering, "storage": storage,
                        "coarsen": coarsen}
@@ -549,46 +536,18 @@ def autotune_write(*, fractal: str = "sierpinski-gasket", n: int = 256,
     params = shard_params(base, mesh, shard_axis)
     seed = best("write", base, cache=cache) if mesh is not None else None
     cands = write_candidates(fractal, n, block, storages=storages,
-                             max_coarsen=max_coarsen, target=backend)
+                             max_coarsen=max_coarsen)
     return autotune("write", params, cands, build, cache=cache,
                     force=force, verbose=verbose, seed_config=seed,
                     verify=vfy)
 
 
-#: Triton compiler axes the gpu targets additionally search (the
-#: TPU-side analogue is the block geometry itself).
-GPU_NUM_WARPS = (2, 4, 8)
-GPU_NUM_STAGES = (1, 2, 3)
-
-
-def flash_candidates(sq: int, sk: int, *, blocks=ALL_FLASH_BLOCKS,
-                     target=None):
-    """lowering x block geometry, crossed with the gpu-structure
-    pipelining axes when the target has them: on a *compiled* gpu
-    target num_warps x num_stages (Triton occupancy + scheduling); on
-    the emulated gpu target num_stages alone, which is still a real
-    knob there -- it sizes the KV-FIFO software pipeline the flash
-    kernel itself unrolls.  ``target`` accepts a BackendTarget, a
-    name, or None (= the process default -- on a CUDA machine the gpu
-    axes appear without asking)."""
-    from . import backend as backend_lib
-    target = backend_lib.resolve(target)
-    gpu = target.kind == "gpu"
-    compiled = gpu and not target.interpret
-    for lowering in _lowering_axis(target):
+def flash_candidates(sq: int, sk: int, *, blocks=ALL_FLASH_BLOCKS):
+    """lowering x block geometry for the flash-attention kernel."""
+    for lowering in _lowering_axis():
         for b in blocks:
             if b <= min(sq, sk) and sq % b == 0 and sk % b == 0:
-                base = {"lowering": lowering, "block_q": b, "block_k": b}
-                if not gpu:
-                    yield base
-                elif compiled:
-                    for nw in GPU_NUM_WARPS:
-                        for ns in GPU_NUM_STAGES:
-                            yield {**base, "num_warps": nw,
-                                   "num_stages": ns}
-                else:
-                    for ns in (1, 2):
-                        yield {**base, "num_stages": ns}
+                yield {"lowering": lowering, "block_q": b, "block_k": b}
 
 
 def autotune_flash(*, kind: str = "causal", batch: int = 1, heads: int = 4,
@@ -598,8 +557,7 @@ def autotune_flash(*, kind: str = "causal", batch: int = 1, heads: int = 4,
                    force: bool = False, interpret: Optional[bool] = None,
                    verbose: bool = False, backend=None,
                    verify: bool = False):
-    """Search lowering x block geometry (x num_warps/num_stages on a
-    compiled gpu target) for the flash-attention kernel.
+    """Search lowering x block geometry for the flash-attention kernel.
     ``verify=True`` statically verifies each candidate's plan before
     measuring it (:mod:`repro.analysis`)."""
     from repro.kernels.flash_attention import flash_attention
@@ -618,8 +576,6 @@ def autotune_flash(*, kind: str = "causal", batch: int = 1, heads: int = 4,
                                    block_q=cfg["block_q"],
                                    block_k=cfg["block_k"],
                                    grid_mode=cfg["lowering"],
-                                   num_warps=cfg.get("num_warps"),
-                                   num_stages=cfg.get("num_stages"),
                                    backend=backend, interpret=interpret)
         return fn
 
@@ -630,8 +586,6 @@ def autotune_flash(*, kind: str = "causal", batch: int = 1, heads: int = 4,
                             block_q=cfg["block_q"],
                             block_k=cfg["block_k"],
                             grid_mode=cfg["lowering"],
-                            num_warps=cfg.get("num_warps"),
-                            num_stages=cfg.get("num_stages"),
                             backend=backend, interpret=interpret,
                             verify=True)
 
@@ -641,8 +595,7 @@ def autotune_flash(*, kind: str = "causal", batch: int = 1, heads: int = 4,
          "window": window},
         "blocks", blocks, ALL_FLASH_BLOCKS), backend)
     return autotune("flash", params,
-                    flash_candidates(sq, sk, blocks=blocks,
-                                     target=backend),
+                    flash_candidates(sq, sk, blocks=blocks),
                     build, cache=cache, force=force, verbose=verbose,
                     verify=vfy)
 
@@ -654,12 +607,11 @@ def autotune_flash(*, kind: str = "causal", batch: int = 1, heads: int = 4,
 ALL_PAGE_SIZES = (8, 16, 32, 64)
 
 
-def paged_candidates(seq: int, *, page_sizes=ALL_PAGE_SIZES,
-                     target=None):
+def paged_candidates(seq: int, *, page_sizes=ALL_PAGE_SIZES):
     """lowering x page_size for the paged decode kernel.  Page sizes
     larger than the sequence are inviable (a one-page pool degenerates
     to the contiguous layout and is covered by the flash search)."""
-    for lowering in _lowering_axis(target):
+    for lowering in _lowering_axis():
         for ps in page_sizes:
             if ps <= seq:
                 yield {"lowering": lowering, "page_size": ps}
@@ -740,8 +692,7 @@ def autotune_paged(*, batch: int = 4, heads: int = 4,
     params = shard_params(base, mesh, shard_axis)
     seed = best("paged", base, cache=cache) if mesh is not None else None
     return autotune("paged", params,
-                    paged_candidates(seq, page_sizes=page_sizes,
-                                     target=backend),
+                    paged_candidates(seq, page_sizes=page_sizes),
                     build, cache=cache, force=force, verbose=verbose,
                     seed_config=seed, verify=vfy)
 

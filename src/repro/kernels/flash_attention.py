@@ -13,8 +13,8 @@ the "order-m equation" map of related work [18]) or through the
 scalar-prefetch lookup table, both emitted by the shared
 :class:`~repro.core.plan.GridPlan` engine.  ``grid_mode`` selects the
 lowering: ``closed_form`` (alias ``compact``) | ``prefetch_lut`` |
-``bounding`` | ``mma`` (digit-basis matmul decode on the MXU / tensor
-cores; the gpu structure consumes a device-built row-extents operand).
+``bounding`` | ``mma`` (the decode table built by digit-basis matmul
+chains on the MXU).
 
 Grid layout: ``(batch*heads, T)``; the compact enumerations are
 row-major in q, so all k-steps of one q row are consecutive: the online
@@ -44,9 +44,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import backend as backend_lib
-from repro.core.backend import full_spec
 from repro.core.compact import key_block_support
 from repro.core.domain import make_attention_domain
 from repro.core.plan import GridPlan, normalize_storage
@@ -70,8 +70,8 @@ def _row_bounds(kind, qb, m_k, wb, off_b):
 def _attn_tile_update(q, k, v, acc, m_prev, l_prev, *, kind, window, qb,
                       kb, block_q, block_k, off, seq_pos=None):
     """One online-softmax step over the (qb, kb) tile -- the kernel
-    math shared by both emission structures (TPU scratch refs, GPU loop
-    carries).  ``q`` is pre-scaled f32; k/v are f32 tiles.  ``seq_pos``
+    math shared by the flash and the paged decode kernels.  ``q`` is
+    pre-scaled f32; k/v are f32 tiles.  ``seq_pos``
     (run-time scalar) additionally masks keys beyond the current decode
     position."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -107,9 +107,9 @@ def _attn_tile_update(q, k, v, acc, m_prev, l_prev, *, kind, window, qb,
 
 def _attn_kernel(coords, *refs, kind, window, scale, block_q, block_k,
                  m_k, wb, off, h, has_pos):
-    """Block-indexed (TPU) attention kernel: one (qb, kb) tile per grid
-    step, online-softmax state in VMEM scratch across the sequential
-    grid.  ``pos_ref`` (when present) is the whole (B,) decode-position
+    """Attention kernel: one (qb, kb) tile per grid step,
+    online-softmax state in VMEM scratch across the sequential grid.
+    ``pos_ref`` (when present) is the whole (B,) decode-position
     vector in SMEM; the batch row of this program is the leading grid
     id divided by the head count ``h``."""
     if has_pos:
@@ -161,183 +161,13 @@ def _attn_kernel(coords, *refs, kind, window, scale, block_q, block_k,
         pl.when(coords.valid & live)(body)
 
 
-def _gpu_flash_call(*, target, domain, lowering, b, h, group, m_q, m_k,
-                    wb, off, block_q, block_k, d, kind, window, scale,
-                    out_shape, dtype, s0, sk_arr, has_pos,
-                    row_extents=None, sharded=False, rows_local=None,
-                    zigzag=False, num_shards=1,
-                    num_warps=None, num_stages=None):
-    """gpu-structured flash attention: grid ``(batch*heads, q_rows)``,
-    one program per query-block row, an in-kernel ``fori_loop`` over
-    that row's key-block extent with the online-softmax state in loop
-    carries (parallel grids cannot persist scratch across steps).  The
-    lowering picks the extent source: ``closed_form`` computes the row
-    bounds inline, ``prefetch_lut`` reads the host-built row-extents
-    table as an HBM operand indexed by the program id, ``mma`` reads an
-    extents operand produced on device by the digit-basis matmul chain
-    (:func:`repro.core.mma.row_extents_chain`, bit-identical to the
-    host table), ``bounding``
-    walks the full key range and where-guards non-member tiles --
-    visiting exactly the tiles (in exactly the order) the block-indexed
-    structure visits, so results are bit-identical per lowering.
-
-    ``num_stages`` >= 2 software-pipelines the key loop: the loads for
-    key blocks k+1 .. k+stages-1 ride the loop carry as a FIFO, so each
-    iteration issues the load for block k+stages-1 *before* the softmax
-    consumes block k and the tile fetches overlap the dot-products of
-    earlier blocks (on a real GPU the same knob also reaches the Triton
-    scheduler via compiler params).  The FIFO rotation consumes tiles
-    in exactly the synchronous order, so results stay bit-identical;
-    loads past the row extent clamp to the last key block and are
-    discarded unread.
-
-    Returns ``call(*tables, q, k, v[, pos])`` where ``tables`` is the
-    row-extents operand under ``prefetch_lut``/``mma`` plus the
-    per-device shard-table row when ``sharded`` (global query row =
-    local row + ``tbl[SHARD_ROWLO]``, or the snake row rebuilt from the
-    device id at ``tbl[SHARD_DEV]`` under ``zigzag``)."""
-    from repro.core.shard import SHARD_DEV, SHARD_ROWLO
-
-    n_ext = 1 if lowering in ("prefetch_lut", "mma") else 0
-    n_tbl = 1 if sharded else 0
-    rows = rows_local if rows_local is not None else m_q
-    kv_blocks = m_k - s0
-    stages = target.resolve_stages(num_stages)
-
-    def kern(*refs):
-        i = 0
-        ext_ref = refs[0] if n_ext else None
-        i += n_ext
-        tbl_ref = refs[i] if n_tbl else None
-        i += n_tbl
-        q_ref, k_ref, v_ref = refs[i:i + 3]
-        i += 3
-        pos_ref = refs[i] if has_pos else None
-        o_ref = refs[-1]
-
-        qb = pl.program_id(1)
-        if sharded and zigzag:
-            two_d = 2 * num_shards
-            dev = tbl_ref[SHARD_DEV]
-            qb = (qb // 2) * two_d + jnp.where(
-                qb % 2 == 0, dev, two_d - 1 - dev)
-        elif sharded:
-            qb = qb + tbl_ref[SHARD_ROWLO]
-        if lowering in ("prefetch_lut", "mma"):
-            start, end = ext_ref[qb, 0], ext_ref[qb, 1]
-        elif lowering == "bounding":
-            start, end = 0 * qb, 0 * qb + (m_k - 1)
-        else:
-            start, end = _row_bounds(kind, qb, m_k, wb, off // block_q)
-        pos = None
-        if has_pos:
-            pos = pos_ref[pl.program_id(0) // h]
-            end = jnp.minimum(end, pos // block_k)
-            if kind == "full" and window:
-                start = jnp.maximum(
-                    start, jnp.maximum(pos - window + 1, 0) // block_k)
-
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-
-        def load_kv(ref, kb):
-            # clamp unconditionally: in-range reads (all the loop ever
-            # consumes) are unchanged, and pipelined prefetches past
-            # the row extent stay in bounds
-            kv = jnp.clip(kb - s0, 0, kv_blocks - 1)
-            t = backend_lib.load(ref, (pl.ds(0, 1), pl.ds(0, 1),
-                                       pl.ds(kv * block_k, block_k),
-                                       pl.ds(0, d)))
-            return t.reshape(block_k, d).astype(jnp.float32)
-
-        def load_tiles(kb):
-            return load_kv(k_ref, kb), load_kv(v_ref, kb)
-
-        def update(carry, kb, tiles):
-            k_t, v_t = tiles
-            new = _attn_tile_update(
-                q, k_t, v_t, *carry, kind=kind, window=window, qb=qb,
-                kb=kb, block_q=block_q, block_k=block_k, off=off,
-                seq_pos=pos)
-            if lowering == "bounding" and not getattr(
-                    domain, "always_member", False):
-                ok = domain.contains(kb, qb)
-                new = tuple(jnp.where(ok, nw, old)
-                            for nw, old in zip(new, carry))
-            return new
-
-        acc0 = (jnp.zeros((block_q, d), jnp.float32),
-                jnp.full((block_q, 1), NEG_INF, jnp.float32),
-                jnp.zeros((block_q, 1), jnp.float32))
-        n_steps = end - start + 1
-        if stages <= 1:
-            def step(j, carry):
-                kb = start + j
-                return update(carry, kb, load_tiles(kb))
-
-            acc, _, l = jax.lax.fori_loop(0, n_steps, step, acc0)
-        else:
-            # software-pipelined KV FIFO: the prologue issues the loads
-            # for key blocks start .. start+stages-2; each iteration
-            # then loads block j+stages-1 *before* the softmax consumes
-            # block j, keeping stages-1 tile fetches in flight past the
-            # compute.  Consumption order equals the synchronous order.
-            fifo0 = tuple(load_tiles(start + i) for i in range(stages - 1))
-
-            def step(j, carry):
-                fifo, state = carry
-                nxt = load_tiles(start + j + (stages - 1))
-                state = update(state, start + j, fifo[0])
-                return fifo[1:] + (nxt,), state
-
-            _, (acc, _, l) = jax.lax.fori_loop(
-                0, n_steps, step, (fifo0, acc0))
-        l = jnp.where(l == 0, 1.0, l)
-        o_ref[0, 0, ...] = (acc / l).astype(o_ref.dtype)
-
-    def q_spec():
-        return pl.BlockSpec((1, 1, block_q, d),
-                            lambda bh, qb: (bh // h, bh % h, qb, 0))
-
-    kv_spec = pl.BlockSpec(
-        (1, 1, sk_arr, d),
-        lambda bh, qb: (bh // h, (bh % h) // group, 0, 0))
-    in_specs = []
-    if n_ext:
-        in_specs.append(full_spec(row_extents.shape))
-    if n_tbl:
-        in_specs.append(None)  # placeholder: shape known at call time
-    in_specs += [q_spec(), kv_spec, kv_spec]
-    if has_pos:
-        in_specs.append(full_spec((b,)))
-
-    interp = target.interpret
-    extra = target.call_kwargs(num_warps, num_stages)
-
-    def call(*args):
-        specs = list(in_specs)
-        if n_tbl:
-            specs[n_ext] = full_spec(args[n_ext].shape)
-        c = pl.pallas_call(
-            kern, grid=(b * h, rows), in_specs=specs,
-            out_specs=q_spec(),
-            out_shape=jax.ShapeDtypeStruct(out_shape, dtype),
-            interpret=interp, name="flash_attention", **extra)
-        return c(*args)
-
-    if n_ext:
-        ext = jnp.asarray(row_extents)
-        return lambda *args: call(ext, *args)
-    return call
-
-
 @functools.partial(jax.jit, static_argnames=(
     "kind", "window", "scale", "block_q", "block_k", "grid_mode",
-    "storage", "kv_seq_len", "backend", "num_warps", "num_stages",
-    "mesh", "shard_axis", "shard_balance", "verify"))
+    "storage", "kv_seq_len", "backend", "mesh", "shard_axis",
+    "shard_balance", "verify"))
 def _flash_impl(q, k, v, seq_pos=None, *, kind, window, scale, block_q,
                 block_k, grid_mode, storage, kv_seq_len, backend,
-                num_warps=None, num_stages=None, mesh=None,
-                shard_axis="data", shard_balance="contiguous",
+                mesh=None, shard_axis="data", shard_balance="contiguous",
                 verify=False):
     b, h, sq, d = q.shape
     _, hkv, sk_arr, _ = k.shape
@@ -371,8 +201,7 @@ def _flash_impl(q, k, v, seq_pos=None, *, kind, window, scale, block_q,
     has_pos = seq_pos is not None
     if has_pos and kind != "full":
         # a band row wholly beyond seq_pos would have start > end: no
-        # step initializes the output on the sequential structure and
-        # the gpu loop runs empty -- garbage, not a defined result.
+        # step initializes the output -- garbage, not a defined result.
         # Decode rides kind="full"; window= gives the run-time sliding
         # window anchored at seq_pos.
         raise ValueError(
@@ -406,11 +235,10 @@ def _flash_impl(q, k, v, seq_pos=None, *, kind, window, scale, block_q,
                     f"zigzag needs the query-block grid ({m_q}) "
                     f"divisible by 2 * mesh axis ({2 * D}) for an "
                     f"exactly balanced snake")
-            if target.block_indexed and grid_mode in ("closed_form",
-                                                      "compact"):
-                # the snake's owned rows are scattered: the sequential
-                # structure decodes them through the LUT (bit-identical
-                # to the closed form by the engine's contract)
+            if grid_mode in ("closed_form", "compact"):
+                # the snake's owned rows are scattered: decode them
+                # through the LUT (bit-identical to the closed form by
+                # the engine's contract)
                 grid_mode = "prefetch_lut"
             partition = "zigzag"
             zz_perm = zigzag_row_order(m_q, D)
@@ -451,65 +279,39 @@ def _flash_impl(q, k, v, seq_pos=None, *, kind, window, scale, block_q,
                 f"got shape {sp.shape}")
         pos_operand = (sp,)
 
-    if not target.block_indexed:
-        lowering = plan.lowering
-        if lowering == "prefetch_lut":
-            extents = plan.row_extents()
-        elif lowering == "mma":
-            from repro.core import mma
-            extents = mma.row_extents_chain(domain)
-        else:
-            extents = None
-        call = _gpu_flash_call(
-            target=target, domain=domain, lowering=lowering, b=b, h=h,
-            group=group, m_q=m_q, m_k=m_k, wb=wb, off=off,
-            block_q=block_q, block_k=block_k, d=d, kind=kind,
-            window=window, scale=scale, out_shape=out_shape,
-            dtype=q.dtype, s0=s0, sk_arr=sk_arr, has_pos=has_pos,
-            row_extents=extents, sharded=mesh is not None,
-            rows_local=(m_q // int(mesh.shape[shard_axis])
-                        if mesh is not None else None),
-            zigzag=zz_perm is not None,
-            num_shards=(int(mesh.shape[shard_axis])
-                        if mesh is not None else 1),
-            num_warps=num_warps, num_stages=num_stages)
-        if mesh is None:
-            return call(q, k, v, *pos_operand)
-    else:
-        def q_place(bx, by, bh):
-            return (bh // h, bh % h, by, 0)
+    def q_place(bx, by, bh):
+        return (bh // h, bh % h, by, 0)
 
-        def kv_place(bx, by, bh):
-            kb = jnp.clip(bx - s0, 0, m_k - s0 - 1) if s0 else bx
-            return (bh // h, (bh % h) // group, kb, 0)
+    def kv_place(bx, by, bh):
+        kb = jnp.clip(bx - s0, 0, m_k - s0 - 1) if s0 else bx
+        return (bh // h, (bh % h) // group, kb, 0)
 
-        kernel = functools.partial(
-            _attn_kernel, kind=kind, window=window, scale=scale,
-            block_q=block_q, block_k=block_k, m_k=m_k, wb=wb, off=off,
-            h=h, has_pos=has_pos)
+    kernel = functools.partial(
+        _attn_kernel, kind=kind, window=window, scale=scale,
+        block_q=block_q, block_k=block_k, m_k=m_k, wb=wb, off=off, h=h,
+        has_pos=has_pos)
 
-        in_specs = [
-            plan.block_spec((1, 1, block_q, d), q_place),
-            plan.block_spec((1, 1, block_k, d), kv_place),
-            plan.block_spec((1, 1, block_k, d), kv_place),
-        ]
-        if has_pos:
-            in_specs.append(target.scalar_spec())
-        call = plan.pallas_call(
-            kernel,
-            in_specs=in_specs,
-            out_specs=plan.block_spec((1, 1, block_q, d), q_place),
-            out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
-            scratch_shapes=[
-                target.scratch((block_q, d), jnp.float32),
-                target.scratch((block_q, 1), jnp.float32),
-                target.scratch((block_q, 1), jnp.float32),
-            ],
-            num_warps=num_warps, num_stages=num_stages,
-            name="flash_attention",
-        )
-        if mesh is None:
-            return call(q, k, v, *pos_operand)
+    in_specs = [
+        plan.block_spec((1, 1, block_q, d), q_place),
+        plan.block_spec((1, 1, block_k, d), kv_place),
+        plan.block_spec((1, 1, block_k, d), kv_place),
+    ]
+    if has_pos:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    call = plan.pallas_call(
+        kernel,
+        in_specs=in_specs,
+        out_specs=plan.block_spec((1, 1, block_q, d), q_place),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+        ],
+        name="flash_attention",
+    )
+    if mesh is None:
+        return call(q, k, v, *pos_operand)
 
     # shard the query-block axis: q/o split along the sequence dim,
     # k/v replicated; each device runs its contiguous query-row band
@@ -519,13 +321,7 @@ def _flash_impl(q, k, v, seq_pos=None, *, kind, window, scale, block_q,
     from repro.core.shard import device_tables
 
     axis = shard_axis
-    if target.block_indexed:
-        tbl, luts = device_tables(plan)
-    else:
-        # gpu structure reads only the shard-table row in-kernel (the
-        # prefetch_lut/mma extents table is bound inside the call), so
-        # skip building/transferring the chunked decode LUT entirely
-        tbl, luts = jnp.asarray(plan.shard_table_host()), ()
+    tbl, luts = device_tables(plan)
     qkv_specs = (P(None, None, axis, None), P(None, None, None, None),
                  P(None, None, None, None))
 
@@ -557,9 +353,7 @@ def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
                     grid_mode: str = "compact",
                     storage: str = "embedded",
                     kv_seq_len: int | None = None, seq_pos=None,
-                    backend=None, num_warps: int | str | None = None,
-                    num_stages: int | str | None = None,
-                    interpret: bool | None = None, mesh=None,
+                    backend=None, interpret: bool | None = None, mesh=None,
                     shard_axis: str = "data",
                     shard_balance: str = "contiguous",
                     verify: bool = False):
@@ -585,24 +379,10 @@ def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
                requires ``kind="full"``; combine with ``window=`` for
                a run-time sliding window): keys at ``kpos > seq_pos``
                are masked and key blocks beyond ``seq_pos // block_k``
-               are predicated off (an SMEM vector on TPU, a regular
-               operand on GPU).  The gpu structure's loop bound
-               truncates the tile *reads* too; the TPU structure's
-               static grid still pipelines every tile and skips only
-               their compute.
-    backend:   emission target ("tpu" | "gpu" | "*-interpret" | None =
-               platform default; see :mod:`repro.core.backend`).  The
-               gpu structure runs one program per query-block row with
-               an in-kernel loop over its key extent; ``num_stages``
-               >= 2 ("auto" = tuned) software-pipelines that loop (a
-               KV-tile FIFO in the loop carry prefetches key block
-               k+stages-1 while the softmax consumes block k;
-               bit-identical to the synchronous loop) and, on a real
-               GPU, also reaches the Triton scheduler together with
-               ``num_warps``.  The TPU structure accepts the knob but
-               keeps it at the grid level: Mosaic already
-               double-buffers BlockSpec operand copies across the
-               sequential grid.
+               are predicated off (an SMEM vector).  The static grid
+               still pipelines every tile and skips only their compute.
+    backend:   emission target ("tpu" | "tpu-interpret" | None =
+               platform default; see :mod:`repro.core.backend`).
     causal requires Sq == Sk; local accepts Sq < Sk with the decode
     convention (queries are the last Sq positions) when
     Sk - Sq >= window (full window per query block).
@@ -628,26 +408,21 @@ def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
     b, h, sq, d = q.shape
     _, hkv, _, _ = k.shape
     sk = kv_seq_len if kv_seq_len is not None else k.shape[2]
-    grid_mode, block_q, block_k, num_warps, num_stages = \
-        resolve_auto_schedule(
-            "flash",
-            tune.target_params(
-                tune.shard_params(
-                    {"kind": kind, "batch": b, "heads": h,
-                     "kv_heads": hkv, "sq": sq, "sk": sk, "d": d,
-                     "window": window},
-                    mesh, shard_axis),
-                target),
-            grid_mode=(grid_mode, "lowering", "closed_form"),
-            block_q=(block_q, "block_q", 128),
-            block_k=(block_k, "block_k", 128),
-            num_warps=(num_warps, "num_warps", None),
-            num_stages=(num_stages, "num_stages", None))
+    grid_mode, block_q, block_k = resolve_auto_schedule(
+        "flash",
+        tune.target_params(
+            tune.shard_params(
+                {"kind": kind, "batch": b, "heads": h, "kv_heads": hkv,
+                 "sq": sq, "sk": sk, "d": d, "window": window},
+                mesh, shard_axis),
+            target),
+        grid_mode=(grid_mode, "lowering", "closed_form"),
+        block_q=(block_q, "block_q", 128),
+        block_k=(block_k, "block_k", 128))
     return _flash_impl(q, k, v, seq_pos, kind=kind, window=window,
                        scale=scale, block_q=block_q, block_k=block_k,
                        grid_mode=grid_mode, storage=storage,
                        kv_seq_len=kv_seq_len, backend=target,
-                       num_warps=num_warps, num_stages=num_stages,
                        mesh=mesh, shard_axis=shard_axis,
                        shard_balance=shard_balance, verify=verify)
 
@@ -657,9 +432,9 @@ def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
 # ---------------------------------------------------------------------------
 
 def paged_decode_grid(slots: int, max_pages: int):
-    """The block-indexed paged decode kernel's grid: one batch dimension
-    over the slots and, per slot, the domain of its logical pages -- one
-    grid step per (slot, page), each reading every head of that page.
+    """The paged decode kernel's grid: one batch dimension over the
+    slots and, per slot, the domain of its logical pages -- one grid
+    step per (slot, page), each reading every head of that page.
     Returns ``(batch_dims, domain)`` for the kernel's
     :class:`~repro.core.paged.PagedPlan`."""
     return (int(slots),), make_attention_domain("full", 1, int(max_pages), 0)
@@ -667,9 +442,9 @@ def paged_decode_grid(slots: int, max_pages: int):
 
 def paged_decode_grid_steps(slots: int, max_pages: int,
                             layers: int = 1) -> int:
-    """Grid steps the block-indexed paged decode kernel runs for one
-    decode step of ``layers`` paged attention layers (every lowering
-    launches the same count: the domain is one full row of pages)."""
+    """Grid steps the paged decode kernel runs for one decode step of
+    ``layers`` paged attention layers (every lowering launches the same
+    count: the domain is one full row of pages)."""
     batch_dims, domain = paged_decode_grid(slots, max_pages)
     return int(layers) * int(np.prod(batch_dims)) * domain.num_blocks
 
@@ -684,10 +459,10 @@ def _paged_live_pages(pos, page_size, window):
 
 
 def _paged_tile_vmem_bytes(h, hkv, page_size, d, itemsize):
-    """VMEM one grid step of the block-indexed paged kernel holds: the
-    double-buffered fused page tile and query block in the pool's dtype,
-    and the float32 K and V every query head reads, all at the chip's
-    tiled layout (lanes padded to 128, sublanes to the dtype's tile)."""
+    """VMEM one grid step of the paged kernel holds: the double-buffered
+    fused page tile and query block in the pool's dtype, and the float32
+    K and V every query head reads, all at the chip's tiled layout
+    (lanes padded to 128, sublanes to the dtype's tile)."""
     lanes = -(-d // 128) * 128
     sub = 8 * max(1, 4 // itemsize)
     rows = -(-page_size // sub) * sub
@@ -697,20 +472,19 @@ def _paged_tile_vmem_bytes(h, hkv, page_size, d, itemsize):
     return 2 * (tile + qblk) + f32
 
 
-#: VMEM the block-indexed paged kernel may plan for one grid step: the
-#: default scoped VMEM limit of a TPU v5e core
+#: VMEM the paged kernel may plan for one grid step: the default
+#: scoped VMEM limit of a TPU v5e core
 PAGED_VMEM_BUDGET = 16 << 20
 
 
 def _paged_attn_kernel(coords, q_ref, kv_ref, o_ref, acc_ref, m_ref,
                        l_ref, *, window, scale, page_size, group,
                        unroll_heads):
-    """Block-indexed (TPU) paged decode kernel.  One grid step per
-    (slot, logical page), reading every head: the query block is the
-    slot's ``(1, h, d)`` rows and the KV block the page's whole
-    ``(1, 2*hkv, page_size, d)`` fused tile, whose *physical* page the
-    BlockSpec index map already resolved from the prefetched page table
-    (``coords.refs[0]``).  K of KV head ``j`` is row ``2j`` of the tile
+    """Paged decode kernel.  One grid step per (slot, logical page),
+    reading every head: the query block is the slot's ``(1, h, d)``
+    rows and the KV block the page's whole ``(1, 2*hkv, page_size, d)``
+    fused tile, whose *physical* page the BlockSpec index map already
+    resolved from the prefetched page table (``coords.refs[0]``).  K of KV head ``j`` is row ``2j`` of the tile
     and V row ``2j + 1``; query head ``i`` reads KV head ``i // group``.
     The index map clamps the logical page into the slot's live pages, so
     a step past the slot's last page (or before its window's first)
@@ -768,73 +542,10 @@ def _paged_attn_kernel(coords, q_ref, kv_ref, o_ref, acc_ref, m_ref,
         pl.when(coords.valid & live)(body)
 
 
-def _gpu_paged_call(*, target, b, h, group, m_k, page_size, d, window,
-                    scale, out_shape, dtype, num_warps=None,
-                    num_stages=None):
-    """gpu-structured paged decode: one program per (slot, head), the
-    whole pool and page table as HBM operands, an in-kernel loop over
-    the slot's logical key blocks that resolves each physical page with
-    a table read and ``backend.load``\\ s the fused ``(2, page_size, d)``
-    head tile at its offset.  The loop bound comes from the slot's
-    ``seq_pos``, so only O(pos / page_size) pages are *read* -- the
-    block-space work saving at run time."""
-
-    def kern(pt_ref, q_ref, kv_ref, pos_ref, o_ref):
-        bh = pl.program_id(0)
-        slot = bh // h
-        kvh = (bh % h) // group
-        pos = pos_ref[slot]
-        start = 0 * pos
-        end = pos // page_size
-        if window:
-            start = jnp.maximum(pos - window + 1, 0) // page_size
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-
-        def load_tiles(kb):
-            page = pt_ref[slot, kb]
-            t = backend_lib.load(
-                kv_ref, (pl.ds(page, 1), pl.ds(2 * kvh, 2),
-                         pl.ds(0, page_size), pl.ds(0, d)))
-            t = t.reshape(2, page_size, d).astype(jnp.float32)
-            return t[0], t[1]
-
-        def step(j, carry):
-            kb = start + j
-            k_t, v_t = load_tiles(kb)
-            return _attn_tile_update(
-                q, k_t, v_t, *carry, kind="full", window=window,
-                qb=0 * kb, kb=kb, block_q=1, block_k=page_size, off=0,
-                seq_pos=pos)
-
-        acc0 = (jnp.zeros((1, d), jnp.float32),
-                jnp.full((1, 1), NEG_INF, jnp.float32),
-                jnp.zeros((1, 1), jnp.float32))
-        acc, _, l = jax.lax.fori_loop(0, end - start + 1, step, acc0)
-        l = jnp.where(l == 0, 1.0, l)
-        o_ref[0, 0, ...] = (acc / l).astype(o_ref.dtype)
-
-    q_spec = pl.BlockSpec((1, 1, 1, d), lambda bh: (bh // h, bh % h, 0, 0))
-    extra = target.call_kwargs(num_warps, num_stages)
-
-    def call(pt, q, kv_pool, pos):
-        c = pl.pallas_call(
-            kern, grid=(b * h,),
-            in_specs=[full_spec(pt.shape), q_spec,
-                      full_spec(kv_pool.shape), full_spec((b,))],
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct(out_shape, dtype),
-            interpret=target.interpret, name=PAGED_KERNEL_NAME, **extra)
-        return c(pt, q, kv_pool, pos)
-
-    return call
-
-
 @functools.partial(jax.jit, static_argnames=(
-    "window", "scale", "grid_mode", "backend", "num_warps",
-    "num_stages", "verify"))
+    "window", "scale", "grid_mode", "backend", "verify"))
 def _paged_impl(q, kv_pool, page_table, seq_pos, *, window, scale,
-                grid_mode, backend, num_warps=None, num_stages=None,
-                verify=False):
+                grid_mode, backend, verify=False):
     from repro.core.paged import PagedPlan
 
     b, h, sq, d = q.shape
@@ -863,14 +574,6 @@ def _paged_impl(q, kv_pool, page_table, seq_pos, *, window, scale,
         from repro.analysis import verify_or_raise
         verify_or_raise(GridPlan(domain, grid_mode, batch_dims=batch_dims,
                                  backend=target), kernel="flash")
-
-    if not target.block_indexed:
-        call = _gpu_paged_call(
-            target=target, b=b, h=h, group=group, m_k=m_k,
-            page_size=page_size, d=d, window=window, scale=scale,
-            out_shape=q.shape, dtype=q.dtype, num_warps=num_warps,
-            num_stages=num_stages)
-        return call(page_table, q, kv_pool, pos)
 
     need = _paged_tile_vmem_bytes(h, hkv, page_size, d,
                                   jnp.dtype(kv_pool.dtype).itemsize)
@@ -911,11 +614,10 @@ def _paged_impl(q, kv_pool, page_table, seq_pos, *, window, scale,
         out_specs=plan.block_spec((1, h, d), slot_place),
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         scratch_shapes=[
-            target.scratch((h, d), jnp.float32),
-            target.scratch((h, 1), jnp.float32),
-            target.scratch((h, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
         ],
-        num_warps=num_warps, num_stages=num_stages,
         name=PAGED_KERNEL_NAME,
     )
     return call(q.reshape(b, h, d), kv_pool).reshape(q.shape)
@@ -924,8 +626,6 @@ def _paged_impl(q, kv_pool, page_table, seq_pos, *, window, scale,
 def paged_flash_attention(q, kv_pool, page_table, seq_pos, *,
                           window: int = 0, scale: float | None = None,
                           grid_mode: str = "compact", backend=None,
-                          num_warps: int | None = None,
-                          num_stages: int | None = None,
                           interpret: bool | None = None,
                           verify: bool = False):
     """Paged single-token decode attention over a fused-KV page pool.
@@ -938,21 +638,18 @@ def paged_flash_attention(q, kv_pool, page_table, seq_pos, *,
                 per slot (null-page entries beyond each slot's length).
     seq_pos:    (B,) int32 per-slot decode positions (a scalar
                 broadcasts).  Keys beyond a slot's position are masked;
-                pages beyond ``pos // page_size`` are never read on
-                either structure.
+                pages beyond ``pos // page_size`` are never read.
     window:     optional run-time sliding window anchored at seq_pos.
 
-    On block-indexed (TPU) targets the grid is ``B x max_pages``
-    (:func:`paged_decode_grid`): one step per (slot, logical page), each
-    reading the page's whole fused tile for every head, so a page is
-    copied once per slot, not once per head.  The page table and the
-    positions are scalar-prefetch operands, resolved in the KV BlockSpec
-    index map -- the lambda-map indirection of the paper, pointed at
-    physical memory -- which clamps each step's logical page into the
-    slot's live pages: steps past a slot's end repeat its last block
-    index and copy nothing.  A page tile over ``PAGED_VMEM_BUDGET`` is
-    refused.  On gpu structures the table is a leading HBM operand read
-    in-kernel, one program per (slot, head).  Bit-identical to the
+    The grid is ``B x max_pages`` (:func:`paged_decode_grid`): one step
+    per (slot, logical page), each reading the page's whole fused tile
+    for every head, so a page is copied once per slot, not once per
+    head.  The page table and the positions are scalar-prefetch
+    operands, resolved in the KV BlockSpec index map -- the lambda-map
+    indirection of the paper, pointed at physical memory -- which clamps
+    each step's logical page into the slot's live pages: steps past a
+    slot's end repeat its last block index and copy nothing.  A page
+    tile over ``PAGED_VMEM_BUDGET`` is refused.  Bit-identical to the
     contiguous ``flash_attention(..., kind="full", seq_pos=...)`` path
     with ``block_k == page_size`` when the mapped pages hold the same
     values."""
@@ -961,5 +658,4 @@ def paged_flash_attention(q, kv_pool, page_table, seq_pos, *,
     return _paged_impl(q, kv_pool, page_table, seq_pos, window=window,
                        scale=scale,
                        grid_mode=normalize_lowering(grid_mode),
-                       backend=target, num_warps=num_warps,
-                       num_stages=num_stages, verify=verify)
+                       backend=target, verify=verify)

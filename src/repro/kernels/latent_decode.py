@@ -22,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import backend as backend_lib
 from repro.core.domain import make_attention_domain
@@ -86,18 +87,12 @@ def _latent_decode_impl(q, pool, page_table, seq_pos, *, scale, v_dim,
     if page_table.shape[0] != b:
         raise ValueError(
             f"page_table rows ({page_table.shape[0]}) != slots ({b})")
-    target = backend
-    if not target.block_indexed:
-        raise ValueError(
-            f"the latent decode kernel has a block-indexed (TPU) "
-            f"structure only, not {target.name!r}: use the XLA path "
-            f"(attn_decode_kernel='xla')")
     m_k = page_table.shape[1]
     page_table = page_table.astype(jnp.int32)
     pos = jnp.broadcast_to(
         jnp.asarray(seq_pos, jnp.int32).reshape(-1), (b,))
     plan = PagedPlan(make_attention_domain("full", 1, m_k, 0), grid_mode,
-                     batch_dims=(b,), backend=target, page_table=page_table)
+                     batch_dims=(b,), backend=backend, page_table=page_table)
 
     def slot_place(bx, by, slot):
         return (slot, 0, 0)
@@ -114,12 +109,12 @@ def _latent_decode_impl(q, pool, page_table, seq_pos, *, scale, v_dim,
         kernel,
         in_specs=[plan.block_spec((1, h, w), slot_place),
                   plan._index_spec((1, page_size, w), page_index),
-                  target.scalar_spec()],
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=plan.block_spec((1, h, v_dim), slot_place),
         out_shape=jax.ShapeDtypeStruct((b, h, v_dim), q.dtype),
-        scratch_shapes=[target.scratch((h, v_dim), jnp.float32),
-                        target.scratch((h, 1), jnp.float32),
-                        target.scratch((h, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((h, v_dim), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32)],
         name=LATENT_KERNEL_NAME)
     return call(q, pool, pos)
 

@@ -53,7 +53,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import backend as backend_lib
-from repro.core.backend import full_spec
 from repro.core.compact import NEIGHBOR_OFFSETS8
 from repro.core.domain import BlockDomain
 from repro.core.plan import GridPlan
@@ -125,11 +124,12 @@ def _round_up(x: int, m: int) -> int:
 
 def _trapezoid_update(tiles, bx, by, steps, *, rule, alpha, block, n,
                       plan, halo):
-    """The fused-CA math, shared by both emission structures: assemble
-    the working array from the center + 8 neighbour supertiles
-    (embedded-storage arrangement; packed fine-block arrangement under
-    compact coarsening), advance the shrinking trapezoid ``steps``
-    times, and return the output supertile in storage arrangement.
+    """The fused-CA math, shared by the synchronous and the
+    DMA-pipelined kernels: assemble the working array from the center +
+    8 neighbour supertiles (embedded-storage arrangement; packed
+    fine-block arrangement under compact coarsening), advance the
+    shrinking trapezoid ``steps`` times, and return the output
+    supertile in storage arrangement.
 
     ``tiles``: 9 arrays in [center] + NEIGHBOR_OFFSETS8 order, each the
     plan's storage-supertile shape.  ``(bx, by)``: scheduled (coarse)
@@ -244,8 +244,7 @@ def _ca_fused_kernel(coords, c_ref, n_ref, s_ref, w_ref, e_ref, nw_ref,
                      ne_ref, sw_ref, se_ref, buf_ref, steps_ref, o_ref,
                      *, rule, alpha, block, n, plan, halo):
     """Advance one (super)block by ``steps_ref[0] <= halo`` CA steps
-    (block-indexed structure: the 9 supertiles arrive as BlockSpec-fed
-    operand views)."""
+    (the 9 supertiles arrive as BlockSpec-fed operand views)."""
     TRACE_COUNTER["kernel"] += 1
     nbr_refs = (n_ref, s_ref, w_ref, e_ref, nw_ref, ne_ref, sw_ref,
                 se_ref)
@@ -267,14 +266,14 @@ def _ca_fused_kernel(coords, c_ref, n_ref, s_ref, w_ref, e_ref, nw_ref,
 def _ca_fused_kernel_dma(coords, c_ref, buf_ref, steps_ref, o_ref,
                          bufs_ref, sems, *, rule, alpha, block, n, plan,
                          halo, stages):
-    """Async-copy pipelined fused CA (TPU structure, ``num_stages`` >=
-    2): the state is parked whole in ``pl.ANY`` and the kernel
-    streams each step's 9 supertiles (center + 8 lambda^-1-resolved
-    neighbours) into rotating VMEM buffers with explicit DMA -- the
-    copies for grid step t+stages-1 start before step t's trapezoid
-    runs, hiding the tile fetches behind compute.  Tile addressing,
-    visit order and the trapezoid math are exactly the synchronous
-    kernel's, so results are bit-identical."""
+    """Async-copy pipelined fused CA (``num_stages`` >= 2): the state
+    is parked whole in ``pl.ANY`` and the kernel streams each step's 9
+    supertiles (center + 8 lambda^-1-resolved neighbours) into rotating
+    VMEM buffers with explicit DMA -- the copies for grid step
+    t+stages-1 start before step t's trapezoid runs, hiding the tile
+    fetches behind compute.  Tile addressing, visit order and the
+    trapezoid math are exactly the synchronous kernel's, so results are
+    bit-identical."""
     TRACE_COUNTER["kernel"] += 1
     refs = coords.refs
     total = plan.steps_per_launch
@@ -304,67 +303,33 @@ def _ca_fused_kernel_dma(coords, c_ref, buf_ref, steps_ref, o_ref,
                       else None)
 
 
-def _ca_fused_kernel_gpu(coords, c_ref, buf_ref, steps_ref, o_ref, *,
-                         rule, alpha, block, n, plan, halo):
-    """gpu-structured fused CA: the state arrives whole; the kernel
-    gathers the center + 8 lambda^-1-resolved neighbour supertiles with
-    computed offsets (slot indices from the plan -- an O(1) read of the
-    HBM LUT operand under ``prefetch_lut``) and stores the advanced
-    interior back at the center slot."""
-    TRACE_COUNTER["kernel"] += 1
-    th, tw = plan.supertile_shape((block, block))
-    gi, refs = coords.grid_ids, coords.refs
-
-    def load_at(iy, ix):
-        return backend_lib.load(
-            c_ref, (pl.ds(iy * th, th), pl.ds(ix * tw, tw)))
-
-    def body():
-        cy, cx = plan.storage_index(gi, refs)
-        tiles = [load_at(cy, cx)]
-        for j in range(8):
-            ny, nx = plan.neighbor_index(j, gi, refs)
-            tiles.append(load_at(ny, nx))
-        out = _trapezoid_update(
-            tiles, coords.bx, coords.by, steps_ref[0], rule=rule,
-            alpha=alpha, block=block, n=n, plan=plan, halo=halo)
-        backend_lib.store(o_ref, (pl.ds(cy * th, th), pl.ds(cx * tw, tw)),
-                          out.astype(o_ref.dtype))
-
-    coords.when_valid(body)
-
-
 def _build_launch(plan, *, rule, alpha, block, n, halo, shape, dtype,
-                  in_shape=None, stages=1):
+                  stages=1):
     """One fused pallas_call: (state, stale, steps[1]) -> new state.
 
-    Block-indexed targets receive nine BlockSpec views of the state;
-    with ``stages >= 2`` on an async-copy target the state instead
-    arrives whole (``pl.ANY``) and the kernel streams the nine tiles
-    through rotating VMEM DMA buffers (:func:`_ca_fused_kernel_dma`).
-    gpu targets receive it whole (``in_shape``, which may be the
-    halo-extended local array under sharding) plus the stale buffer and
-    the step count as a regular scalar operand; their per-step tile
-    gather is already load-then-compute, so ``stages`` only feeds the
-    Triton scheduler on real GPUs."""
+    The kernel receives nine BlockSpec views of the state; with
+    ``stages >= 2`` the state instead arrives whole (``pl.ANY``) and the
+    kernel streams the nine tiles through rotating VMEM DMA buffers
+    (:func:`_ca_fused_kernel_dma`)."""
     TRACE_COUNTER["build"] += 1
-    target = plan.target
-    stages = target.resolve_stages(stages)
+    stages = plan.target.resolve_stages(stages)
     plan.check_output_writeback()
     kernel_kw = dict(rule=rule, alpha=alpha, block=block, n=n, plan=plan,
                      halo=halo)
-    if target.block_indexed and stages > 1:
+    steps_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    if stages > 1:
         tile = plan.storage_spec((block, block))
         th, tw = plan.supertile_shape((block, block))
         call = plan.pallas_call(
             functools.partial(_ca_fused_kernel_dma, **kernel_kw,
                               stages=stages),
-            in_specs=[target.any_spec(), tile, target.scalar_spec()],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY), tile,
+                      steps_spec],
             out_specs=tile,
             out_shape=jax.ShapeDtypeStruct(shape, dtype),
             scratch_shapes=[
-                target.scratch((stages, 9, th, tw), dtype),
-                target.dma_sems((stages, 9)),
+                pltpu.VMEM((stages, 9, th, tw), dtype),
+                pltpu.SemaphoreType.DMA((stages, 9)),
             ],
             input_output_aliases={1: 0},
             name=KERNEL_NAME,
@@ -374,40 +339,22 @@ def _build_launch(plan, *, rule, alpha, block, n, halo, shape, dtype,
             return call(*prefetch, a, b, steps_scalar)
         return launch
 
-    if target.block_indexed:
-        tile = plan.storage_spec((block, block))
-        in_specs = [tile]
-        in_specs += [plan.neighbor_spec((block, block), j)
-                     for j in range(8)]
-        in_specs += [tile]                       # stale buffer
-        in_specs += [plan.target.scalar_spec()]  # step count
-        call = plan.pallas_call(
-            functools.partial(_ca_fused_kernel, **kernel_kw),
-            in_specs=in_specs,
-            out_specs=tile,
-            out_shape=jax.ShapeDtypeStruct(shape, dtype),
-            input_output_aliases={9: 0},
-            name=KERNEL_NAME,
-        )
-
-        def launch(a, b, steps_scalar, prefetch=()):
-            return call(*prefetch, a, a, a, a, a, a, a, a, a, b,
-                        steps_scalar)
-        return launch
-
+    tile = plan.storage_spec((block, block))
+    in_specs = [tile]
+    in_specs += [plan.neighbor_spec((block, block), j) for j in range(8)]
+    in_specs += [tile]                       # stale buffer
+    in_specs += [steps_spec]                 # step count
     call = plan.pallas_call(
-        functools.partial(_ca_fused_kernel_gpu, **kernel_kw),
-        in_specs=[full_spec(in_shape or shape), full_spec(shape),
-                  plan.target.scalar_spec()],
-        out_specs=full_spec(shape),
+        functools.partial(_ca_fused_kernel, **kernel_kw),
+        in_specs=in_specs,
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(shape, dtype),
-        input_output_aliases={1: 0},
-        num_stages=stages if stages > 1 else None,
+        input_output_aliases={9: 0},
         name=KERNEL_NAME,
     )
 
     def launch(a, b, steps_scalar, prefetch=()):
-        return call(*prefetch, a, b, steps_scalar)
+        return call(*prefetch, a, a, a, a, a, a, a, a, a, b, steps_scalar)
     return launch
 
 
@@ -476,17 +423,9 @@ def _ca_run_sharded_impl(state, stale_buf, *, steps, fuse, rule, alpha,
     if not sched:
         return state
     local_shape = plan.local_storage_shape(block)
-    if storage == "compact":
-        # the center operand is the halo-extended local array
-        rpd, ru = plan.rpd, plan.row_unit
-        ext_rows = (rpd + plan.halo.h_max + 1) * ru
-        in_shape = (ext_rows, local_shape[1])
-    else:
-        in_shape = local_shape
     launch = _build_launch(plan, rule=rule, alpha=alpha, block=block,
                            n=n, halo=fuse, shape=local_shape,
-                           dtype=state.dtype, in_shape=in_shape,
-                           stages=stages)
+                           dtype=state.dtype, stages=stages)
     tbl, luts = device_tables(plan)
     sched_arr = jnp.asarray(sched, jnp.int32)
     axis = shard_axis
@@ -513,11 +452,11 @@ def _ca_run_sharded_impl(state, stale_buf, *, steps, fuse, rule, alpha,
             launch_int = _build_launch(
                 plan.phase_view("interior"), rule=rule, alpha=alpha,
                 block=block, n=n, halo=fuse, shape=local_shape,
-                dtype=state.dtype, in_shape=in_shape, stages=stages)
+                dtype=state.dtype, stages=stages)
             launch_bnd = _build_launch(
                 plan.phase_view("boundary"), rule=rule, alpha=alpha,
                 block=block, n=n, halo=fuse, shape=local_shape,
-                dtype=state.dtype, in_shape=in_shape, stages=stages)
+                dtype=state.dtype, stages=stages)
             itb, btb = jnp.asarray(int_h), jnp.asarray(bnd_h)
 
             def device_fn(tbl, luts, itb, btb, sr, a, b):
@@ -633,20 +572,18 @@ def ca_run(state: jnp.ndarray, stale_buf: jnp.ndarray, steps: int, *,
     bit-identical to the single-device run.
 
     ``num_stages`` >= 2 ("auto" = tuned) software-pipelines each
-    launch on capable targets (see README "Pipelining"): the TPU
-    structure streams the 9 halo supertiles through rotating
-    async-copy VMEM buffers so step t+1's fetches overlap step t's
-    trapezoid; under a sharded compact run the scan also splits each
-    launch into interior and boundary phases so the ppermute ghost
-    exchange overlaps interior compute.  Bit-identical to the
-    synchronous path.
+    launch (see README "Pipelining"): the kernel streams the 9 halo
+    supertiles through rotating async-copy VMEM buffers so step t+1's
+    fetches overlap step t's trapezoid; under a sharded compact run the
+    scan also splits each launch into interior and boundary phases so
+    the ppermute ghost exchange overlaps interior compute.
+    Bit-identical to the synchronous path.
 
-    ``backend`` selects the emission target ("tpu" | "gpu" |
-    "*-interpret" | None = platform default; see
-    :mod:`repro.core.backend`).  ``verify=True`` statically verifies
-    the emitted plan (coverage / races / tables / bounds / aliasing;
-    :mod:`repro.analysis`) at trace time and raises on any
-    violation."""
+    ``backend`` selects the emission target ("tpu" | "tpu-interpret" |
+    None = platform default; see :mod:`repro.core.backend`).
+    ``verify=True`` statically verifies the emitted plan (coverage /
+    races / tables / bounds / aliasing; :mod:`repro.analysis`) at trace
+    time and raises on any violation."""
     target = backend_lib.resolve(backend, interpret)
     with span("kernels.ca_run") as entry:
         with span("kernels.ca_run.schedule"):

@@ -1,7 +1,7 @@
 """The paper's SS IV microbenchmark as Pallas kernels, lowered through
 the unified :class:`~repro.core.plan.GridPlan` engine on any
-:mod:`~repro.core.backend` target (TPU Mosaic, GPU Triton, or either
-under the interpreter).
+:mod:`~repro.core.backend` target (Mosaic on the TPU, or the same
+structure under the interpreter).
 
 Three lowerings, extending the paper's A/B to the LUT variant of the
 follow-up work:
@@ -46,9 +46,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import backend as backend_lib
-from repro.core.backend import full_spec
 from repro.core.domain import BlockDomain, make_fractal_domain
 from repro.core.plan import GridPlan, normalize_storage
 from repro.runtime.trace import span
@@ -231,12 +231,12 @@ def _stream_storage_tile(coords, m_ref, bufs_ref, sems, plan, stages):
 
 def _write_kernel_dma(coords, m_ref, alias_ref, o_ref, bufs_ref, sems,
                       *, value, block, n, plan, stages):
-    """Async-copy pipelined write (TPU structure, ``num_stages`` >= 2):
-    the state is parked in ``pl.ANY`` and each step's input tile
-    streams through rotating VMEM DMA buffers while the next step's
-    copy is in flight.  ``alias_ref`` is the same state routed as a
-    BlockSpec operand purely to alias the unwritten remainder to the
-    output; the kernel never reads it."""
+    """Async-copy pipelined write (``num_stages`` >= 2): the state is
+    parked in ``pl.ANY`` and each step's input tile streams through
+    rotating VMEM DMA buffers while the next step's copy is in flight.
+    ``alias_ref`` is the same state routed as a BlockSpec operand purely
+    to alias the unwritten remainder to the output; the kernel never
+    reads it."""
     del alias_ref
     tile = _stream_storage_tile(coords, m_ref, bufs_ref, sems, plan,
                                 stages)
@@ -253,75 +253,41 @@ def _write_kernel_dma(coords, m_ref, alias_ref, o_ref, bufs_ref, sems,
                       else None)
 
 
-def _write_kernel_gpu(coords, m_ref, o_ref, *, value, block, n, plan):
-    """gpu-structured write: the state arrives whole; the kernel
-    resolves its supertile offset itself (the plan's storage index,
-    reading the HBM LUT operand under ``prefetch_lut``) and
-    loads/stores with computed offsets."""
-    th, tw = plan.supertile_shape((block, block))
-
-    def body():
-        iy, ix = plan.storage_index(coords.grid_ids, coords.refs)
-        idx = (pl.ds(iy * th, th), pl.ds(ix * tw, tw))
-        tile = backend_lib.load(m_ref, idx)
-        mask = _tile_mask(plan, coords.bx, coords.by, block, n)
-        backend_lib.store(
-            o_ref, idx,
-            jnp.where(mask, jnp.asarray(value, o_ref.dtype), tile))
-
-    coords.when_valid(body)
-
-
 def _emit_write(plan: GridPlan, shape, dtype, *, value, block, n,
                 stages=1):
-    """The write pallas_call for either emission structure: BlockSpec
-    tiles on block-indexed (TPU) targets, whole-array refs + in-kernel
-    addressing on GPU targets.  The unwritten remainder keeps the input
-    through the output alias either way.  ``stages >= 2`` on an
-    async-copy target streams the input tiles through rotating DMA
-    buffers instead (:func:`_write_kernel_dma`); on the GPU structure
-    it only feeds the Triton scheduler."""
-    target = plan.target
-    stages = target.resolve_stages(stages)
+    """The write pallas_call: one BlockSpec storage tile per grid step.
+    The unwritten remainder keeps the input through the output alias.
+    ``stages >= 2`` streams the input tiles through rotating DMA
+    buffers instead (:func:`_write_kernel_dma`)."""
+    stages = plan.target.resolve_stages(stages)
     plan.check_output_writeback()
-    if target.block_indexed and stages > 1:
+    if stages > 1:
         spec = plan.storage_spec((block, block))
         th, tw = plan.supertile_shape((block, block))
         call = plan.pallas_call(
             functools.partial(_write_kernel_dma, value=value,
                               block=block, n=n, plan=plan,
                               stages=stages),
-            in_specs=[target.any_spec(), spec],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY), spec],
             out_specs=spec,
             out_shape=jax.ShapeDtypeStruct(shape, dtype),
             scratch_shapes=[
-                target.scratch((stages, 1, th, tw), dtype),
-                target.dma_sems((stages, 1)),
+                pltpu.VMEM((stages, 1, th, tw), dtype),
+                pltpu.SemaphoreType.DMA((stages, 1)),
             ],
             input_output_aliases={1: 0},
             name="sierpinski_write",
         )
         # the state rides twice: ANY (DMA source) + BlockSpec (alias)
         return lambda *args: call(*args[:-1], args[-1], args[-1])
-    if target.block_indexed:
-        spec = plan.storage_spec((block, block))
-        return plan.pallas_call(
-            functools.partial(_write_kernel, value=value, block=block,
-                              n=n, plan=plan),
-            in_specs=[spec],
-            out_specs=spec,
-            out_shape=jax.ShapeDtypeStruct(shape, dtype),
-            input_output_aliases={0: 0},
-            name="sierpinski_write",
-        )
+    spec = plan.storage_spec((block, block))
     return plan.pallas_call(
-        functools.partial(_write_kernel_gpu, value=value, block=block,
-                          n=n, plan=plan),
-        in_specs=[full_spec(shape)],
-        out_specs=full_spec(shape),
+        functools.partial(_write_kernel, value=value, block=block, n=n,
+                          plan=plan),
+        in_specs=[spec],
+        out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(shape, dtype),
         input_output_aliases={0: 0},
-        num_stages=stages if stages > 1 else None,
         name="sierpinski_write",
     )
 
@@ -427,10 +393,10 @@ def sierpinski_write(m: jnp.ndarray, value: float = 1.0, *,
     storage: embedded (m is the dense n x n array) | compact (m is the
     packed orthotope array, pass n= or domain=); coarsen: superblock
     side in fine blocks (or "auto"); backend: emission target
-    ("tpu" | "gpu" | "*-interpret" | None = platform default, see
+    ("tpu" | "tpu-interpret" | None = platform default, see
     :mod:`repro.core.backend`); num_stages: software-pipeline depth
-    (">= 2" streams input tiles through async-copy DMA buffers on
-    capable targets, "auto" = tuned; bit-identical either way);
+    (">= 2" streams input tiles through async-copy DMA buffers,
+    "auto" = tuned; bit-identical either way);
     mesh/shard_axis: shard the write across
     a mesh axis (embarrassing: disjoint block ownership, psum combine
     under embedded storage); verify: statically verify the emitted plan
@@ -501,77 +467,33 @@ def _sum_kernel_dma(coords, m_ref, o_ref, bufs_ref, sems, *, block, n,
     coords.when_valid(body)
 
 
-def _sum_kernel_gpu(coords, m_ref, o_ref, *, block, n, plan):
-    """gpu-structured sum: a parallel grid cannot revisit one
-    accumulator, so each step stores its per-tile partial at its step
-    slot; the driver reduces the slots *in step order*, reproducing the
-    sequential grid's accumulation bit-for-bit."""
-    th, tw = plan.supertile_shape((block, block))
-    t = plan.linear_step(coords.grid_ids)
-    out_idx = (pl.ds(t, 1), pl.ds(0, 1))
-    backend_lib.store(o_ref, out_idx, jnp.zeros((1, 1), jnp.float32))
-
-    def body():
-        iy, ix = plan.storage_index(coords.grid_ids, coords.refs)
-        tile = backend_lib.load(
-            m_ref, (pl.ds(iy * th, th), pl.ds(ix * tw, tw)))
-        mask = _tile_mask(plan, coords.bx, coords.by, block, n)
-        part = jnp.sum(jnp.where(mask, tile, 0).astype(jnp.float32))
-        backend_lib.store(o_ref, out_idx, part.reshape(1, 1))
-
-    coords.when_valid(body)
-
-
-def _emit_sum(plan: GridPlan, shape, *, block, n, stages=1,
+def _emit_sum(plan: GridPlan, *, block, n, stages=1,
               dtype=jnp.float32):
-    """The sum pallas_call for either structure.  Returns
-    ``(call, finish)`` where ``finish`` maps the raw kernel output to
-    the (1, 1) f32 total: identity on sequential-grid targets (the
-    kernel accumulated in place), an in-step-order partials reduction
-    on parallel-grid targets.  ``stages >= 2`` streams the input tiles
-    through async-copy DMA buffers on capable targets."""
-    target = plan.target
-    stages = target.resolve_stages(stages)
-    if target.sequential_grid and stages > 1 and target.async_copy:
+    """The sum pallas_call: every grid step accumulates into the one
+    revisited (1, 1) f32 output block.  ``stages >= 2`` streams the
+    input tiles through async-copy DMA buffers."""
+    stages = plan.target.resolve_stages(stages)
+    if stages > 1:
         th, tw = plan.supertile_shape((block, block))
-        call = plan.pallas_call(
+        return plan.pallas_call(
             functools.partial(_sum_kernel_dma, block=block, n=n,
                               plan=plan, stages=stages),
-            in_specs=[target.any_spec()],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=plan.block_spec((1, 1), lambda bx, by: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
             scratch_shapes=[
-                target.scratch((stages, 1, th, tw), dtype),
-                target.dma_sems((stages, 1)),
+                pltpu.VMEM((stages, 1, th, tw), dtype),
+                pltpu.SemaphoreType.DMA((stages, 1)),
             ],
             name="sierpinski_sum",
         )
-        return call, lambda out: out
-    if target.sequential_grid:
-        call = plan.pallas_call(
-            functools.partial(_sum_kernel, block=block, n=n, plan=plan),
-            in_specs=[plan.storage_spec((block, block))],
-            out_specs=plan.block_spec((1, 1), lambda bx, by: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            name="sierpinski_sum",
-        )
-        return call, lambda out: out
-    steps = plan.steps_per_launch
-    call = plan.pallas_call(
-        functools.partial(_sum_kernel_gpu, block=block, n=n, plan=plan),
-        in_specs=[full_spec(shape)],
-        out_specs=full_spec((steps, 1)),
-        out_shape=jax.ShapeDtypeStruct((steps, 1), jnp.float32),
-        num_stages=stages if stages > 1 else None,
+    return plan.pallas_call(
+        functools.partial(_sum_kernel, block=block, n=n, plan=plan),
+        in_specs=[plan.storage_spec((block, block))],
+        out_specs=plan.block_spec((1, 1), lambda bx, by: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         name="sierpinski_sum",
     )
-
-    def finish(partials):
-        total = jax.lax.fori_loop(
-            0, steps, lambda i, acc: acc + partials[i, 0],
-            jnp.float32(0))
-        return total.reshape(1, 1)
-    return call, finish
 
 
 @functools.partial(jax.jit, static_argnames=("block", "grid_mode",
@@ -588,9 +510,9 @@ def _sum_impl(m, *, block, grid_mode, fractal, storage, n, domain,
     if verify:
         from repro.analysis import verify_or_raise
         verify_or_raise(plan, kernel="sum")
-    call, finish = _emit_sum(plan, m.shape, block=block, n=n,
-                             stages=stages, dtype=m.dtype)
-    return finish(call(m))[0, 0]
+    call = _emit_sum(plan, block=block, n=n, stages=stages,
+                     dtype=m.dtype)
+    return call(m)[0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "grid_mode",
@@ -615,16 +537,15 @@ def _sum_sharded_impl(m, *, block, grid_mode, fractal, storage, n,
     if verify:
         from repro.analysis import verify_or_raise
         verify_or_raise(plan, kernel="sum")
-    local_shape = plan.local_storage_shape(block)
-    call, finish = _emit_sum(plan, local_shape, block=block, n=n,
-                             stages=stages, dtype=m.dtype)
+    call = _emit_sum(plan, block=block, n=n, stages=stages,
+                     dtype=m.dtype)
     axis = shard_axis
     lut_specs = tuple(P(axis, None) for _ in luts)
     state_spec = P(axis, None) if storage == "compact" else P(None, None)
     a = plan.pad_rows(m, block) if storage == "compact" else m
 
     def device_fn(tbl, luts, a):
-        part = finish(call(tbl.reshape(-1), *luts, a))
+        part = call(tbl.reshape(-1), *luts, a)
         return jax.lax.psum(part, axis)
 
     out = jax.shard_map(

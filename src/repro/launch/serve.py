@@ -791,11 +791,10 @@ def main():
                     help="GridPlan lowering for the attention block "
                          "domain (default: the arch's attn_schedule)")
     ap.add_argument("--backend", default="",
-                    choices=("", "tpu", "gpu", "tpu-interpret",
-                             "gpu-interpret", "interpret"),
+                    choices=("", "tpu", "tpu-interpret", "interpret"),
                     help="kernel emission target for every block-space "
                          "Pallas call (repro.core.backend; default: "
-                         "platform / REPRO_BACKEND)")
+                         "the platform's)")
     ap.add_argument("--decode-kernel", default="",
                     choices=("", "xla", "blockspace"),
                     help="decode attention path: 'blockspace' runs the "

@@ -259,11 +259,10 @@ def main():
                          "by the sharding.py rules and the block-space "
                          "kernels (shard_axis 'data').")
     ap.add_argument("--backend", default="",
-                    choices=("", "tpu", "gpu", "tpu-interpret",
-                             "gpu-interpret", "interpret"),
+                    choices=("", "tpu", "tpu-interpret", "interpret"),
                     help="kernel emission target for every block-space "
                          "Pallas call (repro.core.backend; default: "
-                         "platform / REPRO_BACKEND)")
+                         "the platform's)")
     args = ap.parse_args()
 
     from repro.configs import get_config

@@ -317,15 +317,14 @@ def decode_attention_flash(q, k, v, pos, *, kind="causal", window=0,
     q: (B,H,1,D); k,v: (B,Hkv,Smax,D) caches; pos: () current position,
     or a (B,) int32 vector of *per-row* positions (continuous batching:
     every slot decodes at its own depth; a scalar broadcasts).
-    The kernel receives ``pos`` as a run-time operand (SMEM on
-    TPU, a regular operand on GPU): keys beyond ``pos`` are masked and
-    key *blocks* beyond ``pos // block_k`` are predicated off -- the
-    run-time analogue of the paper's block-space work saving.  On the
-    gpu structure the in-kernel loop bound truncates outright, so a
-    short sequence in a long cache *reads* O(pos / block_k) tiles; on
-    the TPU structure the static grid still pipelines every cache tile
-    through VMEM and only the dead blocks' compute is skipped
-    (``pl.when``), so the tile-traffic saving is gpu-only.
+    The kernel receives ``pos`` as a run-time SMEM operand: keys
+    beyond ``pos`` are masked and key *blocks* beyond ``pos // block_k``
+    are predicated off.  The static grid still pipelines every cache
+    tile through VMEM and skips only the dead blocks' compute
+    (``pl.when``), so this contiguous decode reads the whole cache on
+    the TPU however short the sequence; the paged decode
+    (:func:`decode_attention_paged`) does not, which is why the
+    contiguous path is queued for deletion with the contiguous server.
     ``kind='local'`` anchors the sliding window at ``pos`` inside the
     kernel.
 

@@ -9,8 +9,9 @@ Three layers:
   colliding storage index map, a dropped/duplicated grid step, an
   unsafe in-place alias declaration, a corrupted ghost-map entry --
   and assert the matching check flags it (no false negatives);
-* sanitizer: real kernel launches on both interpret targets, traced
-  accesses cross-checked against the static read/write sets.
+* sanitizer: real kernel launches under the interpreter, synchronous
+  and DMA-pipelined, traced index maps cross-checked against their
+  static evaluation.
 """
 import json
 
@@ -311,9 +312,15 @@ def test_autotune_all_rejected_raises(tmp_path):
 # interpret-mode access sanitizer
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["gpu-interpret", "tpu-interpret"])
+#: the synchronous launch, and the DMA-pipelined one (the state parked
+#: in ``pl.ANY``; only the aliased and output tiles ride BlockSpecs)
+_STAGES = [pytest.param(1, id="tpu-interpret"),
+           pytest.param(2, id="tpu-interpret-dma")]
+
+
+@pytest.mark.parametrize("num_stages", _STAGES)
 @pytest.mark.parametrize("storage", ["embedded", "compact"])
-def test_sanitizer_write_clean(backend, storage):
+def test_sanitizer_write_clean(num_stages, storage):
     from repro.core.compact import compact_layout
     from repro.kernels.sierpinski_write import sierpinski_write
     dom = make_fractal_domain("sierpinski-gasket", 8)
@@ -321,14 +328,14 @@ def test_sanitizer_write_clean(backend, storage):
         jnp.zeros(compact_layout(dom).array_shape(3), jnp.float32)
     out, findings = verify_launches(
         sierpinski_write, m, 1.0, block=3, grid_mode="closed_form",
-        storage=storage, domain=dom, num_stages=1, backend=backend,
-        kernel="write", strict=True)
+        storage=storage, domain=dom, num_stages=num_stages,
+        backend="tpu-interpret", strict=True)
     assert findings == []
     assert float(out.sum()) > 0
 
 
-@pytest.mark.parametrize("backend", ["gpu-interpret", "tpu-interpret"])
-def test_sanitizer_ca_clean(backend):
+@pytest.mark.parametrize("num_stages", _STAGES)
+def test_sanitizer_ca_clean(num_stages):
     from repro.core.compact import compact_layout
     from repro.kernels.sierpinski_ca import ca_run
     dom = make_fractal_domain("sierpinski-gasket", 8)
@@ -336,7 +343,7 @@ def test_sanitizer_ca_clean(backend):
     _, findings = verify_launches(
         ca_run, state, jnp.zeros_like(state), 2, fuse=1, block=3,
         grid_mode="closed_form", storage="compact", domain=dom,
-        num_stages=1, backend=backend, kernel="ca", strict=True)
+        num_stages=num_stages, backend="tpu-interpret", strict=True)
     assert findings == []
 
 

@@ -1,16 +1,7 @@
-"""Backend-parity tests: the gpu (Triton-structured) emission must be
-bit-identical to the tpu (Mosaic-structured) emission, both under the
-Pallas interpreter, for every kernel x storage x lowering -- plus
-capability-descriptor invariants, target resolution rules, and the
-host-table memoization the emission layer rides on.
-
-The two structures share the kernel *math* but differ in everything the
-BackendTarget describes: operand placement (BlockSpec index maps vs
-in-kernel HBM addressing), decode-table transport (scalar prefetch vs
-regular operands), run-time scalars (SMEM vs operand), and reduction
-state (sequential-grid scratch vs loop carries / ordered partials).
-Bit-identity across that divide is the strongest evidence the backend
-axis preserved semantics.
+"""Emission-target tests: every kernel x storage x lowering on the
+interpreted target against the ``kernels/ref.py`` oracles, plus the
+descriptor invariants, target resolution rules, and the host-table
+memoization the emission layer rides on.
 """
 import jax
 import jax.numpy as jnp
@@ -28,7 +19,7 @@ from repro.kernels.sierpinski_ca import ca_run
 from repro.kernels.sierpinski_write import sierpinski_sum, sierpinski_write
 
 RNG = np.random.default_rng(7)
-TARGETS = ("tpu-interpret", "gpu-interpret")
+TARGET = "tpu-interpret"
 
 
 # ---------------------------------------------------------------------------
@@ -36,71 +27,59 @@ TARGETS = ("tpu-interpret", "gpu-interpret")
 # ---------------------------------------------------------------------------
 
 def test_capability_descriptor_invariants():
+    assert set(B.TARGETS) == {"tpu", "tpu-interpret"}
     for t in B.TARGETS.values():
-        assert t.kind in ("tpu", "gpu")
-        # scalar prefetch, SMEM scalars, BlockSpec placement, grid
-        # sequencing and scratch are one coherent Mosaic feature set:
-        # they must flip together, or kernels would emit half-structures
-        tpu = t.kind == "tpu"
-        assert t.has_scalar_prefetch == tpu
-        assert t.smem_scalar_params == tpu
-        assert t.block_indexed == tpu
-        assert t.sequential_grid == tpu
-        assert t.supports_scratch == tpu
-        assert t.memory_space == ("vmem" if tpu else "hbm")
-        assert t.emulated().interpret
+        assert t.emulated() is B.TPU_INTERPRET
         assert t.emulated().emulated() is t.emulated()  # idempotent
-        assert not t.native().interpret
-        assert B.resolve(t) .kind == t.kind
-        assert B.TARGETS[t.native().name] is t.native()
+        assert t.native() is B.TPU and not t.native().interpret
+        assert B.resolve(t) is t
+        assert B.TARGETS[t.name] is t
+        assert t.pipeline_stages == 2
+        # the chip bounds the SMEM tables; the interpreter does not
+        assert (t.smem_table_bytes is None) == t.interpret
 
 
 def test_resolution_rules():
-    # platform default on CPU is the historical tpu-interpret path
+    # platform default on CPU is the interpreted target
     assert jax.default_backend() == "cpu"
     assert B.resolve(None) is B.TPU_INTERPRET
+    assert B.platform_default() is B.TPU_INTERPRET
     # a native target stays native off its platform: it fails to lower
     # on the CPU instead of being emulated unseen
     assert B.resolve("tpu") is B.TPU
-    assert B.resolve("gpu") is B.GPU
     assert B.resolve(B.TPU) is B.TPU
-    assert not B.resolve("gpu", interpret=False).interpret
+    assert B.resolve("tpu", interpret=False) is B.TPU
     # interpret=True forces emulation; aliases resolve
-    assert B.resolve("triton", interpret=True) is B.GPU_INTERPRET
     assert B.resolve("mosaic") is B.TPU
     assert B.resolve("tpu", interpret=True) is B.TPU_INTERPRET
-    assert B.resolve("interpret").interpret
-    with pytest.raises(ValueError):
-        B.resolve("cuda")
+    assert B.resolve("interpret") is B.TPU_INTERPRET
+    assert B.resolve(B.TPU_INTERPRET, interpret=False) is B.TPU
+    for gone in ("cuda", "gpu", "gpu-interpret", "triton"):
+        with pytest.raises(ValueError, match="unknown backend"):
+            B.resolve(gone)
     # process override (the serve/train --backend flag)
-    B.set_default("gpu-interpret")
+    B.set_default("tpu")
     try:
-        assert B.resolve(None) is B.GPU_INTERPRET
+        assert B.resolve(None) is B.TPU
+        assert B.platform_default() is B.TPU_INTERPRET
     finally:
         B.set_default(None)
     assert B.resolve(None) is B.TPU_INTERPRET
     with pytest.raises(ValueError):
-        B.set_default("not-a-backend")
-
-
-def test_scalar_and_scratch_capabilities():
-    s = B.TPU.scalar_spec()
-    from jax.experimental.pallas import tpu as pltpu
-    assert s.memory_space == pltpu.SMEM
-    g = B.GPU.scalar_spec()
-    assert g.block_shape == (1,)
-    B.TPU.scratch((8, 8), jnp.float32)  # exists
-    with pytest.raises(ValueError):
-        B.GPU.scratch((8, 8), jnp.float32)
+        B.set_default("gpu-interpret")
 
 
 def test_env_override(monkeypatch):
-    monkeypatch.setenv(B.BACKEND_ENV, "gpu-interpret")
-    assert B.resolve(None) is B.GPU_INTERPRET
+    # no environment variable steers the target: a leftover
+    # REPRO_BACKEND (once "gpu-interpret") is ignored, not an error
+    monkeypatch.setenv("REPRO_BACKEND", "gpu-interpret")
+    assert B.resolve(None) is B.TPU_INTERPRET
+    monkeypatch.setenv("REPRO_BACKEND", "tpu")
+    assert B.resolve(None) is B.TPU_INTERPRET
 
 
 # ---------------------------------------------------------------------------
-# bit-identity matrix: write / sum / CA x storage x lowering
+# oracle matrix: write / sum / CA x storage x lowering
 # ---------------------------------------------------------------------------
 
 def _fractal_operands(n, block, fractal="sierpinski-gasket"):
@@ -118,19 +97,15 @@ def test_write_and_sum_backend_parity(storage, lowering):
     n, block = 32, 8
     dom, lay, state = _fractal_operands(n, block)
     m = lay.pack(state, block) if storage == "compact" else state
-    kw = dict(block=block, grid_mode=lowering, storage=storage, n=n)
-    outs, sums = [], []
-    for t in TARGETS:
-        outs.append(np.asarray(sierpinski_write(m, 7.0, backend=t, **kw)))
-        sums.append(np.asarray(sierpinski_sum(m, backend=t, **kw)))
-    np.testing.assert_array_equal(outs[0], outs[1])
-    np.testing.assert_array_equal(sums[0], sums[1])
-    # and both match the reference oracle
-    emb = lay.unpack(jnp.asarray(outs[0]), block) \
-        if storage == "compact" else outs[0]
+    kw = dict(block=block, grid_mode=lowering, storage=storage, n=n,
+              backend=TARGET)
+    out = np.asarray(sierpinski_write(m, 7.0, **kw))
+    total = np.asarray(sierpinski_sum(m, **kw))
+    emb = lay.unpack(jnp.asarray(out), block) \
+        if storage == "compact" else out
     np.testing.assert_array_equal(
         np.asarray(emb), np.asarray(ref.sierpinski_write_ref(state, 7.0)))
-    np.testing.assert_allclose(sums[0], float(jnp.sum(state)), rtol=1e-6)
+    np.testing.assert_allclose(total, float(jnp.sum(state)), rtol=1e-6)
 
 
 @pytest.mark.parametrize("storage", ("embedded", "compact"))
@@ -144,16 +119,14 @@ def test_ca_backend_parity(storage, lowering):
     else:
         a, b = state, zero
     kw = dict(rule="parity", block=block, grid_mode=lowering,
-              storage=storage, n=n, fuse=2, donate=False)
-    outs = [np.asarray(ca_run(a, b, steps, backend=t, **kw))
-            for t in TARGETS]
-    np.testing.assert_array_equal(outs[0], outs[1])
+              storage=storage, n=n, fuse=2, donate=False, backend=TARGET)
+    out = np.asarray(ca_run(a, b, steps, **kw))
     # reference: unfused sequential oracle
     want = state
     for _ in range(steps):
         want = ref.ca_step_ref(want, rule="parity")
-    emb = lay.unpack(jnp.asarray(outs[0]), block) \
-        if storage == "compact" else outs[0]
+    emb = lay.unpack(jnp.asarray(out), block) \
+        if storage == "compact" else out
     np.testing.assert_array_equal(np.asarray(emb), np.asarray(want))
 
 
@@ -164,14 +137,18 @@ def test_ca_coarsen_backend_parity(lowering):
     a, b = lay.pack(state, block), lay.pack(
         jnp.zeros((n, n), jnp.float32), block)
     kw = dict(rule="diffusion", block=block, grid_mode=lowering,
-              storage="compact", n=n, fuse=2, coarsen=2, donate=False)
-    outs = [np.asarray(ca_run(a, b, 4, backend=t, **kw))
-            for t in TARGETS]
-    np.testing.assert_array_equal(outs[0], outs[1])
+              storage="compact", n=n, fuse=2, coarsen=2, donate=False,
+              backend=TARGET)
+    out = ca_run(a, b, 4, **kw)
+    want = state
+    for _ in range(4):
+        want = ref.ca_step_ref(want, rule="diffusion")
+    np.testing.assert_allclose(np.asarray(lay.unpack(out, block)),
+                               np.asarray(want), rtol=1e-5, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
-# bit-identity matrix: flash attention x kind x lowering (+ compact KV)
+# oracle matrix: flash attention x kind x lowering (+ compact KV)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("kind,window", (("causal", 0), ("local", 32),
@@ -183,12 +160,10 @@ def test_flash_backend_parity(kind, window, lowering):
     k = jnp.asarray(RNG.normal(size=(b, 2, s, d)), jnp.float32)
     v = jnp.asarray(RNG.normal(size=(b, 2, s, d)), jnp.float32)
     kw = dict(kind=kind, window=window, block_q=32, block_k=32,
-              grid_mode=lowering)
-    outs = [np.asarray(flash_attention(q, k, v, backend=t, **kw))
-            for t in TARGETS]
-    np.testing.assert_array_equal(outs[0], outs[1])
+              grid_mode=lowering, backend=TARGET)
+    out = np.asarray(flash_attention(q, k, v, **kw))
     want = ref.attention_ref(q, k, v, kind=kind, window=window)
-    np.testing.assert_allclose(outs[0], np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(out, np.asarray(want), atol=2e-5)
 
 
 @pytest.mark.parametrize("lowering", LOWERINGS)
@@ -200,10 +175,15 @@ def test_flash_compact_kv_backend_parity(lowering):
     dom = make_attention_domain("local", sq // bq, sk // bq, w // bq + 1)
     kp, vp = pack_kv(k, dom, bq), pack_kv(v, dom, bq)
     kw = dict(kind="local", window=w, block_q=bq, block_k=bq,
-              grid_mode=lowering, storage="compact", kv_seq_len=sk)
-    outs = [np.asarray(flash_attention(q, kp, vp, backend=t, **kw))
-            for t in TARGETS]
-    np.testing.assert_array_equal(outs[0], outs[1])
+              grid_mode=lowering, backend=TARGET)
+    packed = np.asarray(flash_attention(q, kp, vp, storage="compact",
+                                        kv_seq_len=sk, **kw))
+    # packing only drops key blocks no query reaches: bit-identical to
+    # the embedded KV, and both to the oracle
+    embedded = np.asarray(flash_attention(q, k, v, **kw))
+    np.testing.assert_array_equal(packed, embedded)
+    want = ref.attention_ref(q, k, v, kind="local", window=w)
+    np.testing.assert_allclose(packed, np.asarray(want), atol=2e-5)
 
 
 def test_flash_decode_seq_pos_parity():
@@ -213,18 +193,17 @@ def test_flash_decode_seq_pos_parity():
     k = jnp.asarray(RNG.normal(size=(2, 2, S, 16)), jnp.float32)
     v = jnp.asarray(RNG.normal(size=(2, 2, S, 16)), jnp.float32)
     for pos in (0, 21, S - 1):
-        outs = [np.asarray(flash_attention(
+        out = np.asarray(flash_attention(
             q, k, v, kind="full", block_q=1, block_k=16,
-            seq_pos=jnp.asarray(pos), backend=t)) for t in TARGETS]
-        np.testing.assert_array_equal(outs[0], outs[1])
+            seq_pos=jnp.asarray(pos), backend=TARGET))
         want = decode_attention(q, k, v, jnp.asarray(pos))
-        np.testing.assert_allclose(outs[0], np.asarray(want), atol=2e-6)
+        np.testing.assert_allclose(out, np.asarray(want), atol=2e-6)
 
 
 def test_seq_pos_requires_kind_full():
-    # a band row wholly beyond seq_pos has an empty k-extent: neither
-    # structure can produce a defined result, so the combination is
-    # rejected (decode rides kind="full" + window=)
+    # a band row wholly beyond seq_pos has an empty k-extent: no step
+    # initializes its output, so the combination is rejected (decode
+    # rides kind="full" + window=)
     q = jnp.zeros((1, 1, 64, 8), jnp.float32)
     for kind in ("causal", "local"):
         with pytest.raises(ValueError, match="seq_pos"):
@@ -244,12 +223,11 @@ def test_decode_attention_flash_windowed():
         for pos in (5, 40, S - 1):
             want = decode_attention(q, k, v, jnp.asarray(pos), kind=kind,
                                     window=w)
-            for t in TARGETS:
-                got = decode_attention_flash(
-                    q, k, v, jnp.asarray(pos), kind=kind, window=w,
-                    block_k=16, backend=t)
-                np.testing.assert_allclose(np.asarray(got),
-                                           np.asarray(want), atol=2e-6)
+            got = decode_attention_flash(
+                q, k, v, jnp.asarray(pos), kind=kind, window=w,
+                block_k=16, backend=TARGET)
+            np.testing.assert_allclose(np.asarray(got),
+                                       np.asarray(want), atol=2e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -258,20 +236,19 @@ def test_decode_attention_flash_windowed():
 
 def test_gridplan_carries_target():
     dom = make_fractal_domain("sierpinski-gasket", 4)
-    p_default = GridPlan(dom)
-    assert p_default.target is B.resolve(None)
-    p_gpu = GridPlan(dom, backend="gpu-interpret")
-    assert p_gpu.target is B.GPU_INTERPRET
-    assert not p_gpu.target.block_indexed
-    # the emitter refuses scratch on gpu structures
-    with pytest.raises(ValueError):
-        p_gpu.pallas_call(
-            lambda coords, o_ref: None, in_specs=[],
-            out_specs=B.full_spec((4, 4)),
-            out_shape=jax.ShapeDtypeStruct((4, 4), jnp.float32),
-            scratch_shapes=[B.TPU.scratch((4, 4), jnp.float32)])(
-
-        )
+    assert GridPlan(dom).target is B.resolve(None)
+    p_tpu = GridPlan(dom, "prefetch_lut", backend="tpu")
+    assert p_tpu.target is B.TPU
+    assert GridPlan(dom, backend=B.TPU_INTERPRET).target is B.TPU_INTERPRET
+    # the native target's emitter checks its SMEM budget when traced;
+    # the interpreted one has none
+    assert p_tpu.target.smem_table_bytes == B.SMEM_TABLE_BYTES
+    # every table-backed lowering binds its table, on every target
+    for lowering in ("prefetch_lut", "mma"):
+        for backend in B.TARGETS:
+            plan = GridPlan(dom, lowering, backend=backend)
+            assert plan.num_scalar_prefetch == 1
+            assert len(plan.bound_prefetch()) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -326,9 +303,8 @@ def _mesh_or_skip(D=2):
 
 @pytest.mark.parametrize("storage", ("embedded", "compact"))
 def test_sharded_ca_backend_parity(storage):
-    """The gpu structure on a mesh (slab halo exchange / psum combine)
-    must stay bit-identical to the tpu structure and to the unsharded
-    run."""
+    """A run on a mesh (slab halo exchange / psum combine) must stay
+    bit-identical to the unsharded run."""
     mesh = _mesh_or_skip(2)
     n, block = 32, 8
     dom, lay, state = _fractal_operands(n, block)
@@ -340,12 +316,11 @@ def test_sharded_ca_backend_parity(storage):
     base = np.asarray(ca_run(state, zero, 4, rule="parity", block=block,
                              grid_mode="closed_form", fuse=2,
                              donate=False, backend="tpu-interpret"))
-    for t in TARGETS:
-        got = ca_run(a, b, 4, rule="parity", block=block,
-                     grid_mode="closed_form", storage=storage, n=n,
-                     fuse=2, donate=False, backend=t, mesh=mesh)
-        emb = lay.unpack(got, block) if storage == "compact" else got
-        np.testing.assert_array_equal(np.asarray(emb), base)
+    got = ca_run(a, b, 4, rule="parity", block=block,
+                 grid_mode="closed_form", storage=storage, n=n, fuse=2,
+                 donate=False, backend=TARGET, mesh=mesh)
+    emb = lay.unpack(got, block) if storage == "compact" else got
+    np.testing.assert_array_equal(np.asarray(emb), base)
 
 
 def test_sharded_flash_backend_parity():
@@ -358,11 +333,10 @@ def test_sharded_flash_backend_parity():
                                       block_q=32, block_k=32,
                                       backend="tpu-interpret"))
     for lowering in LOWERINGS:
-        for t in TARGETS:
-            got = flash_attention(q, k, v, kind="causal", block_q=32,
-                                  block_k=32, grid_mode=lowering,
-                                  backend=t, mesh=mesh)
-            np.testing.assert_array_equal(np.asarray(got), base)
+        got = flash_attention(q, k, v, kind="causal", block_q=32,
+                              block_k=32, grid_mode=lowering,
+                              backend=TARGET, mesh=mesh)
+        np.testing.assert_array_equal(np.asarray(got), base)
 
 
 def test_memo_stats_count_hits():
@@ -404,6 +378,5 @@ def test_prefetch_table_over_smem_is_refused_at_plan_time():
                 backend="tpu")).trace(m)
     assert B.TPU.smem_table_bytes == B.SMEM_TABLE_BYTES
     assert B.TPU_INTERPRET.smem_table_bytes is None
-    assert B.GPU.smem_table_bytes is None
     # the embedded table of the same domain (2 columns) rides flat
     B.check_smem_tables([(19683, 2)], limit=B.TPU.smem_table_bytes)

@@ -1,7 +1,7 @@
 """The ``mma`` lowering's digit-basis matmul decode chains
 (:mod:`repro.core.mma`): mixed-precision exactness against the integer
 closed forms, the asserted f32-accumulation bound, and kernel-level
-parity on both interpret targets."""
+parity with the closed form."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -80,27 +80,50 @@ def test_bound_is_asserted():
 
 
 # ---------------------------------------------------------------------------
-# kernel-level parity on both interpret targets (the TPU structure
-# consumes the mma table, the GPU structure runs the chains in-kernel)
+# kernel-level parity: the kernels consume the chain-built table through
+# the same scalar-prefetch path as the host LUT, bit for bit
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["tpu-interpret", "gpu-interpret"])
+def _gasket_operand(n, block, storage, fill=0.0):
+    from repro.core.compact import CompactLayout
+    from repro.core.domain import make_fractal_domain
+    m = jnp.asarray(np.where(F.membership_grid(n), fill, 0.0), jnp.float32)
+    if storage == "compact":
+        lay = CompactLayout(make_fractal_domain("sierpinski-gasket",
+                                                n // block))
+        return lay.pack(m, block), dict(storage="compact", n=n)
+    return m, {}
+
+
+@pytest.mark.parametrize("backend", ["tpu-interpret"])
 @pytest.mark.parametrize("storage", ["embedded", "compact"])
 def test_write_mma_matches_closed_form_both_targets(backend, storage):
     from repro.kernels import ops
     n, block = 64, 8
-    if storage == "compact":
-        from repro.core.compact import CompactLayout
-        from repro.core.domain import make_fractal_domain
-        lay = CompactLayout(make_fractal_domain("sierpinski-gasket",
-                                                n // block))
-        m = jnp.zeros(lay.array_shape(block), jnp.float32)
-        kw = dict(storage="compact", n=n)
-    else:
-        m = jnp.zeros((n, n), jnp.float32)
-        kw = {}
+    m, kw = _gasket_operand(n, block, storage)
     outs = [ops.sierpinski_write(m, 7.0, block=block, grid_mode=gm,
                                  backend=backend, **kw)
             for gm in ("closed_form", "mma")]
     np.testing.assert_array_equal(np.asarray(outs[0]),
                                   np.asarray(outs[1]))
+
+
+@pytest.mark.parametrize("kernel", ["sum", "ca"])
+def test_sum_and_ca_mma_match_closed_form(kernel):
+    from repro.kernels import ops
+    n, block = 64, 8
+    rng = np.random.default_rng(11)
+    m, kw = _gasket_operand(n, block, "compact", fill=1.0)
+    m = m * jnp.asarray(rng.integers(0, 2, m.shape), jnp.float32)
+
+    def run(gm):
+        if kernel == "sum":
+            return ops.sierpinski_sum(m, block=block, grid_mode=gm,
+                                      backend="tpu-interpret", **kw)
+        return ops.ca_run(m, jnp.zeros_like(m), 6, fuse=3, rule="parity",
+                          block=block, grid_mode=gm, donate=False,
+                          backend="tpu-interpret", **kw)
+
+    want, got = np.asarray(run("closed_form")), np.asarray(run("mma"))
+    assert want.any()          # a non-trivial state
+    np.testing.assert_array_equal(got, want)

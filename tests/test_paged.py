@@ -135,7 +135,18 @@ _MHA_D128 = (3, 4, 4, 64, 128)
 _GQA_D128 = (3, 4, 2, 64, 128)
 
 
-@pytest.mark.parametrize("backend", ["tpu-interpret", "gpu-interpret"])
+#: the dead-page axis of the paged decode cases: the table as built
+#: (entries past a slot's last page name pages of other slots), or every
+#: entry outside a slot's live pages pointed at a page of NaNs
+#: (:func:`_nan_dead_pages`), which a step past the slot's end must never
+#: read: it repeats the last live block index instead
+_DEAD_PAGES = [
+    pytest.param(None, id="tpu-interpret"),
+    pytest.param("nan", id="tpu-interpret-nan-dead-pages"),
+]
+
+
+@pytest.mark.parametrize("dead", _DEAD_PAGES)
 @pytest.mark.parametrize("gm", ["closed_form", "prefetch_lut",
                                 "bounding", "mma"])
 @pytest.mark.parametrize("ps,shape", [
@@ -144,21 +155,25 @@ _GQA_D128 = (3, 4, 2, 64, 128)
     pytest.param(16, _MHA_D128, id="16-mha-d128"),
     pytest.param(16, _GQA_D128, id="16-gqa-d128"),
 ])
-def test_paged_decode_bit_identical_to_contiguous(backend, gm, ps, shape):
+def test_paged_decode_bit_identical_to_contiguous(dead, gm, ps, shape):
     b, h, hkv, smax, d = shape
     q, k, v, pool, table, pos = _paged_case(
         b, h, hkv, smax, d, ps, lens=[37, 63, 9])
     # bitwise oracle: the contiguous flash decode at the same block
     # granularity (same online-softmax accumulation order)
     want = A.decode_attention_flash(q, k, v, pos, block_k=ps,
-                                    backend=backend)
-    got = A.decode_attention_paged(q, pool, table, pos, grid_mode=gm,
-                                   backend=backend, verify=True)
-    assert np.array_equal(np.asarray(got), np.asarray(want)), (backend, gm)
+                                    backend="tpu-interpret")
     # the XLA gather rung reproduces the plain softmax path bitwise
     xla = A.decode_attention_paged_xla(q, pool, table, pos)
     assert np.array_equal(np.asarray(xla),
                           np.asarray(A.decode_attention(q, k, v, pos)))
+    if dead:
+        pool, table, _, _ = _nan_dead_pages(pool, table, pos, ps,
+                                            window=0)
+    got = A.decode_attention_paged(q, pool, table, pos, grid_mode=gm,
+                                   backend="tpu-interpret", verify=True)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.array_equal(np.asarray(got), np.asarray(want)), (dead, gm)
     np.testing.assert_allclose(np.asarray(got), np.asarray(xla),
                                rtol=2e-5, atol=2e-5)
 
@@ -210,36 +225,37 @@ def _nan_dead_pages(pool, table, pos, ps, window):
     return pool, jnp.asarray(table), nan_page, live
 
 
-@pytest.mark.parametrize("backend,dead", [
-    pytest.param("tpu-interpret", None, id="tpu-interpret"),
-    pytest.param("gpu-interpret", None, id="gpu-interpret"),
-    pytest.param("tpu-interpret", "nan", id="tpu-interpret-nan-dead-pages"),
-    pytest.param("gpu-interpret", "nan", id="gpu-interpret-nan-dead-pages"),
-])
-def test_paged_decode_local_window(backend, dead):
+@pytest.mark.parametrize("dead", _DEAD_PAGES)
+@pytest.mark.parametrize("gm", ["closed_form", "prefetch_lut",
+                                "bounding", "mma"])
+def test_paged_decode_local_window(gm, dead):
     b, h, hkv, smax, d, ps = 2, 2, 2, 64, 16, 8
     q, k, v, pool, table, pos = _paged_case(
         b, h, hkv, smax, d, ps, lens=[50, 23])
     want = A.decode_attention_flash(q, k, v, pos, kind="local",
                                     window=16, block_k=ps,
-                                    backend=backend)
+                                    backend="tpu-interpret")
     if dead:
         pool, table, nan_page, live = _nan_dead_pages(
             pool, table, pos, ps, window=16)
     got, records = _emitted(lambda: A.decode_attention_paged(
-        q, pool, table, pos, window=16, backend=backend))
+        q, pool, table, pos, window=16, grid_mode=gm,
+        backend="tpu-interpret"))
     assert np.isfinite(np.asarray(got)).all()
     assert np.array_equal(np.asarray(got), np.asarray(want))
-    if dead and backend == "tpu-interpret":
+    if dead:
         # the KV operand's index map, evaluated at every grid step,
         # names only the slot's live pages: a dead step repeats a live
         # block index, so the NaN page is never copied in
         rec, = records
         plan, kv_spec = rec.plan, rec.in_specs[1]
         refs = (np.asarray(table), np.asarray(pos))
-        for slot, t in np.ndindex(*plan.grid):
-            page = int(kv_spec.index_map(slot, t, *refs)[0])
-            assert page != nan_page and page in live[slot], (slot, t)
+        if plan._table_backed:   # the decode table rides last
+            refs += (plan.mma_table_host() if gm == "mma"
+                     else plan.lut_host(),)
+        for ids in np.ndindex(*plan.grid):
+            page = int(kv_spec.index_map(*ids, *refs)[0])
+            assert page != nan_page and page in live[ids[0]], ids
 
 
 @pytest.mark.parametrize("gm", ["closed_form", "prefetch_lut",

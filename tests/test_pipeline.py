@@ -2,18 +2,16 @@
 
 The software pipeline must be a pure scheduling change: every kernel x
 storage x lowering x fuse x stages point returns the same bits as the
-synchronous path on both interpret structures, and the sharded overlap
+synchronous path under the interpreter, and the sharded overlap
 (interior compute concurrent with the halo exchange, boundary steps
 after) must propagate a slab-crossing impulse identically.  Covered:
 
-  * backend capability plumbing: ``async_copy`` / ``pipeline_stages``
-    flags and the ``resolve_stages`` clamp;
+  * backend plumbing: the ``pipeline_stages`` depth and the
+    ``resolve_stages`` clamp;
   * the first-iteration LUT-stall fix: ``_lut_row0`` is a host constant
     equal to LUT row 0 on single-device plans, and None on sharded
     plans (per-device chunks are shard_map operands);
-  * write/sum DMA streaming and ca fused DMA bit-identity matrices on
-    the TPU structure; knob passthrough on the GPU structure;
-  * flash attention's KV FIFO (gpu structure) at stages 2..4;
+  * write/sum DMA streaming and ca fused DMA bit-identity matrices;
   * host geometry of the overlap machinery: interior/boundary phase
     tables partition each device's owned steps, strip halo rounds never
     mix with full-row rounds for the same ghost row, and the trimmed
@@ -82,15 +80,11 @@ def _packed(n, block, a=None):
 
 def test_backend_stage_capabilities_and_clamp():
     from repro.core import backend
-    tpu, gpu = backend.TARGETS["tpu"], backend.TARGETS["gpu"]
-    ti = backend.TARGETS["tpu-interpret"]
-    gi = backend.TARGETS["gpu-interpret"]
-    # in-kernel DMA is a TPU-structure capability; the GPU structure
-    # pipelines through the compiler knob instead
-    assert tpu.async_copy and ti.async_copy
-    assert not gpu.async_copy and not gi.async_copy
-    for t in (tpu, gpu, ti, gi):
-        assert t.pipeline_stages >= 2
+    # the interpreter emulates the chip's DMA double buffer, so both
+    # targets stage the same depth
+    for t in backend.TARGETS.values():
+        assert t.pipeline_stages == 2
+        assert t.resolve_stages("auto") == 1
         assert t.resolve_stages(None) == 1      # "auto" -> synchronous
         assert t.resolve_stages(1) == 1
         assert t.resolve_stages(2) == 2
@@ -259,54 +253,6 @@ def test_ca_pipelined_bit_identical(storage):
                     assert ref.any()  # the matrix point is non-trivial
                 else:
                     assert np.array_equal(ref, out), (gm, fuse, stages)
-
-
-def test_gpu_structure_accepts_stage_knob():
-    # On the GPU structure num_stages maps to the compiler knob (a
-    # no-op under interpret) -- results must not change and nothing
-    # may reject the parameter.
-    import jax.numpy as jnp
-    from repro.kernels import ops
-    from repro.kernels.sierpinski_write import sierpinski_write
-    n, block = 32, 8
-    a = _packed(n, block, _state(n))
-    b = jnp.zeros_like(a)
-    outs = [np.asarray(ops.ca_run(a, b, 4, fuse=2, rule="parity",
-                                  block=block, grid_mode="prefetch_lut",
-                                  storage="compact", n=n, num_stages=s,
-                                  backend="gpu-interpret", donate=False))
-            for s in (1, 4)]
-    assert np.array_equal(outs[0], outs[1])
-    ws = [np.asarray(sierpinski_write(_packed(n, block), value=2.0,
-                                      block=block, grid_mode="closed_form",
-                                      storage="compact", n=n,
-                                      num_stages=s,
-                                      backend="gpu-interpret"))
-          for s in (1, 2)]
-    assert np.array_equal(ws[0], ws[1])
-
-
-@pytest.mark.parametrize("kind,window", [("causal", 0), ("local", 64)])
-def test_flash_kv_fifo_bit_identical(kind, window):
-    import jax.numpy as jnp
-    from repro.kernels.flash_attention import flash_attention
-    sq, d, heads, block = 256, 32, 2, 64
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(size=(1, heads, sq, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(1, heads, sq, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(1, heads, sq, d)), jnp.float32)
-
-    def run(stages, backend):
-        return np.asarray(flash_attention(
-            q, k, v, kind=kind, window=window, block_q=block,
-            block_k=block, num_stages=stages, backend=backend))
-
-    ref = run(1, "gpu-interpret")
-    for stages in (2, 3, 4):
-        assert np.array_equal(ref, run(stages, "gpu-interpret")), stages
-    # the TPU structure has no KV FIFO; the knob must still be accepted
-    tref = run(1, "tpu-interpret")
-    assert np.array_equal(tref, run(2, "tpu-interpret"))
 
 
 # ---------------------------------------------------------------------------

@@ -73,15 +73,10 @@ def test_grid_shapes_per_lowering(dom):
                            ("compact", (dom.num_blocks,))):
         plan = GridPlan(dom, lowering, batch_dims=(3,))
         assert plan.grid == (3,) + want
-        # prefetch_lut always binds its table; mma does only on
-        # block-indexed structures (the gpu structure chains in-kernel)
+        # prefetch_lut binds its host table, mma its chain-built one
         assert plan.num_scalar_prefetch == int(plan._table_backed)
-        if lowering == "prefetch_lut":
-            assert plan._table_backed
-        elif lowering == "mma":
-            assert plan._table_backed == plan.target.block_indexed
-        else:
-            assert not plan._table_backed
+        assert plan._table_backed == (lowering in ("prefetch_lut",
+                                                   "mma"))
 
 
 @pytest.mark.parametrize("dom", _all_domains())

@@ -93,7 +93,7 @@ def test_causal_flash_attention_compiles_for_v5e(one_chip):
     txt = _compile_text(
         lambda q, k, v: ops.flash_attention(
             q, k, v, kind="causal", block_q=128, block_k=128,
-            grid_mode="closed_form", num_stages=1, backend="tpu"),
+            grid_mode="closed_form", backend="tpu"),
         qkv, qkv, qkv)
     assert "tpu_custom_call" in txt
 

@@ -190,7 +190,7 @@ KERNELS = {
     "flash_attention": lambda ops: (
         lambda q, k, v: ops.flash_attention(
             q, k, v, kind="causal", block_q=128, block_k=128,
-            grid_mode="closed_form", num_stages=1, backend="tpu"),
+            grid_mode="closed_form", backend="tpu"),
         [((1, 2, 256, 128), jnp.bfloat16)] * 3),
 }
 
