@@ -259,21 +259,36 @@ def write_prefill_pages(pool, pages, k, v):
 #
 # A latent-attention layer caches one row per token: the compressed kv
 # latent and the shared roped key side by side, ``kv_lora_rank +
-# qk_rope_dim`` wide.  Its pool is ``(num_pages, page_size, width)``,
-# one ``(page_size, width)`` tile per page, addressed by the same page
-# table as the fused-KV pools.
+# qk_rope_dim`` wide.  Its pool is ``(num_pages, page_size, lanes)``,
+# one ``(page_size, lanes)`` tile per page, addressed by the same page
+# table as the fused-KV pools.  ``lanes`` is the row width rounded up to
+# the chip's 128 lanes, zero past the row: a page is then a whole number
+# of the chip's tiles, which the latent kernel's page copies need
+# (Mosaic slices HBM only at tile bounds), and the pool keeps that
+# layout across the decode step instead of being copied into it around
+# every kernel call.
+
+#: the chip's vector lanes: latent pool rows are padded to a multiple
+LANES = 128
+
+
+def latent_lanes(width: int) -> int:
+    """Lanes a latent pool stores a ``width``-wide row in."""
+    return -(-int(width) // LANES) * LANES
+
 
 def init_latent_pool(num_pages: int, page_size: int, width: int,
                      dtype=jnp.float32):
-    """Zeroed latent pool ``(num_pages, page_size, width)``."""
-    return jnp.zeros((num_pages, page_size, width), dtype)
+    """Zeroed latent pool ``(num_pages, page_size, latent_lanes(width))``
+    for rows ``width`` wide."""
+    return jnp.zeros((num_pages, page_size, latent_lanes(width)), dtype)
 
 
 def gather_latent(pool, page_table):
     """Contiguous latent rows from the pool (pure XLA gather): pool (P,
-    ps, w), page_table (B, m) -> (B, m*ps, w).  Rows past each slot's
-    position are whatever their page holds and are masked by every
-    consumer.  The oracle of the latent kernel's tests and the
+    ps, lanes), page_table (B, m) -> (B, m*ps, lanes).  Rows past each
+    slot's position are whatever their page holds and are masked by
+    every consumer.  The oracle of the latent kernel's tests and the
     degradation ladder's ``xla`` rung for latent pools."""
     b, m = page_table.shape
     _, ps, w = pool.shape
@@ -283,28 +298,29 @@ def gather_latent(pool, page_table):
 def append_latent(pool, page_table, pos, rows, active=None):
     """Scatter one new latent row per slot into its current page.
 
-    rows: (B, w); pos: (B,) the token's position; inactive slots write
-    to the null page.  Returns the updated pool."""
-    b = pos.shape[0]
+    rows: (B, w), ``w`` at most the pool's lanes, written to the first
+    ``w``; pos: (B,) the token's position; inactive slots write to the
+    null page.  Returns the updated pool."""
+    b, w = rows.shape
     ps = pool.shape[1]
     pages = page_table[jnp.arange(b), pos // ps]
     if active is not None:
         pages = jnp.where(active, pages, NULL_PAGE)
-    return pool.at[pages, pos % ps, :].set(rows.astype(pool.dtype),
-                                          mode="drop")
+    return pool.at[pages, pos % ps, :w].set(rows.astype(pool.dtype),
+                                            mode="drop")
 
 
 def write_latent_pages(pool, pages, rows):
     """Write one request's contiguous prefill latents (S, w) into its
-    pages (n,), ``S <= n*ps``; the last page's tail is zero padding.
-    Returns the updated pool."""
+    pages (n,), ``S <= n*ps``; the last page's tail and the lanes past
+    ``w`` are zero padding.  Returns the updated pool."""
     n = pages.shape[0]
     s, w = rows.shape
-    ps = pool.shape[1]
-    if n * ps - s:
-        rows = jnp.pad(rows, ((0, n * ps - s), (0, 0)))
-    return pool.at[pages].set(rows.reshape(n, ps, w).astype(pool.dtype),
-                              mode="drop")
+    _, ps, lanes = pool.shape
+    if n * ps - s or lanes - w:
+        rows = jnp.pad(rows, ((0, n * ps - s), (0, lanes - w)))
+    return pool.at[pages].set(
+        rows.reshape(n, ps, lanes).astype(pool.dtype), mode="drop")
 
 
 # ---------------------------------------------------------------------------

@@ -452,16 +452,23 @@ class PagedServer:
         self.decode_grid_steps = self._grid_steps(cfg)
 
     def _grid_steps(self, cfg: ModelConfig) -> int:
-        """Grid steps the block-indexed paged decode kernel runs in one
-        decode step under ``cfg``: its grid times the fused-KV attention
-        layers (0 where no layer runs it)."""
+        """Grid steps the block-indexed paged decode kernels run in one
+        decode step under ``cfg``: the fused-KV kernel's grid times the
+        attention layers plus the latent kernel's times the latent (MLA)
+        layers (0 on the ``xla`` rung, where no layer runs either)."""
         if cfg.attn_decode_kernel != "blockspace":
             return 0
+        from repro.core.paged import latent_lanes
         from repro.kernels.flash_attention import paged_decode_grid_steps
-        layers = sum(model_lib.layer_sig(cfg, i)[0] == "attn"
-                     for i in range(cfg.n_layers))
-        return paged_decode_grid_steps(self.scfg.num_slots, self.max_pages,
-                                       layers)
+        from repro.kernels.latent_decode import latent_decode_grid_steps
+        mixers = [model_lib.layer_sig(cfg, i)[0] for i in range(cfg.n_layers)]
+        slots, pages = self.scfg.num_slots, self.max_pages
+        return (paged_decode_grid_steps(slots, pages, mixers.count("attn"))
+                + latent_decode_grid_steps(
+                    slots, pages, mixers.count("mla"),
+                    page_size=self.scfg.page_size,
+                    width=latent_lanes(cfg.latent_width),
+                    itemsize=jnp.dtype(cfg.jdtype()).itemsize))
 
     # -- host bookkeeping ----------------------------------------------------
 

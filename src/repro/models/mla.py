@@ -138,7 +138,8 @@ def _absorbed_query(p, x, cfg, pos):
 def mla_decode_paged(p, x, cfg, pool, page_table, pos, active=None):
     """Absorbed decode of one token per slot against a paged latent pool
     (:func:`repro.core.paged.init_latent_pool`), every slot at its own
-    position.  x: (B,1,D); pool: (P, page_size, L+dr); page_table: (B,
+    position.  x: (B,1,D); pool: (P, page_size, lanes), rows L+dr wide
+    in the first lanes; page_table: (B,
     max_pages); pos: (B,); inactive slots write to the null page.  The
     attention runs in the ``paged_latent_decode`` kernel, or over the
     gathered latents in XLA when ``cfg.attn_decode_kernel == "xla"``.

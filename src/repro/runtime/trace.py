@@ -13,9 +13,10 @@ Spans, parents first, and what their counters mean:
 ``serve.step`` -- one :meth:`PagedServer.step <repro.launch.serve.PagedServer.step>`
     ``step``: steps this server has run before this one; ``active``:
     slots decoding when the step begins; ``decode_grid_steps``: grid
-    steps the block-indexed paged decode kernel runs in the decode step
-    dispatched (its grid times the fused-KV attention layers; 0 where
-    no layer runs it); at its end ``preempted``:
+    steps the block-indexed paged decode kernels run in the decode step
+    dispatched (the fused-KV kernel's grid times the attention layers
+    plus the latent kernel's times the latent layers; 0 on the ``xla``
+    rung); at its end ``preempted``:
     requests preempted to make room; ``pages_in_use``, ``free_pages``,
     ``live_tokens``, ``alloc_tokens``: the pool after the step; under a
     held-experts share (``ModelConfig.experts_held``) also

@@ -7,7 +7,9 @@ Covered:
     prefill write and gather;
   * the paged latent decode kernel (interpret mode) against the gather
     oracle and against the contiguous absorbed ``mla_decode``, at
-    several positions, page sizes and shuffled page layouts;
+    several positions, page sizes and shuffled page layouts; many pages
+    a grid step, the last chunk partial; NaN rows it must not read;
+    its grid-step count against the grid it launches;
   * the held-experts layer: the 8 shares of a 64-expert layer, the
     shared expert counted once, add up to the uncut oracle; no token is
     dropped when every token routes to one expert; gates are not
@@ -16,8 +18,9 @@ Covered:
   * YaRN's frequencies and attention factor against the published
     formula;
   * ``PagedServer`` over a latent stack: its ``xla`` rung serves through
-    the gather fallback, token for token as the kernel; SSM and shared
-    blocks are still refused, by name.
+    the gather fallback, token for token as the kernel, and its
+    ``decode_grid_steps`` counts the latent kernel's grid; SSM and
+    shared blocks are still refused, by name.
 """
 import math
 
@@ -52,17 +55,20 @@ def test_latent_write_append_gather_roundtrip():
     pool = P.write_latent_pages(pool, pages, rows)
     table = jnp.asarray([[6, 2, 0], [0, 0, 0]], jnp.int32)
     got = P.gather_latent(pool, table)
-    assert got.shape == (2, 12, w)
-    np.testing.assert_array_equal(got[0, :7], rows)
+    # rows are stored in whole 128-lane tiles, zero past the row
+    assert got.shape == (2, 12, P.latent_lanes(w)) == (2, 12, 128)
+    np.testing.assert_array_equal(got[0, :7, :w], rows)
+    np.testing.assert_array_equal(got[..., w:], 0)
     np.testing.assert_array_equal(got[0, 7:8], 0)     # the tail's padding
     new = _rand(2, w)
     pool = P.append_latent(pool, table, jnp.asarray([7, 3], jnp.int32), new,
                            jnp.asarray([True, False]))
     got = P.gather_latent(pool, table)
-    np.testing.assert_array_equal(got[0, :7], rows)
-    np.testing.assert_array_equal(got[0, 7], new[0])
+    np.testing.assert_array_equal(got[0, :7, :w], rows)
+    np.testing.assert_array_equal(got[0, 7, :w], new[0])
+    np.testing.assert_array_equal(pool[..., w:], 0)
     # the inactive slot wrote to the null page only
-    np.testing.assert_array_equal(pool[0, 3], new[1])
+    np.testing.assert_array_equal(pool[0, 3, :w], new[1])
     assert float(jnp.abs(pool[1:]).sum() - jnp.abs(rows).sum()
                  - jnp.abs(new[0]).sum()) == pytest.approx(0, abs=1e-3)
 
@@ -91,6 +97,112 @@ def test_latent_kernel_matches_gather_oracle(ps, shuffle):
         got = paged_latent_decode(q, pool, table, pos, scale=0.3, v_dim=L)
         want = latent_decode_xla(q, pool, table, pos, scale=0.3, v_dim=L)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("ps,m,shuffle", [(4, 300, False), (8, 150, True),
+                                          (16, 70, True)])
+def test_latent_kernel_takes_chunks_of_pages(ps, m, shuffle):
+    """Many pages a grid step, by the shape-derived rule (512 keys a
+    chunk: 128, 64 and 32 pages here), the last chunk partial: positions
+    at a chunk's last key, its next chunk's first, 0 (every chunk after
+    the first dead) and the table's last key, against the gather
+    oracle."""
+    from repro.kernels.latent_decode import latent_decode_grid
+    b, h, L, dr = 4, 4, 16, 8
+    pool, table = _paged_layout(b, m, ps, L + dr, shuffle)
+    _, domain, pages = latent_decode_grid(b, m, ps, L + dr, 4)
+    assert pages * ps == 512 and pages < m and m % pages
+    assert domain.num_blocks == -(-m // pages) == 3
+    q = _rand(b, h, L + dr)
+    for pos in ([pages * ps - 1, pages * ps, 0, m * ps - 1],
+                [m * ps - 1, 2 * pages * ps, 5, pages * ps + 1]):
+        pos = jnp.asarray(pos, jnp.int32)
+        got = paged_latent_decode(q, pool, table, pos, scale=0.3, v_dim=L)
+        want = latent_decode_xla(q, pool, table, pos, scale=0.3, v_dim=L)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_latent_kernel_never_shows_rows_past_a_slots_position():
+    """Every pool page outside each slot's live pages, and each last
+    live page's rows past the slot's position, hold NaN: the kernel
+    neither copies nor weighs them, so its output is finite and equals
+    the oracle's over the same pool with those rows zeroed."""
+    b, h, L, dr, ps, m = 4, 4, 16, 8, 8, 150
+    pool, table = _paged_layout(b, m, ps, L + dr, shuffle=True)
+    q = _rand(b, h, L + dr)
+    pos = np.array([64 * ps - 1, 64 * ps, 0, 2 * 64 * ps + 3])
+    dead = np.ones((pool.shape[0], ps), bool)
+    for s, p in enumerate(pos):
+        pages = np.asarray(table[s, :p // ps + 1])
+        dead[pages] = False
+        dead[pages[-1], p % ps + 1:] = True
+    clean = jnp.where(dead[..., None], 0.0, pool)
+    poisoned = jnp.where(dead[..., None], jnp.nan, pool)
+    pos = jnp.asarray(pos, jnp.int32)
+    got = paged_latent_decode(q, poisoned, table, pos, scale=0.3, v_dim=L)
+    assert np.isfinite(np.asarray(got)).all()
+    want = latent_decode_xla(q, clean, table, pos, scale=0.3, v_dim=L)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_latent_kernel_reads_lane_padded_pools():
+    """A pool from ``init_latent_pool`` stores 24-wide rows in 128
+    lanes: the kernel and the oracle read the rows' own lanes."""
+    b, h, L, dr, ps, m = 2, 4, 16, 8, 4, 6
+    pool, table = _paged_layout(b, m, ps, L + dr, shuffle=True)
+    wide = P.init_latent_pool(pool.shape[0], ps, L + dr)
+    wide = wide.at[..., :L + dr].set(pool)
+    q = _rand(b, h, L + dr)
+    pos = jnp.asarray([5, m * ps - 1], jnp.int32)
+    want = latent_decode_xla(q, pool, table, pos, scale=0.3, v_dim=L)
+    for fn in (paged_latent_decode, latent_decode_xla):
+        got = fn(q, wide, table, pos, scale=0.3, v_dim=L)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def _latent_launches(fn):
+    """Run ``fn`` with the latent kernel re-traced; the emit records."""
+    from repro.core import backend as backend_lib
+    from repro.kernels import latent_decode
+
+    class Log:
+        records = []
+
+        def instrument(self, record, kernel, in_specs, out_specs):
+            self.records.append(record)
+            return kernel, in_specs, out_specs
+
+        def wrap_call(self, record, fn):
+            return fn
+
+    latent_decode._latent_decode_impl.clear_cache()
+    prev = backend_lib.set_emit_hook(Log())
+    try:
+        out = fn()
+    finally:
+        backend_lib.set_emit_hook(prev)
+    return out, Log.records
+
+
+@pytest.mark.parametrize("gm", ["closed_form", "prefetch_lut", "bounding",
+                                "mma"])
+def test_latent_decode_grid_steps_is_the_launched_grid(gm):
+    from repro.kernels.latent_decode import latent_decode_grid_steps
+    # the dsv2lite cell: 16 slots of 384 pages of 16 tokens, rows in 640
+    # lanes of bf16, 27 latent layers: 12 chunks of 32 pages a slot
+    cell = latent_decode_grid_steps(16, 384, 27, page_size=16, width=640,
+                                    itemsize=2)
+    assert cell == 16 * 12 * 27 == 5_184 <= 165_888 // 16
+    b, h, L, dr, ps, m = 3, 4, 16, 8, 16, 70
+    pool, table = _paged_layout(b, m, ps, L + dr, shuffle=True)
+    q = _rand(b, h, L + dr)
+    pos = jnp.asarray([0, 32 * ps, m * ps - 1], jnp.int32)
+    got, (rec,) = _latent_launches(lambda: paged_latent_decode(
+        q, pool, table, pos, scale=0.3, v_dim=L, grid_mode=gm))
+    assert rec.plan.num_steps == latent_decode_grid_steps(
+        b, m, page_size=ps, width=L + dr, itemsize=4) == b * 3
+    want = latent_decode_xla(q, pool, table, pos, scale=0.3, v_dim=L)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 def _mla_cfg(**kw):
@@ -131,7 +243,7 @@ def test_paged_mla_decode_matches_contiguous(ps, shuffle, kernel):
             int(pos[s]))
         np.testing.assert_allclose(out[s], want[0], rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(
-            rows[s, int(pos[s])], jnp.concatenate(
+            rows[s, int(pos[s]), :w], jnp.concatenate(
                 [c2[0, int(pos[s])], r2[0, int(pos[s])]]), atol=1e-6)
 
 
@@ -269,6 +381,23 @@ def _latent_server_setup():
     cfg = get_config("deepseek-v2-lite-16b", smoke=True).replace(
         experts_first=4, experts_held=8)
     return cfg, init(jax.random.PRNGKey(0), cfg)
+
+
+def test_latent_server_counts_the_latent_kernels_grid_steps():
+    """``decode_grid_steps`` counts the latent kernel's grid over the
+    latent layers under ``blockspace``, and is 0 on the ``xla`` rung."""
+    from repro.kernels.latent_decode import latent_decode_grid_steps
+    from repro.launch.serve import PagedServeConfig, PagedServer
+    cfg, params = _latent_server_setup()
+    srv = PagedServer(cfg.replace(attn_decode_kernel="blockspace"), params,
+                      PagedServeConfig(max_len=32, num_slots=2, page_size=4,
+                                       num_pages=20))
+    want = latent_decode_grid_steps(
+        2, 8, cfg.n_layers, page_size=4, width=P.latent_lanes(
+            cfg.latent_width), itemsize=jnp.dtype(cfg.jdtype()).itemsize)
+    assert srv.decode_grid_steps == want == 2 * cfg.n_layers > 0
+    srv._apply_rung({"decode_kernel": "xla"})
+    assert srv.decode_grid_steps == 0
 
 
 def test_latent_server_xla_rung_serves_as_the_kernel():

@@ -559,13 +559,20 @@ def test_paged_server_counts_the_decode_kernels_grid_steps(
     from repro.kernels.flash_attention import paged_decode_grid_steps
     from repro.launch.serve import PagedServeConfig, PagedServer
     from repro.models import init
+    from repro.models import model as model_lib
     cfg = get_config(arch, smoke=True).replace(attn_decode_kernel=kernel)
     params = init(jax.random.PRNGKey(0), cfg)
     srv = PagedServer(cfg, params, PagedServeConfig(
         max_len=40, num_slots=3, page_size=8, num_pages=16))
-    # 3 slots x 5 pages a layer, for each fused-KV attention layer
+    # 3 slots x 5 pages a layer, for each fused-KV attention layer; each
+    # latent layer's kernel takes a slot's 5 pages in one chunk
+    latent_layers = sum(model_lib.layer_sig(cfg, i)[0] == "mla"
+                        for i in range(cfg.n_layers)) \
+        if kernel == "blockspace" else 0
     assert srv.decode_grid_steps == paged_decode_grid_steps(
-        3, 5, attn_layers) == 15 * attn_layers
+        3, 5, attn_layers) + 3 * latent_layers \
+        == 15 * attn_layers + 3 * latent_layers
+    assert (latent_layers > 0) == (arch == "deepseek-v2-lite-16b")
     srv._apply_rung({"decode_kernel": "xla"})     # the ladder's next rung
     assert srv.decode_grid_steps == 0
 

@@ -134,15 +134,21 @@ def test_paged_decode_compiles_for_v5e_at_the_phi3_cell(one_chip, hd):
 
 def test_paged_latent_decode_compiles_for_v5e_at_deepseek_v2_lite_widths(
         one_chip):
-    # DeepSeek-V2-Lite: 16 heads, latent rows of 512 + 64; 16 slots of
-    # 384 pages of 16 tokens in a 6145-page pool
-    from repro.kernels.latent_decode import paged_latent_decode
+    # DeepSeek-V2-Lite: 16 heads, latent rows of 512 + 64 stored in 640
+    # lanes; 16 slots of 384 pages of 16 tokens in a 6145-page pool.
+    # Each grid step copies a chunk of 32 pages into a double buffer
+    from repro.core.paged import latent_lanes
+    from repro.kernels.latent_decode import (latent_chunk_pages,
+                                             paged_latent_decode)
     slots, heads, width, pages, page = 16, 16, 576, 384, 16
+    assert latent_lanes(width) == 640
+    assert latent_chunk_pages(page, latent_lanes(width), pages, 2) == 32
     txt = _compile_text(
         lambda q, pool, pt, pos: paged_latent_decode(
             q, pool, pt, pos, scale=0.1, v_dim=512, backend="tpu"),
         _spec(one_chip, (slots, heads, width), jnp.bfloat16),
-        _spec(one_chip, (slots * pages + 1, page, width), jnp.bfloat16),
+        _spec(one_chip, (slots * pages + 1, page, latent_lanes(width)),
+              jnp.bfloat16),
         _spec(one_chip, (slots, pages), jnp.int32),
         _spec(one_chip, (slots,), jnp.int32))
     assert "tpu_custom_call" in txt
